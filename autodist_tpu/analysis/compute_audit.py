@@ -2,9 +2,8 @@
 
 The communication side of the lowered tier (:mod:`hlo_audit`, X-codes)
 diffs the realized collective schedule against the strategy's plan; this
-module is its COMPUTE counterpart.  The only real on-chip measurement the
-repo holds (``BENCH_MEASURED.json``) fails its MFU gate with XLA
-realizing ~1.95x the model FLOPs — recompute, duplicated fusions and
+module is its COMPUTE counterpart.  XLA's own count for the ResNet-50
+step is ~1.95x the model FLOPs — recompute, duplicated fusions and
 batch-stats overhead that no jaxpr-tier pass can see, because they only
 exist after lowering.  In the Checkmate spirit of static tensor-
 rematerialization accounting (arxiv 1910.02653) and the mixed-precision

@@ -66,6 +66,7 @@ import re
 from collections import defaultdict
 
 from jax import core as jax_core
+from jax.extend import core as jex_core
 
 from autodist_tpu.analysis.jaxpr_utils import (_UNIFORMIZING_PRIMS,
                                                _VARYING_PRIMS, _as_jaxpr,
@@ -88,7 +89,7 @@ _KEY_PLUMBING = frozenset({"random_unwrap", "convert_element_type",
                            "reshape", "squeeze", "broadcast_in_dim",
                            "transpose", "copy", "device_put"})
 
-_INLINE_PRIMS = ("pjit", "closed_call", "core_call", "custom_jvp_call",
+_INLINE_PRIMS = ("jit", "closed_call", "core_call", "custom_jvp_call",
                  "custom_vjp_call")
 _REPLAY_PRIMS = ("remat", "remat2", "checkpoint")
 
@@ -150,7 +151,7 @@ def _walk(state, jaxpr, in_vals, *, record=True, scan_depth=0,
     env = {}
 
     def rd(a):
-        if isinstance(a, jax_core.Literal):
+        if isinstance(a, jex_core.Literal):
             return _Val()
         return env.get(a, _Val())
 
@@ -179,7 +180,7 @@ def _walk(state, jaxpr, in_vals, *, record=True, scan_depth=0,
 
         if name == "random_seed":
             atom = eqn.invars[0]
-            if isinstance(atom, jax_core.Literal):
+            if isinstance(atom, jex_core.Literal):
                 label = f"seed({atom.val})"
             else:
                 label = state.fresh("seed")
@@ -193,11 +194,11 @@ def _walk(state, jaxpr, in_vals, *, record=True, scan_depth=0,
             label = v.key
             if label is None:
                 var = eqn.invars[0]
-                label = None if isinstance(var, jax_core.Literal) \
+                label = None if isinstance(var, jex_core.Literal) \
                     else state.rootmemo.get(var)
                 if label is None:
                     label = state.fresh("key")
-                    if not isinstance(var, jax_core.Literal):
+                    if not isinstance(var, jex_core.Literal):
                         state.rootmemo[var] = label
                 if record:
                     state.reg(label, "root", varying=v.varying)

@@ -26,6 +26,7 @@ varyings; sub-jaxpr signatures inlined) rather than failing.
 import numpy as np
 
 from jax import core as jax_core
+from jax.extend import core as jex_core
 
 # primitives that synchronize devices over mesh axes (an SPMD rendezvous:
 # every participant must issue them in the same order or the program hangs)
@@ -46,7 +47,7 @@ _VARYING_PRIMS = frozenset({"ppermute", "all_to_all", "reduce_scatter",
 
 
 def _as_jaxpr(j):
-    return j.jaxpr if isinstance(j, jax_core.ClosedJaxpr) else j
+    return j.jaxpr if isinstance(j, jex_core.ClosedJaxpr) else j
 
 
 def collective_axes(eqn):
@@ -61,11 +62,11 @@ def subjaxprs(eqn):
     """All sub-jaxprs of an eqn (generic fallback for unknown prims)."""
     subs = []
     for v in eqn.params.values():
-        if isinstance(v, (jax_core.Jaxpr, jax_core.ClosedJaxpr)):
+        if isinstance(v, (jex_core.Jaxpr, jex_core.ClosedJaxpr)):
             subs.append(_as_jaxpr(v))
         elif isinstance(v, (tuple, list)):
             subs.extend(_as_jaxpr(x) for x in v
-                        if isinstance(x, (jax_core.Jaxpr, jax_core.ClosedJaxpr)))
+                        if isinstance(x, (jex_core.Jaxpr, jex_core.ClosedJaxpr)))
     return subs
 
 
@@ -77,7 +78,7 @@ def collective_signature(jaxpr):
       ("scan", length, inner_sig)             — repeated inner signature
       ("cond", (sig_branch0, sig_branch1...)) — per-branch signatures
       ("while", cond_sig, body_sig)           — unbounded repetition
-    Sub-jaxprs of inlining primitives (pjit, remat, custom_*) contribute
+    Sub-jaxprs of inlining primitives (jit, remat, custom_*) contribute
     their signature in place.  Empty sub-structures are dropped so
     collective-free control flow does not pollute the signature.
     """
@@ -120,7 +121,7 @@ def find_shard_map_bodies(jaxpr):
     """(body_jaxpr, mesh, in_varying) for every shard_map eqn, recursively.
 
     ``in_varying``: per-invar frozensets of mesh axes the device-local
-    block may vary over — the axes its ``in_names`` entry shards it over
+    block may vary over — the axes its ``in_specs`` entry shards it over
     (a replicated in_spec means every device sees the same value).
     """
     out = []
@@ -129,14 +130,15 @@ def find_shard_map_bodies(jaxpr):
         if eqn.primitive.name == "shard_map":
             body = _as_jaxpr(eqn.params["jaxpr"])
             mesh = eqn.params.get("mesh")
-            in_names = eqn.params.get("in_names", ())
             varying = []
-            for names in in_names:
+            for spec in eqn.params.get("in_specs", ()):
                 axes = set()
-                for v in dict(names).values():
-                    axes.update(v if isinstance(v, (tuple, list)) else (v,))
+                for entry in spec:       # None | axis name | tuple of names
+                    if entry is not None:
+                        axes.update(entry if isinstance(entry, tuple)
+                                    else (entry,))
                 varying.append(frozenset(a for a in axes if isinstance(a, str)))
-            # in_names covers the body invars positionally; pad defensively
+            # in_specs covers the body invars positionally; pad defensively
             while len(varying) < len(body.invars):
                 varying.append(frozenset())
             out.append((body, mesh, varying))
@@ -150,7 +152,7 @@ def find_shard_map_bodies(jaxpr):
 
 
 def _read(env, atom):
-    if isinstance(atom, jax_core.Literal):
+    if isinstance(atom, jex_core.Literal):
         return frozenset()
     return env.get(atom, frozenset())
 
@@ -223,7 +225,7 @@ def varying_out(jaxpr, in_varying, const_varying=None):
                     break
                 carry = new_carry
             outs = carry + list(ys)
-        elif name in ("pjit", "closed_call", "core_call", "remat",
+        elif name in ("jit", "closed_call", "core_call", "remat",
                       "checkpoint", "custom_jvp_call", "custom_vjp_call"):
             sub = (eqn.params.get("jaxpr")
                    or eqn.params.get("call_jaxpr")
@@ -267,10 +269,10 @@ def liveness_peak_bytes(jaxpr, pinned_invars=None):
     n = len(jaxpr.eqns)
     for i, eqn in enumerate(jaxpr.eqns):
         for a in eqn.invars:
-            if isinstance(a, jax_core.Var):
+            if isinstance(a, jex_core.Var):
                 last_use[a] = i
     for v in jaxpr.outvars:
-        if isinstance(v, jax_core.Var):
+        if isinstance(v, jex_core.Var):
             last_use[v] = n
     if pinned_invars:
         for v in pinned_invars:
@@ -292,7 +294,7 @@ def liveness_peak_bytes(jaxpr, pinned_invars=None):
             live[v] = aval_bytes(v.aval)
             current += live[v]
         peak = max(peak, current + inner)
-        for a in set(a for a in eqn.invars if isinstance(a, jax_core.Var)):
+        for a in set(a for a in eqn.invars if isinstance(a, jex_core.Var)):
             if last_use.get(a) == i and a in live:
                 current -= live.pop(a)
     return peak

@@ -214,7 +214,7 @@ step runs.
 """
 import numpy as np
 
-from jax import core as jax_core
+from jax.extend import core as jex_core
 
 from autodist_tpu.analysis.jaxpr_utils import (
     collective_axes, collective_signature, find_shard_map_bodies,
@@ -757,12 +757,12 @@ def hierarchy_pass(ctx):
 
 def _donation_walk(jaxpr, findings):
     jaxpr = _as_jaxpr(jaxpr)
-    outvars = set(v for v in jaxpr.outvars if isinstance(v, jax_core.Var))
+    outvars = set(v for v in jaxpr.outvars if isinstance(v, jex_core.Var))
     for i, eqn in enumerate(jaxpr.eqns):
         di = eqn.params.get("donated_invars")
         if di and any(di):
             for flag, a in zip(di, eqn.invars):
-                if not flag or not isinstance(a, jax_core.Var):
+                if not flag or not isinstance(a, jex_core.Var):
                     continue
                 readers = [j for j in range(i + 1, len(jaxpr.eqns))
                            if a in jaxpr.eqns[j].invars]
@@ -793,7 +793,7 @@ def donation_pass(ctx):
         return findings
     used = set()
     for eqn in jaxpr.eqns:
-        used.update(a for a in eqn.invars if isinstance(a, jax_core.Var))
+        used.update(a for a in eqn.invars if isinstance(a, jex_core.Var))
     out_slots = {}
     for v in jaxpr.outvars:
         aval = getattr(v, "aval", None)

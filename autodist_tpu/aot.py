@@ -25,9 +25,8 @@ Usage::
     print(aot.memory_analysis)          # HBM demand on the target
     blob = aot.serialize()              # ship to the pod
 
-The process must not be captured by an interactive TPU platform plugin
-(run plain, or with the plugin env unset); the default jax backend (cpu)
-is untouched — only the compile targets the topology.
+The process's own jax backend is untouched — only the compile targets
+the topology.
 """
 import contextlib
 import dataclasses
@@ -149,6 +148,7 @@ class AOTCompiledStep:
         rebuilds them from the same model code when it needs them."""
         import pickle
 
+        import jax
         from jax.experimental.serialize_executable import (
             deserialize_and_load)
 
@@ -160,8 +160,13 @@ class AOTCompiledStep:
             raise ValueError(
                 "not an AOTCompiledStep blob (expected the pickled "
                 f"{cls._BLOB_FORMAT!r} payload from serialize())")
+        # the blob's own device count, not every attached device: a step
+        # compiled for fewer devices than the host holds loads onto the
+        # first ``n_devices`` of them
+        devices = jax.devices(backend)[:d["n_devices"]]
         exe = deserialize_and_load(d["payload"], d["in_tree"], d["out_tree"],
-                                   backend=backend)
+                                   backend=backend,
+                                   execution_devices=devices)
         return cls(topology=d["topology"], n_devices=d["n_devices"],
                    device_kind=d["device_kind"], executable=exe,
                    state_avals=None, donate=d["donate"],

@@ -25,6 +25,7 @@ from autodist_tpu.model_item import ModelItem
 from autodist_tpu.resource_spec import ResourceSpec
 from autodist_tpu.strategy.base import Strategy, StrategyCompiler
 from autodist_tpu.utils import logging
+from autodist_tpu.utils.compile_cache import ensure_compile_cache
 
 _DEFAULT_AUTODIST = {}
 
@@ -56,6 +57,7 @@ class AutoDist:
     def __init__(self, resource_spec_file=None, strategy_builder=None, *,
                  resource_spec: Optional[ResourceSpec] = None):
         set_default_autodist(self)
+        ensure_compile_cache()
         self._resource_spec = resource_spec or ResourceSpec(resource_spec_file)
         if strategy_builder is None:
             from autodist_tpu.strategy import PSLoadBalancing

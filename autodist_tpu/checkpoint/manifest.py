@@ -172,7 +172,7 @@ def geometry_matches(transformer, manifest):
             f"{transformer.num_replicas}")
     if manifest.get("hierarchy") != transformer.sync_hierarchy:
         # the EF-residual rows of a TWO_LEVEL bucket live in ici-major
-        # regions; a hierarchy change relayouts them even at equal R
+        # regions; a hierarchy change lays them out anew even at equal R
         reasons.append(
             f"hierarchy {manifest.get('hierarchy')!r} != "
             f"{transformer.sync_hierarchy!r}")

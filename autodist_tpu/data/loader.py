@@ -7,8 +7,10 @@ with a prefetch ring; this module wraps it with ctypes and shapes batches
 into numpy/device arrays.  Training overlap: while the TPU runs step N, the
 C++ threads assemble batch N+1..N+prefetch.
 
-Build on first use: ``make -C native`` (a cached .so under the repo).
-Falls back to a pure-numpy loader when no compiler is available.
+Built on first use with ``make -C native`` when the library is missing or
+older than its source (the .so is untracked).  Falls back, with a warning,
+to a pure-numpy loader when no compiler is available; :func:`loader_kind`
+says which one a process got.
 """
 import ctypes
 import os
@@ -22,55 +24,33 @@ from autodist_tpu.utils import logging
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libautodist_io.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "autodist_io.cpp")
 _lib = None
+_built_here = False
 _lib_lock = threading.Lock()
 
 
 def _load_native():
-    global _lib
+    """The ctypes handle of the native library, or ``False`` when it could
+    not be built.  The library is untracked: it is (re)built from
+    ``native/autodist_io.cpp`` whenever it is missing or older than its
+    source, so what runs is what the tree's source says."""
+    global _lib, _built_here
     if _lib is not None:
         return _lib
     with _lib_lock:
         if _lib is not None:
             return _lib
-
-        def build():
-            # noqa-justified AD02: a synchronous build-helper make, not
-            # worker process management — no monitor/retry semantics apply
-            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,  # noqa
-                           capture_output=True)
-
         try:
-            if not os.path.exists(_SO_PATH):
-                build()
-            lib = ctypes.CDLL(_SO_PATH)
-            try:
-                lib.adio_loader_new_sharded  # probe: stale prebuilt .so?
-            except AttributeError:
-                # a .so from an older source tree survived (it is
-                # untracked): rebuild and load the fresh binary under a
-                # unique path (dlopen caches by pathname)
-                logging.warning("native IO library is stale; rebuilding")
-                subprocess.run(["make", "-C", _NATIVE_DIR, "clean"],  # noqa - build helper, not worker management
+            if (not os.path.exists(_SO_PATH)
+                    or os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH)):
+                # noqa-justified AD02: a synchronous build-helper make, not
+                # worker process management — no monitor/retry semantics apply
+                subprocess.run(["make", "-C", _NATIVE_DIR],  # noqa
                                check=True, capture_output=True)
-                build()
-                import shutil
-                import tempfile
-
-                fd, tmp_path = tempfile.mkstemp(prefix="autodist_io_",
-                                                suffix=".so")
-                os.close(fd)
-                shutil.copyfile(_SO_PATH, tmp_path)
-                lib = ctypes.CDLL(tmp_path)
-                lib.adio_loader_new_sharded  # must resolve now
-                try:
-                    # the mapped inode persists after unlink (Linux), so the
-                    # temp copy never leaks and no cross-process sweep is
-                    # needed
-                    os.unlink(tmp_path)
-                except OSError:
-                    pass
-        except Exception as e:
+                _built_here = True
+            lib = ctypes.CDLL(_SO_PATH)
+        except (OSError, subprocess.CalledProcessError) as e:
             logging.warning("native IO unavailable (%s); using numpy fallback", e)
             _lib = False
             return _lib
@@ -98,6 +78,15 @@ def _load_native():
         lib.adio_loader_free.argtypes = [ctypes.c_void_p]
         _lib = lib
     return _lib
+
+
+def loader_kind():
+    """Which loader this process runs on: ``"native (built in this
+    process)"``, ``"native (prebuilt)"`` or ``"numpy"``."""
+    if not _load_native():
+        return "numpy"
+    return ("native (built in this process)" if _built_here
+            else "native (prebuilt)")
 
 
 def write_records(path, array):

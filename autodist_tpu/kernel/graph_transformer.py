@@ -27,7 +27,6 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from autodist_tpu.utils import compat  # noqa: F401  (jax.shard_map shim)
 from autodist_tpu.kernel import partitioner as part
 from autodist_tpu.kernel.partitioner import Placement, SyncKind
 from autodist_tpu.kernel.synchronization import all_reduce as ar_sync

@@ -10,10 +10,11 @@ of XLA's three-HBM-round-trip lowering — the remediation the F008
 batch-statistics HBM traffic entirely (per-sample groups, no running
 stats, train == eval).
 
-Both fall back to the unfused reference path when a row slab would not
-fit VMEM (``fused_norm.MAX_FUSED_ROWS`` — early high-resolution ResNet
-stages at large batch) or when ``impl="reference"`` forces it for
-equivalence tests; off TPU the kernels run in interpreter mode.
+Both run the unfused reference path, and log the site once, when a slab
+would not fit VMEM (``fused_norm.bn_fits_vmem`` / ``gn_fits_vmem`` — at
+ResNet-50 B=256 that is every batch-norm site), or when
+``impl="reference"`` forces it for equivalence tests; off TPU the kernels
+run in interpreter mode.
 """
 from typing import Any, Callable
 
@@ -21,15 +22,27 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from autodist_tpu.ops.pallas.fused_norm import (MAX_FUSED_ROWS,
-                                                batch_norm_reference,
+from autodist_tpu.ops.pallas.fused_norm import (batch_norm_reference,
+                                                bn_fits_vmem,
                                                 fused_batch_norm,
                                                 fused_group_norm,
+                                                gn_fits_vmem,
                                                 group_norm_reference)
+from autodist_tpu.utils import logging
 
 
-def _rows_fit(x):
-    return x.size // x.shape[-1] <= MAX_FUSED_ROWS
+def _use_kernel(module, x, fits):
+    """``impl="kernel"`` and the slab fits VMEM; a site that does not fit
+    is logged once, at trace time, and runs the reference."""
+    if module.impl != "kernel":
+        return False
+    if not fits(x):
+        logging.warning_once(
+            "%s on %s %s: slab exceeds the kernel's VMEM budget; running "
+            "the unfused reference at this site", type(module).__name__,
+            tuple(x.shape), str(x.dtype))
+        return False
+    return True
 
 
 class FusedBatchNorm(nn.Module):
@@ -57,7 +70,7 @@ class FusedBatchNorm(nn.Module):
             inv = jax.lax.rsqrt(ra_var.value + self.epsilon) * scale
             y = (x.astype(jnp.float32) - ra_mean.value) * inv + bias
             return y.astype(out_dtype)
-        if self.impl == "kernel" and _rows_fit(x):
+        if _use_kernel(self, x, bn_fits_vmem):
             y, mean, var = fused_batch_norm(x, scale, bias,
                                             eps=self.epsilon)
         else:
@@ -91,8 +104,7 @@ class FusedGroupNorm(nn.Module):
             (ch if ch < self.num_groups else 1)
         scale = self.param("scale", self.scale_init, (ch,), jnp.float32)
         bias = self.param("bias", self.bias_init, (ch,), jnp.float32)
-        if self.impl == "kernel" and \
-                x.size // (x.shape[0] * ch) <= MAX_FUSED_ROWS:
+        if _use_kernel(self, x, gn_fits_vmem):
             y = fused_group_norm(x, scale, bias, groups, eps=self.epsilon)
         else:
             y = group_norm_reference(x, scale, bias, groups,

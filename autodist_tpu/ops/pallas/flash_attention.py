@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from autodist_tpu.utils import logging
+
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 _NEG_INF = -1e30  # finite: -inf NaNs under (0 * -inf) in masked-row algebra
@@ -559,6 +561,10 @@ def flash_attention(q, k, v, causal=False, kv_mask=None, sm_scale=None,
     bq = _pick_block(sq, block_q, align)
     bk = _pick_block(sk, block_k, align)
     if not bq or not bk:
+        logging.warning_once(
+            "flash_attention q%s k%s: no %d-aligned block divides the "
+            "sequence; running XLA attention at this site",
+            tuple(q.shape), tuple(k.shape), align)
         if group > 1:
             k = jnp.repeat(k, group, axis=2)
             v = jnp.repeat(v, group, axis=2)

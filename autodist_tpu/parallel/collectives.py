@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from autodist_tpu.const import DEFAULT_BUCKET_BYTES
-from autodist_tpu.utils import compat  # noqa: F401  (jax.lax.axis_size shim)
 
 
 def _norm_axes(axis_name):

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from autodist_tpu.kernel.collectives import ppermute, ring_perm
+from autodist_tpu.utils import logging
 
 
 def _online_block(q, k_blk, v_blk, bias_blk, m, l, o, scale):
@@ -186,6 +187,10 @@ def ring_attention(q, k, v, axis_name, causal=False, impl="auto"):
         out = _ring_flash(q, k, v, axis_name, causal)
         if out is not None:
             return out
+        logging.warning_once(
+            "ring_attention q%s over '%s': the local sequence cannot be "
+            "tiled for the flash block update; running the XLA block "
+            "update at this site", tuple(q.shape), str(axis_name))
     R = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, Sq, H, D = q.shape

@@ -1023,9 +1023,7 @@ def measure_and_record(session, batch, resource_yaml="", steps=10, warmup=2):
     strategy, runtime) tuple (``simulator/dataset/README.md``).
 
     Timing uses :func:`autodist_tpu.utils.timing.measure_per_step`
-    (chain-differenced, one scalar fetch per window) so the number stays
-    honest on async/tunneled backends where ``block_until_ready`` does
-    not actually block.  ``steps`` bounds the total executed step count:
+    (chain-differenced, one scalar fetch per window).  ``steps`` bounds the total executed step count:
     the two differenced windows run ~steps/3 and ~2*steps/3 steps."""
     from autodist_tpu.utils.timing import fetch_scalar, measure_per_step
 

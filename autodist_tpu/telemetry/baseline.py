@@ -8,8 +8,8 @@ how to diff:
 - step-wall percentiles + achieved ``mfu_p50`` from a finalized
   manifest's summary trailer;
 - ``cpu_mesh_engine_overhead`` — the machine-normalized engine-vs-raw
-  ratio from the cpu_proxy sweep (the only live perf signal while the
-  bench relay is down, ROADMAP item 3);
+  ratio from the cpu_proxy sweep (a CPU-backend signal, never a chip
+  speed);
 - ``predicted_mfu_ceiling`` (F006) and realized comm bytes (X006) — the
   *static* quantities, so a structural regression is caught by
   ``make perf-gate`` before any chip is touched.

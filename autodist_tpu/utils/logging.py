@@ -58,6 +58,18 @@ def warning(msg, *args, **kwargs):
     get_logger().warning(msg, *args, **kwargs)
 
 
+_warned = set()
+
+
+def warning_once(msg, *args):
+    """``warning`` that drops exact repeats: for notes made at trace time,
+    which every re-trace of the same site would otherwise print again.
+    ``args`` must be hashable (shapes, dtypes, names)."""
+    if (msg, args) not in _warned:
+        _warned.add((msg, args))
+        warning(msg, *args)
+
+
 def error(msg, *args, **kwargs):
     get_logger().error(msg, *args, **kwargs)
 

@@ -537,7 +537,8 @@ def test_f008_respects_absolute_bytes_floor():
 def test_roofline_reconciles_measured_v5e_resnet_step():
     from autodist_tpu.simulator.cost_model import roofline_bound, roofline_s
 
-    # BENCH_MEASURED.json: 99.8 ms/step, XLA-counted 6.12 TFLOP, 83.4 GB
+    # A round-3 chip reading of 99.8 ms/step (to be re-measured, ROADMAP
+    # S2), XLA-counted 6.12 TFLOP, 83.4 GB
     # of HBM traffic, 197 bf16 TFLOP/s peak, 819 GB/s HBM.  The byte leg
     # is what explains the wall -- the step is memory-bound, and the
     # roofline lands within 25% of the measured step time.

@@ -123,3 +123,31 @@ def test_device_prefetcher_preserves_order_and_values(dataset):
     # prefetched batches run through the session directly
     m = sess.run(sess._shard_batch(host_batches[0]))
     assert np.isfinite(float(m["loss"]))
+
+
+@pytest.mark.parametrize("stale", ["missing", "older_than_source"])
+def test_native_lib_rebuilt_from_source_when_stale(monkeypatch, stale):
+    """The .so is untracked: what runs is built from native/autodist_io.cpp
+    whenever the library is missing or older than that source."""
+    import os
+
+    import autodist_tpu.data.loader as L
+
+    assert L._load_native()              # make sure one is on disk
+    if stale == "missing":
+        os.unlink(L._SO_PATH)
+    else:
+        old = os.path.getmtime(L._SRC_PATH) - 10
+        os.utime(L._SO_PATH, (old, old))
+    monkeypatch.setattr(L, "_lib", None)
+    monkeypatch.setattr(L, "_built_here", False)
+    assert L._load_native()
+    assert os.path.getmtime(L._SO_PATH) >= os.path.getmtime(L._SRC_PATH)
+    assert L.loader_kind() == "native (built in this process)"
+
+
+def test_loader_kind_names_the_numpy_fallback(monkeypatch):
+    import autodist_tpu.data.loader as L
+
+    monkeypatch.setattr(L, "_lib", False)
+    assert L.loader_kind() == "numpy"

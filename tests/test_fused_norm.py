@@ -16,8 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from autodist_tpu.ops.pallas.fused_norm import (MAX_FUSED_ROWS,
-                                                batch_norm_reference,
+from autodist_tpu.ops.pallas.fused_norm import (batch_norm_reference,
+                                                bn_fits_vmem,
                                                 fused_batch_norm,
                                                 fused_group_norm,
                                                 group_norm_reference)
@@ -189,18 +189,25 @@ def test_fused_batch_norm_module_tracks_nn_batchnorm():
     np.testing.assert_allclose(ye, pe, atol=2e-5, rtol=2e-5)
 
 
-def test_fused_module_falls_back_above_max_rows():
+def test_fused_module_falls_back_above_vmem_budget(monkeypatch):
     from autodist_tpu.models import FusedBatchNorm
+    from autodist_tpu.utils import logging
 
-    # rows = B*H*W > MAX_FUSED_ROWS: the module must take the reference
-    # path (whole-slab kernel would blow the VMEM bound) and still agree
-    x = _mk((MAX_FUSED_ROWS + 64, 1, 1, 8), jnp.float32)
+    # a row slab over the kernel's VMEM budget: the module must take the
+    # reference path, say so once with the site's shape, and still agree
+    x = _mk((8192, 1, 1, 8), jnp.float32)
+    assert bn_fits_vmem(x[:4096]) and not bn_fits_vmem(x)
+    said = []
+    monkeypatch.setattr(logging, "warning",
+                        lambda msg, *a: said.append(msg % a))
     mod = FusedBatchNorm(use_running_average=False)
     v = mod.init(jax.random.PRNGKey(0), x)
     y, _ = mod.apply(v, x, mutable=["batch_stats"])
     y_ref, _, _ = batch_norm_reference(
         x, v["params"]["scale"], v["params"]["bias"])
     np.testing.assert_allclose(y, y_ref, atol=2e-5, rtol=2e-5)
+    assert len(said) == 1 and "(8192, 1, 1, 8)" in said[0] \
+        and "reference" in said[0]
 
 
 def test_resnet_norm_knob_bn_fused_matches_bn():

@@ -600,7 +600,7 @@ def test_telemetry_records_sharded_update(tmp_path):
 # -- bench CPU-mesh proxy (satellite) ---------------------------------------
 
 def test_bench_cpu_proxy_contract():
-    """The relay-down proxy emits the documented record shape: an
+    """The named CPU-mesh proxy emits the documented record shape: an
     engine-vs-raw overhead ratio (never a hardware claim) including the
     sharded-update variant's step time."""
     import bench

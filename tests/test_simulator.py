@@ -110,8 +110,7 @@ def test_update_phase_separates_dense_strategies():
 
 
 def test_record_measure_calibrate_rank_pipeline(tmp_path):
-    """The full AutoSync loop on the CPU mesh (relay-down insurance,
-    VERDICT r4 item 7): measure real sessions under three strategies,
+    """The full AutoSync loop on the CPU mesh: measure real sessions under three strategies,
     dump/load RuntimeRecords (backend-labeled), fit a calibration from
     the (estimate, measured) pairs, and rank with it — every stage of
     the record→calibrate→rank pipeline exercised end-to-end.  The

@@ -52,7 +52,12 @@ def _clean_telemetry_state():
 
 # -- the 5-step acceptance run ---------------------------------------------
 
-def test_five_step_run_manifest_report_calibrate(tmp_path):
+def test_five_step_run_manifest_report_calibrate(tmp_path, monkeypatch):
+    from autodist_tpu.utils import timing
+
+    # the table-hit case: the CPU has no entry of its own, and without one
+    # a step record carries no mfu (test_step_record_without_peak_entry)
+    monkeypatch.setitem(timing.PEAK_BF16_FLOPS, "cpu", 1e12)
     run_dir = str(tmp_path / "run")
     telemetry.enable(run_dir=run_dir)
     sess = _session()
@@ -70,7 +75,7 @@ def test_five_step_run_manifest_report_calibrate(tmp_path):
         assert r["wall_cancelled_s"] >= 0
         assert r["examples"] == 16
         assert r["throughput_eps"] > 0
-        assert 0 <= r["mfu"] < 1  # CPU: tiny but present, against assumed peak
+        assert 0 <= r["mfu"] < 1 and r["peak_flops"] == 1e12
         assert r["flops_per_device"] > 0
         assert r["w"] == 0 and "pid" in r
     snaps = [r for r in records if r["kind"] == "snapshot"]
@@ -109,6 +114,30 @@ def test_five_step_run_manifest_report_calibrate(tmp_path):
     assert set(cal) == {"compute_scale", "comm_scale", "overhead_s"}
     assert pairs[0][1] == rec.step_time_s
     assert pairs[0][0].comm_s >= 0  # the rebuilt case priced by estimate()
+
+
+def test_step_record_without_peak_entry(tmp_path):
+    """A device the peak table has no entry for (the CPU here) gets the
+    FLOP count and no ``mfu``/``peak_flops``: never an MFU against another
+    device's peak."""
+    from autodist_tpu.utils.timing import peak_flops
+
+    with pytest.raises(KeyError, match="device_kind"):
+        peak_flops()
+    telemetry.enable(run_dir=str(tmp_path / "run"))
+    sess = _session()
+    sess.run_steps([BATCH] * 2)
+    records, errors = telemetry.validate_manifest(
+        os.path.join(str(tmp_path / "run"), "manifest.jsonl"),
+        require_steps=True)
+    assert errors == []
+    steps = [r for r in records if r["kind"] == "step"]
+    assert len(steps) == 2
+    for r in steps:
+        assert r["flops_per_device"] > 0
+        assert "mfu" not in r and "peak_flops" not in r
+    (summary,) = [r for r in records if r["kind"] == "summary"]
+    assert "mfu_p50" not in summary
 
 
 def test_disabled_zero_overhead(monkeypatch):

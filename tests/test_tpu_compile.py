@@ -1,0 +1,230 @@
+"""The main path's Pallas kernels, compiled for a described v5e at real widths.
+
+The TPU compiler is installed where the tests run; it compiles for a chip
+that is described (``v5e:2x2``) and not attached.  That shows what interpret
+mode cannot: a block Mosaic cannot tile, a slab over the VMEM limit.  Nothing
+runs, so nothing here says a kernel is right or fast — ``chip_smoke.py`` and
+the interpret-mode tests do that.
+
+This is the only test file that describes a topology, and it does so inside a
+fixture: the process that does it holds the TPU library's lock until it exits.
+``tests/conftest.py`` has the persistent compile cache off, so these compiles
+are neither written to it nor (unreadably, without a chip) looked up in it.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from autodist_tpu.models import FusedBatchNorm
+from autodist_tpu.models.llama import LlamaConfig
+from autodist_tpu.ops.pallas import flash_attention as F
+from autodist_tpu.ops.pallas import fused_norm as N
+from autodist_tpu.ops.pallas import quantize as Q
+
+# GPT-2-small's training shape in chip_smoke.py: (B, S, H, D)
+GPT_ATTN = (32, 1024, 12, 64)
+# every BatchNorm input of ResNet-50 at B=256, 224x224: (rows, channels)
+RESNET50_B256_BN_SITES = [
+    (256 * 112 * 112, 64), (256 * 56 * 56, 64), (256 * 56 * 56, 256),
+    (256 * 56 * 56, 128), (256 * 28 * 28, 128), (256 * 28 * 28, 512),
+    (256 * 28 * 28, 256), (256 * 14 * 14, 256), (256 * 14 * 14, 1024),
+    (256 * 14 * 14, 512), (256 * 7 * 7, 512), (256 * 7 * 7, 2048)]
+# the same per sample, for GroupNorm: (rows per sample, channels)
+RESNET50_GN_SITES = [(r // 256, c) for r, c in RESNET50_B256_BN_SITES]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or its lock is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _aval(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile(fn, *avals):
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the executable"
+    return text
+
+
+# ------------------------------------------------------- flash attention --
+
+@pytest.mark.parametrize("kv_heads", [
+    GPT_ATTN[2], LlamaConfig().num_kv_heads], ids=["mha", "gqa"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_flash_attention_gpt_small_train_shape(one_chip, kv_heads, grad):
+    b, s, h, d = GPT_ATTN
+    q = _aval(one_chip, (b, s, h, d), jnp.bfloat16)
+    kv = _aval(one_chip, (b, s, kv_heads, d), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = F.flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)) if grad else loss, q, kv, kv)
+
+
+def test_flash_block_update_ring_step(one_chip):
+    # one ring step of a device that holds S=1024 positions of 2 x 12 heads
+    bh, s, d = 2 * GPT_ATTN[2], 1024, GPT_ATTN[3]
+    qkv = _aval(one_chip, (bh, s, d), jnp.bfloat16)
+    ml = _aval(one_chip, (bh, s), jnp.float32)
+    o = _aval(one_chip, (bh, s, d), jnp.float32)
+    off = _aval(one_chip, (), jnp.int32)
+
+    def step(q, k, v, m, l, o, q_off, k_off):
+        out = F.flash_block_update(q, k, v, m, l, o, q_off, k_off,
+                                   causal=True, interpret=False)
+        assert out is not None, "S=1024 must tile for the compiled kernel"
+        return out
+
+    _compile(step, qkv, qkv, qkv, ml, ml, o, off, off)
+
+
+# ------------------------------------------------------ int8 wire codecs --
+
+@pytest.fixture(scope="module")
+def gpt_small_bucket_blocks():
+    """``(n_dev, blocks per chunk)`` of the largest gradient bucket the
+    engine plans for GPT-2-small under an int8 codec on four replicas, tiled
+    as ``Int8Compressor.all_reduce`` tiles it for the kernels."""
+    import optax
+
+    from autodist_tpu.kernel.graph_transformer import GraphTransformer
+    from autodist_tpu.model_item import ModelItem
+    from autodist_tpu.models.gpt import GPT, GPT_SMALL
+    from autodist_tpu.resource_spec import ResourceSpec
+    from autodist_tpu.strategy import AllReduce
+    from autodist_tpu.strategy.base import StrategyCompiler
+    from autodist_tpu.utils.rng import host_key
+
+    n_dev = 4
+    shapes = jax.eval_shape(lambda: GPT(GPT_SMALL).init(
+        host_key(0), jnp.zeros((1, 8), jnp.int32),
+        return_hidden=True))["params"]
+    spec = ResourceSpec.from_num_chips(n_dev)
+    item = ModelItem(lambda p, b: 0.0, shapes, optax.sgd(0.1))
+    strategy = StrategyCompiler(item, spec).compile(
+        AllReduce(compressor="Int8Compressor").build(item, spec))
+    t = GraphTransformer(strategy, item,
+                         Mesh(np.array(jax.devices()[:n_dev]), ("replica",)))
+    n = max(sum(b.sizes) for b in t.buckets)
+    tile = Q.ROWS * Q.BLOCK
+    chunk = -(-(-(-n // n_dev)) // tile) * tile   # compressor.py: use_pallas
+    return n_dev, chunk // Q.BLOCK
+
+
+def test_int8_codec_kernels_gpt_small_bucket(one_chip,
+                                             gpt_small_bucket_blocks):
+    n_dev, blocks = gpt_small_bucket_blocks
+    assert blocks * Q.BLOCK * n_dev > 60e6      # over half of GPT-2-small
+    x = _aval(one_chip, (n_dev * blocks, Q.BLOCK), jnp.float32)
+    q_rx = _aval(one_chip, (n_dev, blocks, Q.BLOCK), jnp.int8)
+    s_rx = _aval(one_chip, (n_dev, blocks, 1), jnp.float32)
+    _compile(lambda x: Q.quantize_int8(x, interpret=False), x)
+    _compile(lambda q, s: Q.dequant_sum(q, s, interpret=False), q_rx, s_rx)
+    _compile(lambda q, s: Q.equarx_hop(q, s, n_dev, interpret=False),
+             q_rx, s_rx)
+
+
+# ----------------------------------------------------------- fused norms --
+
+def _largest_fitting_rows(fits, hi=1 << 16):
+    return max(r for r in range(N.SUB, hi, N.SUB) if fits(r))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_fused_batch_norm_at_the_guard(one_chip, dtype, grad):
+    """The guard is what the compiler accepts: at the widest ResNet-50
+    channel count, the tallest slab ``bn_fits_vmem`` admits compiles, plain
+    and with residual + relu."""
+    ch = 2048
+    for residual in (False, True):
+        rows = _largest_fitting_rows(lambda r: N.bn_fits_vmem(
+            jax.ShapeDtypeStruct((r, ch), dtype), residual=residual))
+        x = _aval(one_chip, (rows, ch), dtype)
+        sb = _aval(one_chip, (ch,), jnp.float32)
+
+        def loss(x, scale, bias, res):
+            y, mean, var = N.fused_batch_norm(
+                x, scale, bias, act="relu" if residual else None,
+                residual=res if residual else None, interpret=False)
+            return (jnp.sum(y.astype(jnp.float32)) + jnp.sum(mean)
+                    + jnp.sum(var))
+
+        _compile(jax.grad(loss, argnums=(0, 1, 2, 3)) if grad else loss,
+                 x, sb, sb, x)
+
+
+def test_resnet50_b256_batch_norm_sites_are_all_over_the_guard():
+    # the finding ROADMAP S2 rests on: at B=256 the whole-slab kernel fits
+    # at no ResNet-50 site, so norm="bn_fused" is the reference everywhere
+    for rows, ch in RESNET50_B256_BN_SITES:
+        assert not N.bn_fits_vmem(
+            jax.ShapeDtypeStruct((rows, ch), jnp.bfloat16)), (rows, ch)
+
+
+def test_fused_batch_norm_module_over_the_guard_runs_the_reference(
+        one_chip, monkeypatch):
+    from autodist_tpu.utils import logging
+
+    shape = (256, 7, 7, 2048)       # ResNet-50 B=256's smallest BN site
+    said = []
+    monkeypatch.setattr(logging, "warning",
+                        lambda msg, *a: said.append(msg % a))
+    monkeypatch.setattr(N, "_on_tpu", lambda: True)  # interpret=False
+    mod = FusedBatchNorm(use_running_average=False, dtype=jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: mod.init(jax.random.key(0), jnp.zeros(shape, jnp.bfloat16)))
+
+    def fwd(v, x):
+        return mod.apply(v, x, mutable=["batch_stats"])[0]
+
+    avals = jax.tree.map(lambda a: _aval(one_chip, a.shape, a.dtype),
+                         variables)
+    text = jax.jit(fwd).lower(
+        avals, _aval(one_chip, shape, jnp.bfloat16)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert any(str(shape) in s and "reference" in s for s in said), said
+
+
+def test_fused_group_norm_largest_admitted_resnet50_site(one_chip):
+    admitted = [(r, c) for r, c in RESNET50_GN_SITES if N.gn_fits_vmem(
+        jax.ShapeDtypeStruct((256, r, c), jnp.bfloat16))]
+    assert (112 * 112, 64) not in admitted       # the stem's slab is over
+    rows, ch = max(admitted, key=lambda rc: rc[0] * max(rc[1], N.LANE))
+    assert (rows, ch) == (56 * 56, 256)
+    x = _aval(one_chip, (256, rows, ch), jnp.bfloat16)
+    sb = _aval(one_chip, (ch,), jnp.float32)
+
+    def loss(x, scale, bias):
+        y = N.fused_group_norm(x, scale, bias, 32, interpret=False)
+        return jnp.sum(y.astype(jnp.float32))
+
+    _compile(loss, x, sb, sb)
+    # (value_and_grad: the backward is closed-form jnp, and without the
+    # value nothing would need the kernel's output)
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), x, sb, sb)
+    # and over the guard the compiler does refuse: the guard is not slack
+    # by an order of magnitude
+    over = _aval(one_chip, (256, 112 * 112, 64), jnp.bfloat16)
+    sb64 = _aval(one_chip, (64,), jnp.float32)
+    with pytest.raises(Exception, match="vmem|VMEM"):
+        jax.jit(loss).lower(over, sb64, sb64).compile()

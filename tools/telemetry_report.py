@@ -7,8 +7,8 @@ files are merged in memory when the chief's ``manifest.jsonl`` is
 absent; schema in ``autodist_tpu/telemetry/schema.py``) and reports:
 
 - step-time percentiles (RTT-cancelled walls) + compile split,
-- throughput and achieved-MFU percentiles (with the assumed-peak caveat
-  when the device kind is unknown),
+- throughput and achieved-MFU percentiles (MFU only where the device
+  kind has an entry in the peak table),
 - HBM peak and headroom against the device generation's budget (when
   the backend reports ``memory_stats`` and the kind is recognized),
 - predicted comm/compute overlap from the recorded cost estimate next
@@ -124,7 +124,6 @@ def summarize_manifest(records, stats=None):
     mfus = [r["mfu"] for r in steps if "mfu" in r]
     if mfus:
         out["mfu_p50"] = percentiles(mfus)[0.5]
-        out["peak_assumed"] = any(r.get("peak_assumed") for r in steps)
     for s in summaries:
         if "compile_s" in s:
             out["compile_s"] = s["compile_s"]
@@ -207,9 +206,7 @@ def render(summary):
     if "throughput_eps_p50" in summary:
         add(f"throughput p50: {summary['throughput_eps_p50']:.1f} examples/s")
     if "mfu_p50" in summary:
-        caveat = " (peak ASSUMED — unknown device kind)" \
-            if summary.get("peak_assumed") else ""
-        add(f"achieved MFU p50: {summary['mfu_p50']:.4%}{caveat}")
+        add(f"achieved MFU p50: {summary['mfu_p50']:.4%}")
     if "hbm_peak_bytes" in summary:
         line = f"HBM peak: {_fmt_bytes(summary['hbm_peak_bytes'])}"
         if "hbm_headroom_bytes" in summary:

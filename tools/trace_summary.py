@@ -7,7 +7,7 @@ Reads the chrome-trace JSON (``*.trace.json.gz``) that
 ``jax.profiler.trace`` writes under ``<dir>/plugins/profile/<run>/`` and
 aggregates complete events on device-side tracks (TPU/accelerator lanes)
 by event name — the quick "where do the milliseconds go" view for MFU work
-(STATUS.md round-3 item 2) without external profiler tooling.
+without external profiler tooling.
 
 The chrome-trace event model (loaders, device-lane detection) lives in
 :mod:`autodist_tpu.telemetry.timeline` — the one blessed parser
