@@ -1,0 +1,1 @@
+"""The repo's benchmark: see BENCHMARK.json and PERF.md."""
