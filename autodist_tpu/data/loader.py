@@ -19,6 +19,7 @@ import threading
 
 import numpy as np
 
+from autodist_tpu import telemetry
 from autodist_tpu.utils import logging
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
@@ -73,6 +74,8 @@ def _load_native():
             ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64]
         lib.adio_loader_next.restype = ctypes.POINTER(ctypes.c_uint8)
         lib.adio_loader_next.argtypes = [ctypes.c_void_p]
+        lib.adio_loader_ready.restype = ctypes.c_uint64
+        lib.adio_loader_ready.argtypes = [ctypes.c_void_p]
         lib.adio_loader_release.argtypes = [ctypes.c_void_p,
                                             ctypes.POINTER(ctypes.c_uint8)]
         lib.adio_loader_free.argtypes = [ctypes.c_void_p]
@@ -178,15 +181,19 @@ class DevicePrefetcher:
         self._it = iter(source)
         self._sess = session
         self._q = collections.deque()
+        self._pushed = 0        # batches taken from the source
+        self._handed = 0        # batches handed to the consumer
         for _ in range(depth):
             self._push()
 
     def _push(self):
-        try:
-            host_batch = next(self._it)
-        except StopIteration:
-            return
-        self._q.append(self._sess._shard_batch(host_batch))
+        with telemetry.span("ad.prefetch.push", batch=self._pushed):
+            try:
+                host_batch = next(self._it)
+            except StopIteration:
+                return
+            self._q.append(self._sess._shard_batch(host_batch))
+            self._pushed += 1
 
     def __iter__(self):
         return self
@@ -194,9 +201,17 @@ class DevicePrefetcher:
     def __next__(self):
         if not self._q:
             raise StopIteration
-        out = self._q.popleft()
-        self._push()
-        return out
+        import jax
+
+        # ``ready``: whether the batch handed over has already arrived on
+        # the device (the first leaf's transfer; asking does not block)
+        leaves = jax.tree.leaves(self._q[0])
+        with telemetry.span("ad.prefetch.next", batch=self._handed,
+                            ready=int(leaves[0].is_ready()) if leaves else 1):
+            out = self._q.popleft()
+            self._handed += 1
+            self._push()
+            return out
 
 
 class BatchLoader:
@@ -241,16 +256,27 @@ class BatchLoader:
         return self
 
     def __next__(self):
-        if self._native:
-            lib = _load_native()
-            buf = lib.adio_loader_next(self._ld)
+        if not self._native:
+            with telemetry.span("ad.loader.next"):
+                return self._next_numpy()
+        lib = _load_native()
+        # ``ring``: assembled batches waiting in the C++ ring on arrival
+        with telemetry.span("ad.loader.next",
+                            ring=int(lib.adio_loader_ready(self._ld))):
+            with telemetry.span("ad.loader.wait"):
+                buf = lib.adio_loader_next(self._ld)
             if not buf:
                 raise StopIteration
-            n = self._batch * self._ds.record_bytes
-            out = np.ctypeslib.as_array(buf, shape=(n,)).view(self._ds.dtype)
-            out = out.reshape((self._batch,) + self._ds.record_shape).copy()
-            lib.adio_loader_release(self._ld, buf)
+            with telemetry.span("ad.loader.copy"):
+                n = self._batch * self._ds.record_bytes
+                out = np.ctypeslib.as_array(buf, shape=(n,)).view(
+                    self._ds.dtype)
+                out = out.reshape(
+                    (self._batch,) + self._ds.record_shape).copy()
+                lib.adio_loader_release(self._ld, buf)
             return out
+
+    def _next_numpy(self):
         # fallback path: true epoch permutation, reshuffled per epoch
         idx = np.empty(self._batch, np.int64)
         for i in range(self._batch):

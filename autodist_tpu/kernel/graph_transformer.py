@@ -1019,6 +1019,11 @@ class GraphTransformer:
         return x
 
     def _spmd_step(self, storage, opt_state, comp, mutable, step, rng, batch):
+        """One device's program of the step.  Each numbered stage sits in a
+        ``jax.named_scope`` (``ad.materialize``, ``ad.grad``, ``ad.sync``,
+        ``ad.clip``, ``ad.update``, ``ad.gather``): metadata only, the
+        compiled program is the same, and a profile's device operations
+        carry the scope in their ``op_name`` (PERF.md section 3)."""
         from autodist_tpu.parallel.collectives import axis_index
 
         axis = self.axis
@@ -1034,16 +1039,17 @@ class GraphTransformer:
         # buckets: 6b writes the fresh f32 shard straight back.
         s_leaves = self.treedef.flatten_up_to(storage)
         s_by_name = dict(zip(self.names, s_leaves))
-        bf16_full = {}
-        for b_pr in self.precision_buckets:
-            shards = {n: s_by_name[n].astype(jnp.bfloat16)
-                      for n in b_pr.var_names}
-            bf16_full.update(ar_sync.gather_bucket_params(
-                shards, b_pr, axis, self.hier_spec))
-        full_leaves = [bf16_full[n] if n in bf16_full
-                       else self._materialize(l, p)
-                       for n, l, p in zip(self.names, s_leaves, plans)]
-        full = self.treedef.unflatten(full_leaves)
+        with jax.named_scope("ad.materialize"):
+            bf16_full = {}
+            for b_pr in self.precision_buckets:
+                shards = {n: s_by_name[n].astype(jnp.bfloat16)
+                          for n in b_pr.var_names}
+                bf16_full.update(ar_sync.gather_bucket_params(
+                    shards, b_pr, axis, self.hier_spec))
+            full_leaves = [bf16_full[n] if n in bf16_full
+                           else self._materialize(l, p)
+                           for n, l, p in zip(self.names, s_leaves, plans)]
+            full = self.treedef.unflatten(full_leaves)
 
         # 2. local gradients (sparse lookups sync inside their backward)
         item = self.model_item
@@ -1135,394 +1141,410 @@ class GraphTransformer:
             n for b in scan_buckets for n in b.var_names)
         synced = comp_new_local = None
         with replica_axis_context(axis), seq_axis_context(self.seq_axis):
-            if A <= 1:
-                (loss, (maybe_mut, aux)), grads = run_vag(batch, 0, mutable)
-                grads = upcast_grads(grads)
-                new_mutable = maybe_mut if has_mutable else None
-            else:
-                # gradient accumulation: split the local batch into A
-                # microbatches, scan value_and_grad, average — one sync per
-                # step regardless of A (trades HBM for step latency).
-                # Mutable state (e.g. BN stats) threads THROUGH the scan so
-                # each microbatch updates the previous one's statistics.
-                def to_micro(x):
-                    if x.shape[0] % A:
-                        raise ValueError(
-                            f"Per-device batch {x.shape[0]} must divide by "
-                            f"accum_steps={A}")
-                    return x.reshape((A, x.shape[0] // A) + x.shape[1:])
-
-                micro = jax.tree.map(to_micro, batch)
-
-                def scan_body(carry, mb_i):
-                    mb, i = mb_i
-                    acc_l, acc_g, mut_cur = carry
-                    (l, (mut_next, aux_)), g = run_vag(mb, i, mut_cur)
-                    g = upcast_grads(g)
-                    if not has_mutable:
-                        mut_next = mut_cur
-                    return ((acc_l + l / A,
-                             jax.tree.map(lambda a, b: a + b / A, acc_g, g),
-                             mut_next),
-                            aux_)
-
-                def scan_body_overlap(carry, mb_i):
-                    mb, i = mb_i
-                    acc_l, acc_g, mut_cur, comp_cur, acc_synced = carry
-                    (l, (mut_next, aux_)), g = run_vag(mb, i, mut_cur)
-                    g = upcast_grads(g)
-                    if not has_mutable:
-                        mut_next = mut_cur
-                    g_leaves_ = self.treedef.flatten_up_to(g)
-                    g_names = dict(zip(self.names, g_leaves_))
-                    synced_i, comp_next = ar_sync.sync_overlapped(
-                        g_names, scan_buckets, comp_cur, axis,
-                        hier=self.hier_spec)
-                    acc_synced = {n: acc_synced[n] + synced_i[n] / A
-                                  for n in acc_synced}
-                    # bucketed vars accumulate ONLY their synced mean (the
-                    # raw-grad accumulator stays zero for them — no double
-                    # buffering of the bucketed gradient set)
-                    acc_leaves = self.treedef.flatten_up_to(acc_g)
-                    new_acc = [a if n in bucket_names else a + gl / A
-                               for n, a, gl in zip(self.names, acc_leaves,
-                                                   g_leaves_)]
-                    return ((acc_l + l / A,
-                             self.treedef.unflatten(new_acc),
-                             mut_next, comp_next, acc_synced),
-                            aux_)
-
-                # grads of bf16-master vars are upcast to f32 before
-                # accumulation, so their accumulators carry f32 too
-                zero_g = jax.tree.map(jnp.zeros_like, upcast_grads(full))
-                if overlap_in_scan:
-                    # sharded-update buckets sync into per-var (ss,) flat
-                    # SHARDS inside the scan; their accumulator carries the
-                    # shard shape, never the full gradient
-                    zero_synced = {
-                        n: (jnp.zeros((self._shard_of[n][1],),
-                                      jnp.float32 if n in prec_names
-                                      else leaf.dtype)
-                            if n in self._shard_of else jnp.zeros_like(leaf))
-                        for n, leaf in zip(self.names,
-                                           self.treedef.flatten_up_to(full))
-                        if n in bucket_names}
-                    comp_scan = {b.key: comp_local[b.key]
-                                 for b in scan_buckets}
-                    (loss, grads, mut_final, comp_scan_new, synced), auxs = (
-                        jax.lax.scan(
-                            scan_body_overlap,
-                            (jnp.zeros((), jnp.float32), zero_g, mutable,
-                             comp_scan, zero_synced),
-                            (micro, jnp.arange(A))))
+            with jax.named_scope("ad.grad"):
+                if A <= 1:
+                    (loss, (maybe_mut, aux)), grads = run_vag(batch, 0, mutable)
+                    grads = upcast_grads(grads)
+                    new_mutable = maybe_mut if has_mutable else None
                 else:
-                    (loss, grads, mut_final), auxs = jax.lax.scan(
-                        scan_body,
-                        (jnp.zeros((), jnp.float32), zero_g, mutable),
-                        (micro, jnp.arange(A)))
-                new_mutable = mut_final if has_mutable else None
-                aux = jax.tree.map(lambda x: jnp.mean(x, axis=0), auxs)
-            if has_mutable:
-                # cross-replica average of float statistics (e.g. BN stats)
-                new_mutable = jax.tree.map(
-                    lambda x: jax.lax.pmean(x, axis)
-                    if jnp.issubdtype(x.dtype, jnp.floating) else x,
-                    new_mutable)
+                    # gradient accumulation: split the local batch into A
+                    # microbatches, scan value_and_grad, average — one sync per
+                    # step regardless of A (trades HBM for step latency).
+                    # Mutable state (e.g. BN stats) threads THROUGH the scan so
+                    # each microbatch updates the previous one's statistics.
+                    def to_micro(x):
+                        if x.shape[0] % A:
+                            raise ValueError(
+                                f"Per-device batch {x.shape[0]} must divide by "
+                                f"accum_steps={A}")
+                        return x.reshape((A, x.shape[0] // A) + x.shape[1:])
 
-            # 3. bucketed allreduce for dense AR vars.  barrier: one sync
-            # point here, after the full backward; overlap (A<=1): per-
-            # bucket reverse-topological collectives the latency-hiding
-            # scheduler can pipeline; overlap (A>1): elementwise-codec
-            # buckets already synced inside the scan above, block-codec
-            # buckets sync here on the accumulated gradients.
-            g_leaves = self.treedef.flatten_up_to(grads)
-            g_by_name = dict(zip(self.names, g_leaves))
-            if synced is None:
-                if self.sync_schedule == "overlap":
-                    synced, comp_new_local = ar_sync.sync_overlapped(
-                        g_by_name, self.buckets, comp_local, axis,
+                    micro = jax.tree.map(to_micro, batch)
+
+                    def scan_body(carry, mb_i):
+                        mb, i = mb_i
+                        acc_l, acc_g, mut_cur = carry
+                        (l, (mut_next, aux_)), g = run_vag(mb, i, mut_cur)
+                        g = upcast_grads(g)
+                        if not has_mutable:
+                            mut_next = mut_cur
+                        return ((acc_l + l / A,
+                                 jax.tree.map(lambda a, b: a + b / A, acc_g, g),
+                                 mut_next),
+                                aux_)
+
+                    def scan_body_overlap(carry, mb_i):
+                        mb, i = mb_i
+                        acc_l, acc_g, mut_cur, comp_cur, acc_synced = carry
+                        (l, (mut_next, aux_)), g = run_vag(mb, i, mut_cur)
+                        g = upcast_grads(g)
+                        if not has_mutable:
+                            mut_next = mut_cur
+                        g_leaves_ = self.treedef.flatten_up_to(g)
+                        g_names = dict(zip(self.names, g_leaves_))
+                        # nested under ad.grad: a reader that takes the
+                        # innermost scope charges it to sync
+                        with jax.named_scope("ad.sync"):
+                            synced_i, comp_next = ar_sync.sync_overlapped(
+                                g_names, scan_buckets, comp_cur, axis,
+                                hier=self.hier_spec)
+                        acc_synced = {n: acc_synced[n] + synced_i[n] / A
+                                      for n in acc_synced}
+                        # bucketed vars accumulate ONLY their synced mean (the
+                        # raw-grad accumulator stays zero for them — no double
+                        # buffering of the bucketed gradient set)
+                        acc_leaves = self.treedef.flatten_up_to(acc_g)
+                        new_acc = [a if n in bucket_names else a + gl / A
+                                   for n, a, gl in zip(self.names, acc_leaves,
+                                                       g_leaves_)]
+                        return ((acc_l + l / A,
+                                 self.treedef.unflatten(new_acc),
+                                 mut_next, comp_next, acc_synced),
+                                aux_)
+
+                    # grads of bf16-master vars are upcast to f32 before
+                    # accumulation, so their accumulators carry f32 too
+                    zero_g = jax.tree.map(jnp.zeros_like, upcast_grads(full))
+                    if overlap_in_scan:
+                        # sharded-update buckets sync into per-var (ss,) flat
+                        # SHARDS inside the scan; their accumulator carries the
+                        # shard shape, never the full gradient
+                        zero_synced = {
+                            n: (jnp.zeros((self._shard_of[n][1],),
+                                          jnp.float32 if n in prec_names
+                                          else leaf.dtype)
+                                if n in self._shard_of else jnp.zeros_like(leaf))
+                            for n, leaf in zip(self.names,
+                                               self.treedef.flatten_up_to(full))
+                            if n in bucket_names}
+                        comp_scan = {b.key: comp_local[b.key]
+                                     for b in scan_buckets}
+                        (loss, grads, mut_final, comp_scan_new, synced), auxs = (
+                            jax.lax.scan(
+                                scan_body_overlap,
+                                (jnp.zeros((), jnp.float32), zero_g, mutable,
+                                 comp_scan, zero_synced),
+                                (micro, jnp.arange(A))))
+                    else:
+                        (loss, grads, mut_final), auxs = jax.lax.scan(
+                            scan_body,
+                            (jnp.zeros((), jnp.float32), zero_g, mutable),
+                            (micro, jnp.arange(A)))
+                    new_mutable = mut_final if has_mutable else None
+                    aux = jax.tree.map(lambda x: jnp.mean(x, axis=0), auxs)
+            with jax.named_scope("ad.sync"):
+                if has_mutable:
+                    # cross-replica average of float statistics (e.g. BN stats)
+                    new_mutable = jax.tree.map(
+                        lambda x: jax.lax.pmean(x, axis)
+                        if jnp.issubdtype(x.dtype, jnp.floating) else x,
+                        new_mutable)
+
+                # 3. bucketed allreduce for dense AR vars.  barrier: one sync
+                # point here, after the full backward; overlap (A<=1): per-
+                # bucket reverse-topological collectives the latency-hiding
+                # scheduler can pipeline; overlap (A>1): elementwise-codec
+                # buckets already synced inside the scan above, block-codec
+                # buckets sync here on the accumulated gradients.
+                g_leaves = self.treedef.flatten_up_to(grads)
+                g_by_name = dict(zip(self.names, g_leaves))
+                if synced is None:
+                    if self.sync_schedule == "overlap":
+                        synced, comp_new_local = ar_sync.sync_overlapped(
+                            g_by_name, self.buckets, comp_local, axis,
+                            hier=self.hier_spec)
+                    elif self.hier_spec is not None:
+                        # barrier schedule on a factored mesh: the two-level
+                        # entry (FLAT buckets inside it still reduce flat)
+                        synced, comp_new_local = ar_sync.sync_hierarchical(
+                            g_by_name, self.buckets, comp_local, axis,
+                            hier=self.hier_spec)
+                    else:
+                        synced, comp_new_local = ar_sync.sync_bucketed(
+                            g_by_name, self.buckets, comp_local, axis)
+                elif post_buckets:
+                    synced_post, comp_post = ar_sync.sync_overlapped(
+                        g_by_name, post_buckets, comp_local, axis,
                         hier=self.hier_spec)
-                elif self.hier_spec is not None:
-                    # barrier schedule on a factored mesh: the two-level
-                    # entry (FLAT buckets inside it still reduce flat)
-                    synced, comp_new_local = ar_sync.sync_hierarchical(
-                        g_by_name, self.buckets, comp_local, axis,
-                        hier=self.hier_spec)
+                    synced = {**synced, **synced_post}
+                    comp_new_local = {**comp_post, **comp_scan_new}
                 else:
-                    synced, comp_new_local = ar_sync.sync_bucketed(
-                        g_by_name, self.buckets, comp_local, axis)
-            elif post_buckets:
-                synced_post, comp_post = ar_sync.sync_overlapped(
-                    g_by_name, post_buckets, comp_local, axis,
-                    hier=self.hier_spec)
-                synced = {**synced, **synced_post}
-                comp_new_local = {**comp_post, **comp_scan_new}
-            else:
-                comp_new_local = {**comp_local, **comp_scan_new}
-        comp_new = {k: jax.tree.map(lambda a: a[None], v)
-                    for k, v in comp_new_local.items()}
+                    comp_new_local = {**comp_local, **comp_scan_new}
+        with jax.named_scope("ad.sync"):
+            comp_new = {k: jax.tree.map(lambda a: a[None], v)
+                        for k, v in comp_new_local.items()}
 
-        # 4a. fused reduce-scatter for the dense PS family: every PS var's
-        # flat padding reshapes to (R_ps, shard); concatenating along dim 1
-        # lets ONE psum_scatter per (dtype, ps_axes) group deliver every
-        # device exactly its row — its shard of every variable — instead of
-        # a collective per variable (hundreds, for transformer-sized
-        # models).  With a mesh-axis SUBSET (e.g. ici of a dcn x ici mesh)
-        # the scatter stays inside the subset and only the 1/R_ps-sized
-        # shards cross the remaining axes via psum — DCN sees shard-sized
-        # traffic, never full gradients (the reference shapes this with
-        # load-balanced PS placement, ``ps_synchronizer.py:635-656``).
-        def _ps_shard_len(plan):
-            r = self._R_for(plan)
-            n = int(np.prod(plan.shape)) if plan.shape else 1
-            return (-(-n // r) * r) // r
+            # 4a. fused reduce-scatter for the dense PS family: every PS var's
+            # flat padding reshapes to (R_ps, shard); concatenating along dim 1
+            # lets ONE psum_scatter per (dtype, ps_axes) group deliver every
+            # device exactly its row — its shard of every variable — instead of
+            # a collective per variable (hundreds, for transformer-sized
+            # models).  With a mesh-axis SUBSET (e.g. ici of a dcn x ici mesh)
+            # the scatter stays inside the subset and only the 1/R_ps-sized
+            # shards cross the remaining axes via psum — DCN sees shard-sized
+            # traffic, never full gradients (the reference shapes this with
+            # load-balanced PS placement, ``ps_synchronizer.py:635-656``).
+            def _ps_shard_len(plan):
+                r = self._R_for(plan)
+                n = int(np.prod(plan.shape)) if plan.shape else 1
+                return (-(-n // r) * r) // r
 
-        ps_fused = self.ps_groups
-        ps_grad_shards = {}
-        for (dtype, _axes_key), names_d in ps_fused.items():
-            plan0 = self.plans[names_d[0]]
-            ps_axis = self._ps_axis(plan0)
-            other = self._ps_other_axes(plan0)
-            r_ps = self._R_for(plan0)
-            mats = []
-            for name in names_d:
-                plan = self.plans[name]
-                g = g_by_name[name]
-                ss = _ps_shard_len(plan)
-                flatg = jnp.zeros((ss * r_ps,), g.dtype).at[:g.size].set(g.ravel())
-                mats.append(flatg.reshape(r_ps, ss))
-            bucket = jnp.concatenate(mats, axis=1) if len(mats) > 1 else mats[0]
-            red = jax.lax.psum_scatter(bucket, ps_axis, scatter_dimension=0,
-                                       tiled=True)            # (1, S) -> (S,)
-            if other:  # cross-slice sum of the already-scattered shards
-                red = jax.lax.psum(red, other)
-            red = red.reshape(-1) / R
-            off = 0
-            for name in names_d:
-                ss = _ps_shard_len(self.plans[name])
-                ps_grad_shards[name] = jax.lax.dynamic_slice_in_dim(red, off, ss)
-                off += ss
+            ps_fused = self.ps_groups
+            ps_grad_shards = {}
+            for (dtype, _axes_key), names_d in ps_fused.items():
+                plan0 = self.plans[names_d[0]]
+                ps_axis = self._ps_axis(plan0)
+                other = self._ps_other_axes(plan0)
+                r_ps = self._R_for(plan0)
+                mats = []
+                for name in names_d:
+                    plan = self.plans[name]
+                    g = g_by_name[name]
+                    ss = _ps_shard_len(plan)
+                    flatg = jnp.zeros((ss * r_ps,), g.dtype).at[:g.size].set(g.ravel())
+                    mats.append(flatg.reshape(r_ps, ss))
+                bucket = jnp.concatenate(mats, axis=1) if len(mats) > 1 else mats[0]
+                red = jax.lax.psum_scatter(bucket, ps_axis, scatter_dimension=0,
+                                           tiled=True)            # (1, S) -> (S,)
+                if other:  # cross-slice sum of the already-scattered shards
+                    red = jax.lax.psum(red, other)
+                red = red.reshape(-1) / R
+                off = 0
+                for name in names_d:
+                    ss = _ps_shard_len(self.plans[name])
+                    ps_grad_shards[name] = jax.lax.dynamic_slice_in_dim(red, off, ss)
+                    off += ss
 
-        # 4a'. fused pmean of CUSTOM (tensor-parallel) grads: one collective
-        # per (spec, dtype) group over the data axes instead of one per var
-        custom_synced = {}
-        for (_, _), (names_c, _axes) in self.custom_groups.items():
-            flats = [jnp.ravel(g_by_name[n]) for n in names_c]
-            buf = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-            buf = jax.lax.pmean(buf, axis)
-            off = 0
-            for n in names_c:
-                gshape = g_by_name[n].shape
-                size = g_by_name[n].size
-                custom_synced[n] = jax.lax.dynamic_slice_in_dim(
-                    buf, off, size).reshape(gshape)
-                off += size
+            # 4a'. fused pmean of CUSTOM (tensor-parallel) grads: one collective
+            # per (spec, dtype) group over the data axes instead of one per var
+            custom_synced = {}
+            for (_, _), (names_c, _axes) in self.custom_groups.items():
+                flats = [jnp.ravel(g_by_name[n]) for n in names_c]
+                buf = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
+                buf = jax.lax.pmean(buf, axis)
+                off = 0
+                for n in names_c:
+                    gshape = g_by_name[n].shape
+                    size = g_by_name[n].size
+                    custom_synced[n] = jax.lax.dynamic_slice_in_dim(
+                        buf, off, size).reshape(gshape)
+                    off += size
 
         # 4b. update-space params/grads per variable.  Sharded-update AR
         # vars slice their flat padded 1/R param shard at the row the
         # bucket's reduce-scatter assigned this device (ici-major under
         # the fused TWO_LEVEL schedule).
-        shard_rows = {b_sh.key: ar_sync.shard_index(b_sh, axis,
-                                                    self.hier_spec)
-                      for b_sh in self.sharded_buckets}
-        u_params, u_grads = [], []
-        for name, plan, s_leaf in zip(self.names, plans, s_leaves):
-            g = g_by_name[name]
-            if plan.placement == Placement.CUSTOM:
-                # tensor-parallel block: replicated over the data axes,
-                # sharded over model axes -> averaged over data axes (fused)
-                u_params.append(s_leaf)
-                u_grads.append(custom_synced[name])
-            elif plan.placement == Placement.SHARDED:
-                if plan.sparse and plan.partition_axis == 0:
-                    # ShardedTable lookup: the backward already produced the
-                    # local block's mean gradient (update space) directly
-                    from autodist_tpu.ops.sparse import ShardedTable
+        with jax.named_scope("ad.update"):
+            shard_rows = {b_sh.key: ar_sync.shard_index(b_sh, axis,
+                                                        self.hier_spec)
+                          for b_sh in self.sharded_buckets}
+            u_params, u_grads = [], []
+            for name, plan, s_leaf in zip(self.names, plans, s_leaves):
+                g = g_by_name[name]
+                if plan.placement == Placement.CUSTOM:
+                    # tensor-parallel block: replicated over the data axes,
+                    # sharded over model axes -> averaged over data axes (fused)
+                    u_params.append(s_leaf)
+                    u_grads.append(custom_synced[name])
+                elif plan.placement == Placement.SHARDED:
+                    if plan.sparse and plan.partition_axis == 0:
+                        # ShardedTable lookup: the backward already produced the
+                        # local block's mean gradient (update space) directly
+                        from autodist_tpu.ops.sparse import ShardedTable
 
-                    assert isinstance(g, ShardedTable)
+                        assert isinstance(g, ShardedTable)
+                        u_params.append(s_leaf)
+                        u_grads.append(g.block)
+                    elif plan.sparse:
+                        # non-dim0 shard of a sparse var: pre-synced dense mean
+                        gp = self._pad_axis(g, plan)
+                        block = plan.padded_dim // R
+                        ug = jax.lax.dynamic_slice_in_dim(
+                            gp, my * block, block, axis=plan.partition_axis)
+                        u_params.append(s_leaf)
+                        u_grads.append(ug)
+                    else:
+                        with jax.named_scope("ad.sync"):
+                            gp = self._pad_axis(g, plan)
+                            ug = jax.lax.psum_scatter(
+                                gp, axis,
+                                scatter_dimension=plan.partition_axis,
+                                tiled=True) / R
+                        u_params.append(s_leaf)
+                        u_grads.append(ug)
+                elif plan.placement == Placement.DIVERGENT:
+                    # local update either way: dense grads are local by nature,
+                    # sparse grads arrive pre-synced (a harmless strengthening)
                     u_params.append(s_leaf)
-                    u_grads.append(g.block)
-                elif plan.sparse:
-                    # non-dim0 shard of a sparse var: pre-synced dense mean
-                    gp = self._pad_axis(g, plan)
-                    block = plan.padded_dim // R
-                    ug = jax.lax.dynamic_slice_in_dim(
-                        gp, my * block, block, axis=plan.partition_axis)
-                    u_params.append(s_leaf)
-                    u_grads.append(ug)
-                else:
-                    gp = self._pad_axis(g, plan)
-                    ug = jax.lax.psum_scatter(
-                        gp, axis, scatter_dimension=plan.partition_axis,
-                        tiled=True) / R
-                    u_params.append(s_leaf)
-                    u_grads.append(ug)
-            elif plan.placement == Placement.DIVERGENT:
-                # local update either way: dense grads are local by nature,
-                # sparse grads arrive pre-synced (a harmless strengthening)
-                u_params.append(s_leaf)
-                u_grads.append(g[None])
-            elif plan.sync == SyncKind.PS:
-                r_ps = self._R_for(plan)
-                my_ps = my if r_ps == R else axis_index(self._ps_axis(plan))
-                n = int(np.prod(plan.shape)) if plan.shape else 1
-                ss = _ps_shard_len(plan)
-                npad = ss * r_ps
-                flatp = jnp.zeros((npad,), s_leaf.dtype).at[:n].set(s_leaf.ravel())
-                u_params.append(jax.lax.dynamic_slice_in_dim(flatp, my_ps * ss, ss))
-                if plan.sparse:
-                    # sparse grads arrive pre-synced (full-mesh mean), so
-                    # the subset shard is identical across the other axes
-                    flatg = jnp.zeros((npad,), g.dtype).at[:n].set(g.ravel())
-                    ug = jax.lax.dynamic_slice_in_dim(flatg, my_ps * ss, ss)
-                else:
-                    ug = ps_grad_shards[name]
-                u_grads.append(ug)
-            elif name in self._shard_of:
-                # ZeRO sharded update: the bucket scatter already delivered
-                # this device's (ss,) gradient shard in `synced`; pair it
-                # with the matching flat param shard
-                b_sh, ss = self._shard_of[name]
-                if b_sh.precision:
-                    # bf16-master: s_leaf IS this device's flat f32
-                    # master shard (storage == update space)
-                    u_params.append(s_leaf)
-                else:
+                    u_grads.append(g[None])
+                elif plan.sync == SyncKind.PS:
+                    r_ps = self._R_for(plan)
+                    my_ps = my if r_ps == R else axis_index(self._ps_axis(plan))
                     n = int(np.prod(plan.shape)) if plan.shape else 1
-                    flatp = jnp.zeros((ss * b_sh.num_shards,),
-                                      s_leaf.dtype).at[:n].set(s_leaf.ravel())
-                    u_params.append(jax.lax.dynamic_slice_in_dim(
-                        flatp, shard_rows[b_sh.key] * ss, ss))
-                u_grads.append(synced[name])
-            else:  # REPLICATED + AllReduce
-                u_params.append(s_leaf)
-                u_grads.append(synced.get(name, g))  # sparse: pre-synced
+                    ss = _ps_shard_len(plan)
+                    npad = ss * r_ps
+                    flatp = jnp.zeros((npad,), s_leaf.dtype).at[:n].set(s_leaf.ravel())
+                    u_params.append(jax.lax.dynamic_slice_in_dim(flatp, my_ps * ss, ss))
+                    if plan.sparse:
+                        # sparse grads arrive pre-synced (full-mesh mean), so
+                        # the subset shard is identical across the other axes
+                        flatg = jnp.zeros((npad,), g.dtype).at[:n].set(g.ravel())
+                        ug = jax.lax.dynamic_slice_in_dim(flatg, my_ps * ss, ss)
+                    else:
+                        ug = ps_grad_shards[name]
+                    u_grads.append(ug)
+                elif name in self._shard_of:
+                    # ZeRO sharded update: the bucket scatter already delivered
+                    # this device's (ss,) gradient shard in `synced`; pair it
+                    # with the matching flat param shard
+                    b_sh, ss = self._shard_of[name]
+                    if b_sh.precision:
+                        # bf16-master: s_leaf IS this device's flat f32
+                        # master shard (storage == update space)
+                        u_params.append(s_leaf)
+                    else:
+                        n = int(np.prod(plan.shape)) if plan.shape else 1
+                        flatp = jnp.zeros((ss * b_sh.num_shards,),
+                                          s_leaf.dtype).at[:n].set(s_leaf.ravel())
+                        u_params.append(jax.lax.dynamic_slice_in_dim(
+                            flatp, shard_rows[b_sh.key] * ss, ss))
+                    u_grads.append(synced[name])
+                else:  # REPLICATED + AllReduce
+                    u_params.append(s_leaf)
+                    u_grads.append(synced.get(name, g))  # sparse: pre-synced
 
         # 4c. mesh-aware global-norm clipping: optax.clip_by_global_norm
         # would see per-shard norms for PS/SHARDED update spaces; here the
         # TRUE global norm is assembled from per-leaf contributions (sharded
         # leaves psum their squared sums; replicated leaves count once)
-        grad_norm = None
-        if self.clip_global_norm is not None:
-            sq = jnp.zeros((), jnp.float32)
-            sq_sharded = jnp.zeros((), jnp.float32)
-            # CUSTOM blocks are disjoint only over the axes their spec
-            # names; psum per spec-axis set (a block replicated over an
-            # unnamed model axis must be counted once)
-            sq_custom = {}  # frozenset(axes) -> scalar
-            for plan, ug in zip(plans, u_grads):
-                s = jnp.sum(jnp.square(ug.astype(jnp.float32)))
-                if plan.placement == Placement.CUSTOM:
-                    axes_key = next(a for (_, _), (ns, a)
-                                    in self.custom_groups.items()
-                                    if plan.name in ns)
-                    sq_custom[axes_key] = sq_custom.get(
-                        axes_key, jnp.zeros((), jnp.float32)) + s
-                elif plan.placement == Placement.DIVERGENT:
-                    # local (or pre-synced sparse) gradients: count each
-                    # device's copy once by averaging, not summing, over the
-                    # axis — keeps the norm comparable to single-device
-                    sq_sharded = sq_sharded + s / R
-                elif (plan.placement == Placement.SHARDED
-                        or part.flat_shard_update(plan)):
-                    # disjoint shards (PS flat shards, sharded-update AR
-                    # shards, SHARDED storage): full-axis psum = true sum.
-                    # A subset-axis PS shard is replicated over the other
-                    # data axes, so pre-divide by that multiplicity.
-                    mult = R // self._R_for(plan)
-                    sq_sharded = sq_sharded + (s / mult if mult > 1 else s)
-                else:
-                    sq = sq + s
-            total = sq + jax.lax.psum(sq_sharded, axis)
-            for axes_key, s in sq_custom.items():
-                total = (total + jax.lax.psum(s, tuple(sorted(axes_key)))
-                         if axes_key else total + s)
-            grad_norm = jnp.sqrt(total)
-            scale = jnp.minimum(
-                1.0, self.clip_global_norm / jnp.maximum(grad_norm, 1e-12))
-            u_grads = [g * scale.astype(g.dtype) for g in u_grads]
+        with jax.named_scope("ad.clip"):
+            grad_norm = None
+            if self.clip_global_norm is not None:
+                sq = jnp.zeros((), jnp.float32)
+                sq_sharded = jnp.zeros((), jnp.float32)
+                # CUSTOM blocks are disjoint only over the axes their spec
+                # names; psum per spec-axis set (a block replicated over an
+                # unnamed model axis must be counted once)
+                sq_custom = {}  # frozenset(axes) -> scalar
+                for plan, ug in zip(plans, u_grads):
+                    s = jnp.sum(jnp.square(ug.astype(jnp.float32)))
+                    if plan.placement == Placement.CUSTOM:
+                        axes_key = next(a for (_, _), (ns, a)
+                                        in self.custom_groups.items()
+                                        if plan.name in ns)
+                        sq_custom[axes_key] = sq_custom.get(
+                            axes_key, jnp.zeros((), jnp.float32)) + s
+                    elif plan.placement == Placement.DIVERGENT:
+                        # local (or pre-synced sparse) gradients: count each
+                        # device's copy once by averaging, not summing, over the
+                        # axis — keeps the norm comparable to single-device
+                        sq_sharded = sq_sharded + s / R
+                    elif (plan.placement == Placement.SHARDED
+                            or part.flat_shard_update(plan)):
+                        # disjoint shards (PS flat shards, sharded-update AR
+                        # shards, SHARDED storage): full-axis psum = true sum.
+                        # A subset-axis PS shard is replicated over the other
+                        # data axes, so pre-divide by that multiplicity.
+                        mult = R // self._R_for(plan)
+                        sq_sharded = sq_sharded + (s / mult if mult > 1 else s)
+                    else:
+                        sq = sq + s
+                total = sq + jax.lax.psum(sq_sharded, axis)
+                for axes_key, s in sq_custom.items():
+                    total = (total + jax.lax.psum(s, tuple(sorted(axes_key)))
+                             if axes_key else total + s)
+                grad_norm = jnp.sqrt(total)
+                scale = jnp.minimum(
+                    1.0, self.clip_global_norm / jnp.maximum(grad_norm, 1e-12))
+                u_grads = [g * scale.astype(g.dtype) for g in u_grads]
 
-        u_params_t = self.treedef.unflatten(u_params)
-        u_grads_t = self.treedef.unflatten(u_grads)
+        with jax.named_scope("ad.update"):
+            u_params_t = self.treedef.unflatten(u_params)
+            u_grads_t = self.treedef.unflatten(u_grads)
 
-        # 5. optimizer (elementwise transforms shard transparently)
-        updates, opt_new = self.model_item.optimizer.update(
-            u_grads_t, opt_state, u_params_t)
-        new_u = optax.apply_updates(u_params_t, updates)
-        new_u_leaves = self.treedef.flatten_up_to(new_u)
+            # 5. optimizer (elementwise transforms shard transparently)
+            updates, opt_new = self.model_item.optimizer.update(
+                u_grads_t, opt_state, u_params_t)
+            new_u = optax.apply_updates(u_params_t, updates)
+            new_u_leaves = self.treedef.flatten_up_to(new_u)
 
-        # 6a. fused all-gather of updated PS shards (mirror of 4a): one
-        # all_gather per (dtype, ps_axes) group rebuilds every PS
-        # variable's full value — over the subset axis only; shards are
-        # identical across the other axes (same grads -> same update), so
-        # no cross-slice gather is needed at all.
-        new_by_name = dict(zip(self.names, new_u_leaves))
+        with jax.named_scope("ad.gather"):
+            # 6a. fused all-gather of updated PS shards (mirror of 4a): one
+            # all_gather per (dtype, ps_axes) group rebuilds every PS
+            # variable's full value — over the subset axis only; shards are
+            # identical across the other axes (same grads -> same update), so
+            # no cross-slice gather is needed at all.
+            new_by_name = dict(zip(self.names, new_u_leaves))
 
-        # 6a'. fused per-bucket all-gather of FRESH PARAMS for the ZeRO
-        # sharded-update buckets — the collective that replaces the
-        # replicated schedule's gradient all-gather (under TWO_LEVEL it
-        # retraces the scatter hops in reverse: DCN shard gather, then
-        # ICI gather).  One gather per bucket, each depending only on its
-        # own bucket's updated shards, so under schedule="overlap" the
-        # latency-hiding scheduler pipelines bucket i's gather behind
-        # bucket i+1's still-running shard update.
-        sharded_full = {}
-        for b_sh in self.sharded_buckets:
-            if b_sh.precision:
-                # bf16-master: no post-update gather — the fresh f32
-                # shard IS the new storage (6b falls through to `nu`);
-                # the NEXT step's entry gather rebuilds the bf16 copy
-                continue
-            sharded_full.update(ar_sync.gather_bucket_params(
-                new_by_name, b_sh, axis, self.hier_spec))
+            # 6a'. fused per-bucket all-gather of FRESH PARAMS for the ZeRO
+            # sharded-update buckets — the collective that replaces the
+            # replicated schedule's gradient all-gather (under TWO_LEVEL it
+            # retraces the scatter hops in reverse: DCN shard gather, then
+            # ICI gather).  One gather per bucket, each depending only on its
+            # own bucket's updated shards, so under schedule="overlap" the
+            # latency-hiding scheduler pipelines bucket i's gather behind
+            # bucket i+1's still-running shard update.
+            sharded_full = {}
+            for b_sh in self.sharded_buckets:
+                if b_sh.precision:
+                    # bf16-master: no post-update gather — the fresh f32
+                    # shard IS the new storage (6b falls through to `nu`);
+                    # the NEXT step's entry gather rebuilds the bf16 copy
+                    continue
+                sharded_full.update(ar_sync.gather_bucket_params(
+                    new_by_name, b_sh, axis, self.hier_spec))
 
-        ps_full = {}
-        for (dtype, _axes_key), names_d in ps_fused.items():
-            plan0 = self.plans[names_d[0]]
-            ps_axis = self._ps_axis(plan0)
-            r_ps = self._R_for(plan0)
-            cat = (jnp.concatenate([new_by_name[n] for n in names_d])
-                   if len(names_d) > 1 else new_by_name[names_d[0]])
-            S = cat.shape[0]
-            gathered = jax.lax.all_gather(cat, ps_axis, axis=0, tiled=True)
-            gathered = gathered.reshape(r_ps, S)
-            off = 0
-            for name in names_d:
-                plan = self.plans[name]
-                ss = _ps_shard_len(plan)
-                n = int(np.prod(plan.shape)) if plan.shape else 1
-                cols = jax.lax.dynamic_slice_in_dim(gathered, off, ss, axis=1)
-                ps_full[name] = jnp.reshape(cols.reshape(-1)[:n], plan.shape)
-                off += ss
+            ps_full = {}
+            for (dtype, _axes_key), names_d in ps_fused.items():
+                plan0 = self.plans[names_d[0]]
+                ps_axis = self._ps_axis(plan0)
+                r_ps = self._R_for(plan0)
+                cat = (jnp.concatenate([new_by_name[n] for n in names_d])
+                       if len(names_d) > 1 else new_by_name[names_d[0]])
+                S = cat.shape[0]
+                gathered = jax.lax.all_gather(cat, ps_axis, axis=0, tiled=True)
+                gathered = gathered.reshape(r_ps, S)
+                off = 0
+                for name in names_d:
+                    plan = self.plans[name]
+                    ss = _ps_shard_len(plan)
+                    n = int(np.prod(plan.shape)) if plan.shape else 1
+                    cols = jax.lax.dynamic_slice_in_dim(gathered, off, ss, axis=1)
+                    ps_full[name] = jnp.reshape(cols.reshape(-1)[:n], plan.shape)
+                    off += ss
 
         # 6b. write back to storage
-        new_storage = []
-        for name, plan, nu, s_leaf in zip(self.names, plans, new_u_leaves, s_leaves):
-            if plan.placement in (Placement.SHARDED, Placement.CUSTOM):
-                new_storage.append(nu)
-            elif plan.placement == Placement.DIVERGENT:
-                # lax.cond skips the collective entirely on non-averaging
-                # steps (the whole point of staleness); the predicate is
-                # replicated so all devices take the same branch
-                period = plan.sync_period
-                do_avg = jnp.equal(jnp.mod(step + 1, period), 0)
-                new_storage.append(jax.lax.cond(
-                    do_avg,
-                    lambda x: jax.lax.pmean(x, axis),
-                    lambda x: x,
-                    nu))
-            elif plan.sync == SyncKind.PS:
-                if name in ps_full:
-                    new_storage.append(ps_full[name])
-                else:  # sparse PS var: gather its own shard ring
-                    n = int(np.prod(plan.shape)) if plan.shape else 1
-                    flat = jax.lax.all_gather(nu, self._ps_axis(plan),
-                                              axis=0, tiled=True)
-                    new_storage.append(jnp.reshape(flat[:n], plan.shape))
-            elif name in sharded_full:  # sharded-update AR var
-                new_storage.append(sharded_full[name])
-            else:
-                new_storage.append(nu)
+        with jax.named_scope("ad.update"):
+            new_storage = []
+            for name, plan, nu, s_leaf in zip(self.names, plans, new_u_leaves, s_leaves):
+                if plan.placement in (Placement.SHARDED, Placement.CUSTOM):
+                    new_storage.append(nu)
+                elif plan.placement == Placement.DIVERGENT:
+                    # lax.cond skips the collective entirely on non-averaging
+                    # steps (the whole point of staleness); the predicate is
+                    # replicated so all devices take the same branch
+                    period = plan.sync_period
+                    do_avg = jnp.equal(jnp.mod(step + 1, period), 0)
+                    with jax.named_scope("ad.sync"):
+                        new_storage.append(jax.lax.cond(
+                            do_avg,
+                            lambda x: jax.lax.pmean(x, axis),
+                            lambda x: x,
+                            nu))
+                elif plan.sync == SyncKind.PS:
+                    if name in ps_full:
+                        new_storage.append(ps_full[name])
+                    else:  # sparse PS var: gather its own shard ring
+                        n = int(np.prod(plan.shape)) if plan.shape else 1
+                        with jax.named_scope("ad.gather"):
+                            flat = jax.lax.all_gather(
+                                nu, self._ps_axis(plan), axis=0, tiled=True)
+                            new_storage.append(
+                                jnp.reshape(flat[:n], plan.shape))
+                elif name in sharded_full:  # sharded-update AR var
+                    new_storage.append(sharded_full[name])
+                else:
+                    new_storage.append(nu)
 
         metrics = {"loss": jax.lax.pmean(loss, axis), "step": step + 1}
         if grad_norm is not None:

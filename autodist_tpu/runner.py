@@ -17,6 +17,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from autodist_tpu import telemetry as _telemetry
 from autodist_tpu.const import BATCH_MASK_KEY
 from autodist_tpu.kernel.partitioner import Placement
 from autodist_tpu.utils import logging
@@ -109,8 +110,6 @@ class DistributedSession:
         # per process (AUTODIST_TELEMETRY=1 / telemetry.enable()) or per
         # session (telemetry=True or a prebuilt SessionTelemetry).
         if telemetry is None:
-            from autodist_tpu import telemetry as _telemetry
-
             telemetry = _telemetry.enabled()
         if telemetry is True:
             from autodist_tpu.telemetry.session import SessionTelemetry
@@ -119,6 +118,15 @@ class DistributedSession:
                 transformer, mem_fn=self.memory_stats)
         else:
             self._telemetry = telemetry or None
+        # the program's host spans (``ad.*``, PERF.md section 3): always a
+        # profiler annotation, and the session's registry record besides
+        # when it is instrumented
+        self._span = (self._telemetry.span if self._telemetry is not None
+                      else _telemetry.span)
+        # calls of ``run`` so far (``step_num`` of ``ad.run``; ``self.step``
+        # would fetch from the device) and compiled variants of the step
+        self._dispatches = 0
+        self._variants = 0
 
     # -- feeds (reference remapper._remap_feed analog) ---------------------
 
@@ -239,6 +247,10 @@ class DistributedSession:
         return self._pad_to(batch, B, target), pad
 
     def _shard_batch(self, batch, _prepadded=False):
+        with self._span("ad.shard_batch"):
+            return self._shard_batch_impl(batch, _prepadded)
+
+    def _shard_batch_impl(self, batch, _prepadded):
         spec = tuple(self._batch_spec)
         if self._batch_mask and not _prepadded:
             # (_prepadded: predict() already padded — skip, in particular
@@ -351,57 +363,63 @@ class DistributedSession:
         ``<trace_dir>/step_<n>/`` (namespaced so repeated traced runs
         keep every capture) and the metrics carry the capture path under
         ``"trace_dir"``.
+
+        Without telemetry this is the hot path: one async dispatch, no
+        host sync (unless tracing), no file I/O.  With it (``tel``), the
+        step's wall time is closed at a real sync point and the watchdog
+        may arm a capture.  Both carry the same ``ad.*`` spans, inert
+        while no profile is taken.
         """
-        if self._telemetry is None:
-            return self._run_plain(batch, trace_dir)
-        return self._run_instrumented(batch, trace_dir)
-
-    def _run_plain(self, batch, trace_dir):
-        """The uninstrumented hot path — exactly one async dispatch, no
-        telemetry code, no host sync (unless tracing)."""
-        gbatch = self._shard_batch(batch)
-        self._pre_step(gbatch)
-        if trace_dir:
-            path = self._trace_step_dir(trace_dir, self.step)
-            with jax.profiler.trace(path):
-                self.state, metrics = self._step(self.state, gbatch)
-                jax.block_until_ready(metrics)
-            metrics = dict(metrics)
-            metrics["trace_dir"] = path
-            return metrics
-        self.state, metrics = self._step(self.state, gbatch)
-        return metrics
-
-    def _run_instrumented(self, batch, trace_dir):
-        """Telemetry path: host spans around batch staging, per-step wall
-        time closed at a real sync point, watchdog auto-capture."""
         tel = self._telemetry
-        capture_dir = None
-        with tel.span("shard_batch"):
+        with jax.profiler.StepTraceAnnotation(
+                "ad.run", step_num=self._dispatches) as run_span:
             gbatch = self._shard_batch(batch)
-        with tel.span("pre_step"):
-            self._pre_step(gbatch)
-        path = None
-        if trace_dir:
-            path = self._trace_step_dir(trace_dir, self.step)
-        else:
-            capture_dir = tel.arm_capture_dir()
-            if capture_dir:
-                os.makedirs(capture_dir, exist_ok=True)
-                path = capture_dir
-        tel.step_started()
-        if path:
-            with jax.profiler.trace(path):
-                self.state, metrics = self._step(self.state, gbatch)
-                jax.block_until_ready(metrics)
-        else:
-            self.state, metrics = self._step(self.state, gbatch)
-        tel.step_finished(metrics, gbatch, trace_dir=path,
-                          watchdog_capture=capture_dir is not None)
+            with self._span("ad.pre_step"):
+                self._pre_step(gbatch)
+            path = capture_dir = None
+            if trace_dir:
+                path = self._trace_step_dir(trace_dir, self.step)
+            elif tel is not None:
+                path = capture_dir = tel.arm_capture_dir()
+                if capture_dir:
+                    os.makedirs(capture_dir, exist_ok=True)
+            if tel is not None:
+                tel.step_started()
+            if path:
+                with jax.profiler.trace(path):
+                    metrics = self._dispatch(gbatch)
+                    jax.block_until_ready(metrics)
+            else:
+                metrics = self._dispatch(gbatch)
+            variants = self._step._cache_size()
+            run_span.set_metadata(variants=variants)
+            if variants > self._variants:
+                self._note_compiled(variants)
+            self._dispatches += 1
+            if tel is not None:
+                tel.step_finished(metrics, gbatch, trace_dir=path,
+                                  watchdog_capture=capture_dir is not None)
         if path:
             metrics = dict(metrics)
             metrics["trace_dir"] = path
         return metrics
+
+    def _dispatch(self, gbatch):
+        with self._span("ad.dispatch"):
+            self.state, metrics = self._step(self.state, gbatch)
+        return metrics
+
+    def _note_compiled(self, variants):
+        """The step's compiled variants grew with this dispatch: the
+        program's own compile count.  The first is the expected compile;
+        any later one means a batch of another shape, dtype or sharding,
+        and gets a warning that names the dispatch."""
+        if self._variants:
+            logging.warning(
+                "the training step compiled again at dispatch %d (%d "
+                "variants now): its batch differs in shape, dtype or "
+                "sharding from the ones before", self._dispatches, variants)
+        self._variants = variants
 
     @staticmethod
     def _metrics_log_str(metrics):

@@ -4,9 +4,10 @@ The observability layer of the stack (``docs/observability.md``):
 
 - :mod:`~autodist_tpu.telemetry.metrics` — zero-dep counters / gauges /
   histograms in a bounded ring, JSONL export per host;
-- :mod:`~autodist_tpu.telemetry.spans` — ``telemetry.span("name")``
-  host spans, Chrome-trace/Perfetto compatible, joinable with
-  ``jax.profiler`` device traces via ``tools/trace_summary.py``;
+- :mod:`~autodist_tpu.telemetry.spans` — ``telemetry.span("name")``:
+  a ``jax.profiler.TraceAnnotation`` always (the ``ad.*`` host spans of a
+  profile, on the device trace's clock) and, when telemetry is on, a
+  registry ``span`` record too;
 - :mod:`~autodist_tpu.telemetry.session` — per-step session
   instrumentation (wall time, throughput, achieved MFU, memory
   snapshots, compile split) for :class:`DistributedSession`;
@@ -52,7 +53,7 @@ from autodist_tpu.telemetry.health import HealthMonitor
 from autodist_tpu.telemetry.metrics import (JsonlWriter, MetricsRegistry,
                                             percentiles)
 from autodist_tpu.telemetry.schema import validate_manifest
-from autodist_tpu.telemetry.spans import SpanRecorder, dump_chrome_trace
+from autodist_tpu.telemetry.spans import SpanRecorder
 from autodist_tpu.telemetry.stream import (ClusterView, StreamPublisher,
                                            TelemetryCollector,
                                            stream_address_from_env)
@@ -62,7 +63,7 @@ __all__ = [
     "enabled", "enable", "disable", "get_registry", "reset_registry",
     "counter", "gauge", "histogram", "span", "default_run_dir",
     "MetricsRegistry", "JsonlWriter", "SpanRecorder", "SlowStepWatchdog",
-    "SessionTelemetry", "dump_chrome_trace", "percentiles",
+    "SessionTelemetry", "percentiles",
     "validate_manifest", "merge_worker_manifests", "load_manifest",
     "load_manifest_with_stats", "HealthMonitor",
     "ClusterView", "StreamPublisher", "TelemetryCollector",
@@ -150,12 +151,27 @@ def flight(worker=None, run_dir=None):
     return recorder(worker=worker, run_dir=run_dir)
 
 
-def span(name, **args):
-    """``with telemetry.span("shard_batch"):`` — a recorded host span when
-    enabled, a null context otherwise."""
-    if not _STATE["enabled"]:
-        return contextlib.nullcontext()
-    return SpanRecorder(get_registry()).span(name, **args)
+def span(name, recorder=None, **args):
+    """``with telemetry.span("ad.shard_batch", batch=3):`` — the one span
+    entry of the program.  Always a ``jax.profiler.TraceAnnotation``: inert
+    while no profile is taken, and in a profile a host span on the device
+    trace's clock with ``args`` as its stats.  When telemetry is enabled
+    (or a session hands in its own ``recorder``) the registry ``span``
+    record is written as well."""
+    import jax
+
+    annotation = jax.profiler.TraceAnnotation(name, **args)
+    if recorder is None:
+        if not _STATE["enabled"]:
+            return annotation
+        recorder = SpanRecorder(get_registry())
+    return _recorded(annotation, recorder.span(name, **args))
+
+
+@contextlib.contextmanager
+def _recorded(annotation, record):
+    with annotation, record:
+        yield
 
 
 def new_run_id():

@@ -192,7 +192,9 @@ class SessionTelemetry:
             return None
 
     def span(self, name, **args):
-        return self.spans.span(name, **args)
+        from autodist_tpu import telemetry
+
+        return telemetry.span(name, recorder=self.spans, **args)
 
     def _publish(self, frame):
         """Push one frame to the live collector (non-blocking no-op when
@@ -428,12 +430,11 @@ class SessionTelemetry:
     # -- run trailer -------------------------------------------------------
 
     def finalize(self):
-        """Write the summary trailer, dump host spans + the measured
-        RuntimeRecord, and (on the chief) merge worker manifests.
+        """Write the summary trailer, dump the measured RuntimeRecord,
+        and (on the chief) merge worker manifests.
         Idempotent — safe to call after every run_steps/fit."""
         from autodist_tpu.telemetry.aggregate import merge_worker_manifests
         from autodist_tpu.telemetry.metrics import percentiles
-        from autodist_tpu.telemetry.spans import dump_chrome_trace
 
         if self._n == 0:
             return None
@@ -467,12 +468,6 @@ class SessionTelemetry:
                     summary["step_skew"] = sk
             except Exception:
                 pass
-        span_records = self.spans.events()
-        if span_records:
-            summary["host_spans"] = dump_chrome_trace(
-                span_records,
-                os.path.join(self.run_dir,
-                             f"host_spans_worker_{self.worker}.trace.json"))
         if self.health is not None:
             summary["health"] = self.health.summary()
         if self.stream is not None:

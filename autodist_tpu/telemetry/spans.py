@@ -1,18 +1,12 @@
-"""Host-side span tracing, Chrome-trace / Perfetto compatible.
+"""Host-side span records of the telemetry registry.
 
-``jax.profiler.trace`` captures *device* lanes; this module is the
-*host* complement: ``with spans.span("shard_batch"):`` records a
-complete-event (``ph: "X"``) with microsecond wall-clock timestamps, so
-a dumped span file loads in Perfetto / ``chrome://tracing`` next to a
-device trace from the same run, and ``tools/trace_summary.py
---host-spans`` can join the two timelines (device time under each host
-span).
-
-Timestamps are ``time.time_ns() // 1000`` — wall-clock microseconds,
-the same timebase the profiler's chrome export uses — so host and
-device lanes line up without a clock-translation step.  Durations are
-measured with ``perf_counter`` (monotonic) to stay immune to wall-clock
-steps mid-span.
+``SpanRecorder.span`` writes one ``span`` record (name, category,
+wall-clock start in microseconds, ``perf_counter`` duration, pid, tid,
+arguments) into a registry ring; the JSONL manifest and its schema read
+them.  The timeline of a run is not made from these: ``telemetry.span``
+also opens a ``jax.profiler.TraceAnnotation``, so a profile holds the
+program's ``ad.*`` spans on the device trace's own clock
+(docs/observability.md).
 """
 import contextlib
 import os
@@ -21,7 +15,7 @@ import time
 
 
 class SpanRecorder:
-    """Collects chrome-trace complete events into a registry ring."""
+    """Collects span records into a registry ring."""
 
     def __init__(self, registry):
         self._registry = registry
@@ -41,31 +35,3 @@ class SpanRecorder:
 
     def events(self):
         return self._registry.events("span")
-
-
-def to_chrome_events(span_records, process_name="autodist_tpu host"):
-    """Registry span records -> chrome-trace event list (with the
-    ``process_name`` metadata events viewers use to label lanes)."""
-    pids = sorted({r.get("pid", 0) for r in span_records})
-    events = [{"ph": "M", "name": "process_name", "pid": pid,
-               "args": {"name": f"{process_name} (pid {pid})"}}
-              for pid in pids]
-    for r in span_records:
-        events.append({
-            "ph": "X", "name": r.get("name", "?"), "cat": r.get("cat", "host"),
-            "ts": r.get("ts", 0), "dur": r.get("dur", 0.0),
-            "pid": r.get("pid", 0), "tid": r.get("tid", 0),
-            "args": r.get("args", {}),
-        })
-    return events
-
-
-def dump_chrome_trace(span_records, path, process_name="autodist_tpu host"):
-    """Write span records as a chrome-trace JSON file; returns the path."""
-    import json
-
-    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    with open(path, "w") as f:
-        json.dump({"traceEvents": to_chrome_events(span_records, process_name),
-                   "displayTimeUnit": "ms"}, f)
-    return path

@@ -194,6 +194,13 @@ const uint8_t* adio_loader_next(AdioLoader* ld) {
   return b;
 }
 
+// assembled batches waiting in the ring (the loader's queue depth)
+uint64_t adio_loader_ready(AdioLoader* ld) {
+  if (!ld) return 0;
+  std::lock_guard<std::mutex> lk(ld->mu);
+  return ld->ready.size();
+}
+
 void adio_loader_release(AdioLoader* ld, const uint8_t* buf) {
   if (!ld || !buf) return;
   {

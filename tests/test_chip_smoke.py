@@ -4,6 +4,7 @@ size on the virtual CPU mesh.  The steering is here, not in the script: it has
 no size or platform option for a rehearsal to use."""
 import dataclasses
 import os
+import re
 import subprocess
 import sys
 
@@ -123,13 +124,18 @@ def test_four_chip_phase_refuses_a_mesh_of_other_devices(tmp_path, events):
 
 # ---------------------------------------------------- compile cache helper --
 
+KEYING = [("jax_compilation_cache_include_metadata_in_key", True),
+          ("jax_hlo_source_file_canonicalization_regex",
+           "^" + re.escape(REPO + os.sep))]
+
+
 def test_compile_cache_left_alone_when_placed_from_outside(monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
     seen = []
     monkeypatch.setattr(jax.config, "update",
                         lambda *a: seen.append(a))
     assert compile_cache.ensure_compile_cache() == "/some/dir"
-    assert seen == []
+    assert seen == KEYING          # no directory is set, the keying is
 
 
 def test_compile_cache_fixed_in_checkout_path(monkeypatch):
@@ -139,7 +145,7 @@ def test_compile_cache_fixed_in_checkout_path(monkeypatch):
     first = compile_cache.ensure_compile_cache()
     assert first == compile_cache.ensure_compile_cache() \
         == os.path.join(REPO, ".jax_cache")
-    assert seen == [("jax_compilation_cache_dir", first)] * 2
+    assert seen == (KEYING + [("jax_compilation_cache_dir", first)]) * 2
     ignored = open(os.path.join(REPO, ".gitignore")).read().split()
     assert ".jax_cache/" in ignored
 

@@ -88,13 +88,6 @@ def test_five_step_run_manifest_report_calibrate(tmp_path, monkeypatch):
     assert meta["backend"] == "cpu" and meta["num_devices"] == 8
     assert "cost_estimate" in meta  # predicted-vs-measured substrate
 
-    # host spans were recorded and dumped chrome-trace compatible
-    spans_path = summary["host_spans"]
-    with open(spans_path) as f:
-        trace = json.load(f)
-    names = {e["name"] for e in trace["traceEvents"] if e.get("ph") == "X"}
-    assert "shard_batch" in names
-
     # the report renders the manifest
     from tools.telemetry_report import render, summarize_manifest
 
@@ -224,7 +217,7 @@ def test_schema_validator_catches_bad_records():
     assert not any("exotic" in e for e in errors)
 
 
-def test_span_recorder_chrome_dump(tmp_path):
+def test_span_recorder_chrome_dump():
     reg = telemetry.MetricsRegistry()
     rec = telemetry.SpanRecorder(reg)
     with rec.span("outer", step=3):
@@ -233,15 +226,7 @@ def test_span_recorder_chrome_dump(tmp_path):
     events = rec.events()
     assert [e["name"] for e in events] == ["inner", "outer"]  # close order
     assert all(e["dur"] >= 0 and e["ts"] > 0 for e in events)
-    path = telemetry.dump_chrome_trace(events, str(tmp_path / "s.trace.json"))
-    with open(path) as f:
-        data = json.load(f)
-    xs = [e for e in data["traceEvents"] if e.get("ph") == "X"]
-    assert {e["name"] for e in xs} == {"outer", "inner"}
-    assert any(e.get("ph") == "M" and e.get("name") == "process_name"
-               for e in data["traceEvents"])
-    outer = next(e for e in xs if e["name"] == "outer")
-    assert outer["args"] == {"step": 3}
+    assert events[1]["args"] == {"step": 3} and "args" not in events[0]
 
 
 def test_jsonl_writer_and_merge(tmp_path):

@@ -1,8 +1,7 @@
 """tools/trace_summary.py on synthetic chrome traces.
 
-Pins the top-ops aggregation (device-track filtering, totals, counts)
-and the host-span join (device time inside host span windows) on a small
-hand-built trace — no profiler run needed, so the numbers are exact.
+Pins the top-ops aggregation (device-track filtering, totals, counts) on a
+small hand-built trace — no profiler run needed, so the numbers are exact.
 """
 import gzip
 import json
@@ -12,8 +11,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from tools.trace_summary import (device_intervals, find_trace_file,  # noqa: E402
-                                 join_host_spans, load_events,
-                                 load_span_events, summarize)
+                                 load_events, summarize)
 from tools import trace_summary  # noqa: E402
 
 # two lanes: pid 1 is a device track (name matches the device pattern),
@@ -30,16 +28,6 @@ SYNTHETIC_EVENTS = [
     # non-complete events must be ignored by the aggregation
     {"ph": "B", "pid": 1, "tid": 1, "name": "begin.only", "ts": 100},
 ]
-
-HOST_SPANS = [
-    # covers the first fusion.1 (1000-1100) fully, nothing else
-    {"ph": "X", "pid": 7, "tid": 1, "name": "step", "ts": 950, "dur": 200},
-    # covers half of the second fusion.1 (2000-2050 -> 2025 cut)
-    {"ph": "X", "pid": 7, "tid": 1, "name": "step", "ts": 1975, "dur": 50},
-    # empty window: no device activity at all
-    {"ph": "X", "pid": 7, "tid": 1, "name": "idle", "ts": 3000, "dur": 100},
-]
-
 
 def _write_trace(tmp_path, gz=True):
     run_dir = tmp_path / "plugins" / "profile" / "run1"
@@ -85,19 +73,6 @@ def test_device_intervals_filters_host():
     assert (1000.0, 1100.0) in ivs and (1500.0, 1530.0) in ivs
 
 
-def test_host_span_join_pins_overlap():
-    joined = join_host_spans(SYNTHETIC_EVENTS, HOST_SPANS)
-    assert set(joined) == {"step", "idle"}
-    step = joined["step"]
-    # window 1: fusion.1 fully inside -> 100us; window 2: 2000-2025 -> 25us
-    assert step["host_us"] == 250.0
-    assert step["count"] == 2
-    assert step["device_us"] == 125.0
-    assert abs(step["device_share"] - 0.5) < 1e-9
-    idle = joined["idle"]
-    assert idle["device_us"] == 0.0 and idle["device_share"] == 0.0
-
-
 def test_main_host_only_trace_degrades_gracefully(tmp_path, capsys):
     # a CPU/host-only capture has no device-pattern lane — the CLI must
     # say so and summarize the host tracks instead of printing nothing
@@ -133,21 +108,3 @@ def test_main_trace_without_complete_events(tmp_path, capsys):
     out = capsys.readouterr().out
     assert rc == 0
     assert "no complete ('X') events" in out
-
-
-def test_main_with_host_spans(tmp_path, capsys):
-    # spans live OUTSIDE the profile dir — find_trace_file globs every
-    # *.trace.json under its argument and must not pick the span dump
-    profile_dir = tmp_path / "profile"
-    profile_dir.mkdir()
-    _write_trace(profile_dir, gz=True)
-    spans_path = tmp_path / "host_spans.trace.json"
-    spans_path.write_text(json.dumps({"traceEvents": HOST_SPANS}))
-    assert load_span_events(str(spans_path)) == HOST_SPANS
-    rc = trace_summary.main([str(profile_dir), "--host-spans",
-                             str(spans_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "fusion.1" in out
-    assert "host spans" in out
-    assert "step" in out and "idle" in out

@@ -1,7 +1,6 @@
 """Summarize a jax.profiler trace: top ops by device time.
 
 Usage:  python tools/trace_summary.py <trace_dir> [--top 25]
-                                      [--host-spans spans.trace.json]
 
 Reads the chrome-trace JSON (``*.trace.json.gz``) that
 ``jax.profiler.trace`` writes under ``<dir>/plugins/profile/<run>/`` and
@@ -15,11 +14,10 @@ The chrome-trace event model (loaders, device-lane detection) lives in
 this tool is the human-facing view, ``autodist_tpu/analysis/
 runtime_audit.py`` the machine-facing one.
 
-``--host-spans`` joins the host-side span file the telemetry layer dumps
-(``host_spans_worker_<rank>.trace.json`` — same wall-clock-microsecond
-timebase) against the device lanes: per host span, how much device time
-ran concurrently inside its window — the host/device overlap view for
-input-pipeline and dispatch-stall hunting (docs/observability.md).
+The program's own host spans (``ad.run``, ``ad.dispatch``,
+``ad.prefetch.next``, ... — docs/observability.md) are
+``jax.profiler.TraceAnnotation``s: they sit in the same profile, on the
+host tracks, on the device's clock; ``--all-tracks`` lists them.
 """
 import argparse
 import os
@@ -87,56 +85,12 @@ def device_intervals(events, pnames=None):
     return out
 
 
-def _overlap_us(window, intervals):
-    lo, hi = window
-    return sum(max(0.0, min(hi, b) - max(lo, a)) for a, b in intervals)
-
-
-def join_host_spans(device_events, span_events):
-    """Join host spans against device lanes (shared wall-clock-µs
-    timebase): per span name -> dict with host total/count and the
-    device time that ran concurrently inside the span windows.
-
-    ``device_ms`` double-counts overlapping device lanes (it is a busy
-    SUM, like :func:`summarize`'s totals); ``device_share`` therefore
-    answers "while the host was in this span, how busy were the
-    devices", and can exceed 1.0 on multi-lane captures.
-    """
-    intervals = device_intervals(device_events)
-    rows = {}
-    for e in span_events:
-        if e.get("ph") not in (None, "X"):
-            continue
-        name = e.get("name", "?")
-        ts = float(e.get("ts", 0.0))
-        dur = float(e.get("dur", 0.0))
-        row = rows.setdefault(name, {"host_us": 0.0, "count": 0,
-                                     "device_us": 0.0})
-        row["host_us"] += dur
-        row["count"] += 1
-        row["device_us"] += _overlap_us((ts, ts + dur), intervals)
-    for row in rows.values():
-        row["device_share"] = (row["device_us"] / row["host_us"]
-                               if row["host_us"] else 0.0)
-    return rows
-
-
-def load_span_events(path):
-    """Load host-span events from a telemetry chrome-trace dump (or any
-    chrome-trace JSON): complete ("X") events only."""
-    events = load_events(path)
-    return [e for e in events if e.get("ph") == "X"]
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("trace_dir")
     ap.add_argument("--top", type=int, default=25)
     ap.add_argument("--all-tracks", action="store_true",
                     help="include host-side tracks too")
-    ap.add_argument("--host-spans", default="",
-                    help="telemetry host-span trace JSON to join against "
-                         "the device lanes")
     args = ap.parse_args(argv)
 
     path = find_trace_file(args.trace_dir)
@@ -167,16 +121,6 @@ def main(argv=None):
     for name, (us, count) in rows:
         share = us / total if total else 0.0
         print(f"{us / 1e3:10.2f} {count:7d} {share:6.1%}  {name[:90]}")
-    if args.host_spans:
-        spans = load_span_events(args.host_spans)
-        joined = join_host_spans(events, spans)
-        print(f"\nhost spans ({args.host_spans}):")
-        print(f"{'host_ms':>10} {'count':>7} {'dev_ms':>10} {'dev_share':>9}  span")
-        for name, row in sorted(joined.items(),
-                                key=lambda kv: -kv[1]["host_us"]):
-            print(f"{row['host_us'] / 1e3:10.2f} {row['count']:7d} "
-                  f"{row['device_us'] / 1e3:10.2f} "
-                  f"{row['device_share']:9.1%}  {name[:80]}")
     return 0
 
 
