@@ -58,16 +58,25 @@ def warning(msg, *args, **kwargs):
     get_logger().warning(msg, *args, **kwargs)
 
 
-_warned = set()
+_said = set()
+
+
+def _once(say, msg, args):
+    if (msg, args) not in _said:
+        _said.add((msg, args))
+        say(msg, *args)
 
 
 def warning_once(msg, *args):
     """``warning`` that drops exact repeats: for notes made at trace time,
     which every re-trace of the same site would otherwise print again.
     ``args`` must be hashable (shapes, dtypes, names)."""
-    if (msg, args) not in _warned:
-        _warned.add((msg, args))
-        warning(msg, *args)
+    _once(warning, msg, args)
+
+
+def info_once(msg, *args):
+    """``info`` under the rule of ``warning_once``."""
+    _once(info, msg, args)
 
 
 def error(msg, *args, **kwargs):

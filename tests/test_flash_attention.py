@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from autodist_tpu.ops.pallas import flash_attention as F
 from autodist_tpu.ops.pallas.flash_attention import flash_attention
 
 
@@ -85,6 +86,176 @@ def test_gradients_match_xla(causal, masked):
     g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=1e-4)
+
+
+# what the tile program adapts to: the fold (B*H of 6 and 80 are no multiple
+# of the 8 heads a program would like), one tile or several a row, the dtype
+@pytest.mark.parametrize("b,h,s,dtype,causal", [
+    (2, 3, 64, jnp.float32, True),       # 6 folds -> 6 heads a program
+    (4, 20, 64, jnp.float32, True),      # 80 folds (gpt2_large a chip) -> 8
+    (1, 7, 64, jnp.float32, False),      # a prime fold -> 7
+    (1, 2, 32, jnp.float32, True),       # one tile a row
+    (1, 2, 128, jnp.float32, True),      # four tiles a row
+    (1, 2, 320, jnp.float32, True),      # ten: past the prefix form's cases
+    (2, 2, 64, jnp.bfloat16, True),
+    (2, 2, 64, jnp.bfloat16, False),
+], ids=["fold6", "fold80", "fold7", "one_tile", "four_tiles", "ten_tiles",
+        "bf16_causal", "bf16_full"])
+def test_folds_tiles_and_dtypes_match_xla(b, h, s, dtype, causal):
+    q, k, v, w = (_rand((b, s, h, 16), dtype, seed=i) for i in range(4))
+    # bf16: one ulp of an output or gradient of size 2-4 is 1.6e-2
+    atol_out, atol_grad = (1e-5, 1e-4) if dtype == jnp.float32 else (5e-2,) * 2
+
+    def f(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=32, block_k=32)
+
+    def ref(q, k, v):
+        return ref_attn(q, k, v, causal=causal)
+
+    np.testing.assert_allclose(flash(q, k, v).astype(np.float32),
+                               ref(q, k, v).astype(np.float32), atol=atol_out)
+    g1 = jax.grad(f(flash), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(f(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(g1, g2):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(a.astype(np.float32),
+                                   b_.astype(np.float32), atol=atol_grad)
+
+
+@pytest.mark.parametrize("causal,masked,dtype", [
+    (True, False, jnp.float32), (False, False, jnp.float32),
+    (False, True, jnp.float32), (True, False, jnp.bfloat16)],
+    ids=["causal", "full", "masked", "causal_bf16"])
+def test_loop_form_matches_prefix_form(monkeypatch, causal, masked, dtype):
+    """A program visits its visible prefix as one slab where that fits, and
+    block by block with partials merged in VMEM scratch where it does not
+    (long rows; ring attention's traced offsets).  Same numbers either way,
+    forward and gradients."""
+    q, k, v = (_rand((2, 128, 3, 16), dtype, seed=i) for i in range(3))
+    kv_mask = None
+    if masked:
+        m = np.ones((2, 128), bool)
+        m[0, 70:] = False
+        m[1, :] = False
+        kv_mask = jnp.asarray(m)
+
+    def f(q, k, v):
+        return jnp.sum(jnp.sin(flash_attention(
+            q, k, v, causal=causal, kv_mask=kv_mask, block_q=32,
+            block_k=32).astype(jnp.float32)))
+
+    want = jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+    monkeypatch.setattr(F, "_SLAB_BUDGET", 0)
+    F._make_flash.cache_clear()
+    got = jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+    F._make_flash.cache_clear()
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == jnp.float32 \
+        else dict(rtol=2e-2, atol=5e-2)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a.astype(np.float32),
+                                   b.astype(np.float32), **tol)
+
+
+def test_rectangular_causal_matches_xla():
+    # more q rows than keys, masked from position 0 of both (the loop form:
+    # a prefix's length is static per tile only on a square)
+    q = _rand((2, 64, 2, 16), seed=0)
+    k, v = (_rand((2, 32, 2, 16), seed=i) for i in (1, 2))
+
+    def f(attn):
+        return lambda q, k, v: jnp.sum(jnp.sin(attn(q, k, v)))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=16, block_k=16)
+
+    def ref(q, k, v):
+        return ref_attn(q, k, v, causal=True)
+
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=1e-5)
+    for a, b in zip(jax.grad(f(flash), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(f(ref), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_no_mask_build_equals_all_true_mask_build(causal):
+    """``kv_mask=None`` builds the kernels without a bias operand; an
+    all-true mask builds them with one that adds 0.  Same numbers, forward
+    and gradients (the biased build groups heads by example: 2 a program,
+    the other all 6 folds)."""
+    q, k, v = (_rand((3, 64, 2, 16), seed=i) for i in range(3))
+    mask = jnp.ones((3, 64), bool)
+
+    def f(kv_mask):
+        return lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+            q, k, v, causal=causal, kv_mask=kv_mask, block_q=32, block_k=32)))
+
+    np.testing.assert_array_equal(
+        flash_attention(q, k, v, causal=causal, block_q=32, block_k=32),
+        flash_attention(q, k, v, causal=causal, kv_mask=mask, block_q=32,
+                        block_k=32))
+    for a, b in zip(jax.grad(f(None), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(f(mask), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("bh,h,group,biased,want", [
+    (512, 16, 1, False, 8),     # gpt2_medium.train_fed's fold
+    (80, 20, 1, False, 8),      # gpt2_large over four chips, a chip
+    (80, 20, 1, True, 5),       # a per-example bias keeps a program in one
+    (6, 3, 1, False, 6),
+    (7, 7, 1, False, 7),
+    (11, 11, 1, False, 1),      # a prime fold over the preference
+    (64, 32, 4, False, 4),      # GQA: the query heads of one K/V head
+])
+def test_heads_per_program_follow_the_fold(bh, h, group, biased, want):
+    # rows short enough that VMEM binds nothing
+    assert F._pick_heads(bh, h, group, biased, 256, 256, 64, 2,
+                         256, 256) == want
+
+
+@pytest.mark.parametrize("s,itemsize,want,raised", [
+    (1024, 2, 4, False),        # the benchmark's rows: 4 of the 8 heads fit
+    (1024, 4, 2, False),        # what the default scoped limit leaves
+    (8192, 2, 1, False),
+    (32768, 2, 1, True),        # one head's rows: Mosaic is told a limit
+    (65536, 2, 0, None),        # the XLA fallback's case
+])
+def test_heads_per_program_shrink_to_the_vmem_budget(s, itemsize, want,
+                                                     raised):
+    shape = (s, s, 64, itemsize, 512, 512)
+    assert F._pick_heads(512, 16, 1, False, *shape) == want
+    if want:
+        assert (F._vmem_bytes(want, *shape) > F._VMEM_BUDGET) == raised
+        limit = F._tpu_params(F._vmem_bytes(want, *shape)).vmem_limit_bytes
+        assert limit == (F._VMEM_LIMIT if raised else None)
+
+
+@pytest.mark.parametrize("bq,bk", [(32, 32), (64, 32), (32, 64), (1, 1)])
+@pytest.mark.parametrize("q_off,k_off", [(0, 0), (128, 0), (0, 128),
+                                         (64, 64)])
+def test_causal_loop_bounds_are_the_visible_tiles(bq, bk, q_off, k_off):
+    """The in-kernel loops run over [0, n_full) unmasked and [n_full, n_vis)
+    masked k tiles (and the mirror image over q tiles): against the tile
+    classes counted from the positions themselves, with ring offsets."""
+    s = 128
+    nq, nk = s // bq, s // bk
+    qpos = q_off + np.arange(s)[:, None]
+    kpos = k_off + np.arange(s)[None, :]
+    vis = (qpos >= kpos).reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    some, every = vis.any((2, 3)), vis.all((2, 3))
+    for i in range(nq):
+        n_full, n_vis = F._k_bounds(q_off + i * bq, k_off, bq, bk, nk, True)
+        assert every[i, :n_full].all() and not every[i, n_full:].any()
+        assert some[i, :n_vis].all() and not some[i, n_vis:].any()
+    for j in range(nk):
+        i_vis, i_full = F._q_bounds(k_off + j * bk, q_off, bq, bk, nq, True)
+        assert every[i_full:, j].all() and not every[:i_full, j].any()
+        assert some[i_vis:, j].all() and not some[:i_vis, j].any()
 
 
 @pytest.mark.parametrize("kv_heads", [1, 2])

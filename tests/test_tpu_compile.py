@@ -26,6 +26,10 @@ from autodist_tpu.ops.pallas import quantize as Q
 
 # GPT-2-small's training shape in chip_smoke.py: (B, S, H, D)
 GPT_ATTN = (32, 1024, 12, 64)
+# the attention shapes of the benchmark's GPT cells, a chip: gpt2_medium at
+# batch 32, gpt2_large at global batch 16 over four chips
+BENCHMARK_ATTN = {"gpt2_medium": (32, 1024, 16, 64),
+                  "gpt2_large": (4, 1024, 20, 64)}
 # every BatchNorm input of ResNet-50 at B=256, 224x224: (rows, channels)
 RESNET50_B256_BN_SITES = [
     (256 * 112 * 112, 64), (256 * 56 * 56, 64), (256 * 56 * 56, 256),
@@ -77,6 +81,40 @@ def test_flash_attention_gpt_small_train_shape(one_chip, kv_heads, grad):
         return jnp.sum(out.astype(jnp.float32))
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)) if grad else loss, q, kv, kv)
+
+
+def _kernel_results(text):
+    """The result types of each ``tpu_custom_call`` of a compiled text, split
+    as ``benchmark/layer_metrics/flash_attn_roofline.py`` splits them."""
+    out = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            result = line.split(" = ", 1)[1].split(" custom-call(", 1)[0]
+            out.append([p.split("[")[0]
+                        for p in result.strip("()").split("}, ")])
+    return sorted(out)
+
+
+@pytest.mark.parametrize("config", sorted(BENCHMARK_ATTN))
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_flash_attention_benchmark_shapes_and_signatures(one_chip, config,
+                                                         grad):
+    """The three kernels compile at the shapes the benchmark's GPT cells run,
+    with the result signatures its roofline reader tells them apart by:
+    forward ``(out, f32 row statistics)``, dq one result, dk/dv two results
+    in the input dtype."""
+    qkv = _aval(one_chip, BENCHMARK_ATTN[config], jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = F.flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)) if grad else loss,
+                    qkv, qkv, qkv)
+    want = [["bf16", "f32"]]
+    if grad:
+        want += [["bf16"], ["bf16", "bf16"]]
+    assert _kernel_results(text) == sorted(want)
 
 
 def test_flash_block_update_ring_step(one_chip):
