@@ -19,5 +19,8 @@ from autodist_tpu.models.gpt import (  # noqa: F401
 from autodist_tpu.models.llama import (  # noqa: F401
     LLAMA_TINY, Llama, LlamaConfig,
 )
+from autodist_tpu.models.qwen3_next import (  # noqa: F401
+    QWEN3_NEXT_TINY, Qwen3Next, Qwen3NextConfig,
+)
 from autodist_tpu.models.lm import LMConfig, LSTMBody, LSTMLM  # noqa: F401
 from autodist_tpu.models.ncf import NCFConfig, NeuMF  # noqa: F401

@@ -172,6 +172,40 @@ def llama_capture(config, seq_len, rng=None, streaming_loss=False,
     return loss_fn, params, ["embed"]
 
 
+def qwen3_next_capture(config, seq_len, rng=None, loss_chunk=8192):
+    """Init a Qwen3-Next causal LM; returns (loss_fn, params, sparse_vars).
+
+    ``loss_fn(params, batch) -> (loss, counters)``: pass ``has_aux=True`` to
+    ``distribute``; the step's metrics then carry ``moe_rows_here``,
+    ``moe_load_max_over_mean`` and ``moe_overflow_rows``
+    (``models/qwen3_next.py:routing_counters``).  The loss streams the
+    untied head (``ops/losses.py``, "dv" layout).  If any layer was sent
+    more assignments than its ``rows_bound`` holds, the loss is ``inf``: the
+    surplus was not computed, and nobody should train on without knowing.
+    """
+    from autodist_tpu.models.qwen3_next import Qwen3Next, routing_counters
+    from autodist_tpu.ops.losses import streaming_softmax_xent
+
+    rng = rng if rng is not None else host_key(0)
+    model = Qwen3Next(config)
+    dummy = jnp.zeros((1, seq_len), jnp.int32)
+    params = model.init(rng, dummy, return_hidden=True)["params"]
+
+    def loss_fn(p, batch):
+        hidden, stats = model.apply({"params": p}, batch["tokens"],
+                                    return_hidden=True)
+        t = batch["targets"]
+        loss = streaming_softmax_xent(
+            hidden, p["lm_head"], t,
+            valid=_positional_mask(t, batch.get(BATCH_MASK_KEY)),
+            chunk=loss_chunk, layout="dv")
+        counters = routing_counters(jax.lax.stop_gradient(stats))
+        return jnp.where(counters["moe_overflow_rows"] > 0, jnp.inf,
+                         loss), counters
+
+    return loss_fn, params, []
+
+
 def lm_capture(config, seq_len, rng=None):
     """The embedding table is a TOP-LEVEL param (not flax-managed) so a
     PartitionedPS strategy can shard it end-to-end: the engine then hands

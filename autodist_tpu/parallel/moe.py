@@ -1,99 +1,174 @@
-"""Expert parallelism: mixture-of-experts with all_to_all token routing.
+"""Expert parallelism: a routed feed-forward layer that is told which experts
+it holds.
 
-Beyond the reference's strategy space (SURVEY.md section 2.8 lists EP as a
-future dimension): experts are sharded over the ``expert`` mesh axis, and
-tokens travel to their expert's device via ``all_to_all`` — the standard
-TPU MoE dispatch (GShard-style), with fixed capacity so every shape is
-static for XLA.
+The router scores ALL ``experts_total`` experts and takes the ``top_k``
+largest for every token; nothing is dropped for capacity.  The layer then
+computes the part of the result that its own experts give: the experts
+``first_expert ... first_expert + experts_held`` whose weights it was handed.
+The weights of a token's ``top_k`` are normalised over all of them, held here
+or not, so the parts that the shares of a layer give add up to the whole
+layer (``tests/test_moe.py``).  One chip that holds a share runs it as it is;
+under an ``expert`` mesh axis (``axis_name``) every device holds its own
+share and the parts are summed over the axis.
 
-Functions run inside ``shard_map`` with the expert axis present.  The
-expert weights live sharded over the axis (one expert group per device); the
-engine stores them like any other array — callers shard via a leading
-``num_local_experts`` dim so EP composes with the strategy engine's
-replicated storage (weights replicated across the DATA axes, distinct along
-the expert axis is achieved by per-device slicing of a stacked tensor).
+Static shapes throughout.  The assignments to held experts are packed,
+sorted by expert, into a buffer of ``rows_bound`` rows (default: the worst
+case ``T * min(top_k, experts_held)``), run through grouped matrix products
+(``lax.ragged_dot``, which the TPU compiler turns into a grouped-matmul
+kernel) and added back onto their tokens with their weights.  If more
+assignments arrive than ``rows_bound`` the surplus is NOT computed and
+``overflow_rows`` counts it: the caller makes the step's loss non-finite
+(``models/train_lib.py:qwen3_next_capture``), so a bound set too low is seen
+at once and never trains on silently.
+
+What is still missing for expert parallelism as a strategy dimension is in
+ROADMAP R1: the expert axis in the strategy space, an all-to-all exchange
+that moves only the routed rows (here tokens are all-gathered), and its term
+in the cost model.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 
-
-def top1_gating(logits, num_experts, capacity):
-    """Top-1 router with fixed per-expert capacity.  logits: (T, E).
-    Returns (expert_idx, gate, pos, keep): chosen expert per token, its
-    gate value (zeroed for overflow), the token's position in the expert's
-    queue, and the keep mask (False = dropped by capacity)."""
-    gate = jax.nn.softmax(logits, axis=-1)
-    expert_idx = jnp.argmax(gate, axis=-1)                     # (T,)
-    gate_val = jnp.take_along_axis(gate, expert_idx[:, None], axis=-1)[:, 0]
-    # position of each token within its expert's queue
-    onehot = jax.nn.one_hot(expert_idx, num_experts, dtype=jnp.int32)  # (T, E)
-    pos_in_expert = jnp.cumsum(onehot, axis=0) * onehot
-    pos = jnp.sum(pos_in_expert, axis=-1) - 1                  # (T,)
-    keep = pos < capacity                                      # overflow drops
-    return expert_idx, gate_val * keep, pos, keep
+from autodist_tpu.parallel.tensor_parallel import copy_to_tp, reduce_from_tp
 
 
-def moe_dispatch(x, expert_idx, pos, keep, num_experts, capacity):
-    """Scatter tokens into (E, C, D) expert buffers (dropped slots zero)."""
-    T, D = x.shape
-    buf = jnp.zeros((num_experts, capacity, D), x.dtype)
-    safe_pos = jnp.where(keep, pos, 0)
-    buf = buf.at[expert_idx, safe_pos].add(
-        jnp.where(keep[:, None], x, 0.0))
-    return buf
+@functools.partial(jax.checkpoint, static_argnums=(1,))
+def top_k_of(p, k):
+    """``(values, indices)`` of the ``k`` largest of each row of ``p``
+    ``[T, E]``, largest first, the lower index first among equals (as
+    ``lax.top_k``): ``k`` passes of ``argmax`` and a masked sum, all in
+    the vector unit, where a sort of every row, or a gather of the values
+    and its scatter going backward, is not (PERF.md, PR 28: the gather
+    alone took 3.3 ms a layer).  Differentiable in the values; the
+    backward pass makes the masks again from ``p``."""
+    rest = jax.lax.stop_gradient(p)
+    lanes = jnp.arange(p.shape[-1], dtype=jnp.int32)
+    values, picked = [], []
+    for _ in range(k):
+        i = jnp.argmax(rest, axis=-1).astype(jnp.int32)
+        mine = lanes == i[:, None]
+        picked.append(i)
+        values.append(jnp.sum(jnp.where(mine, p, 0.0), axis=-1))
+        rest = jnp.where(mine, -jnp.inf, rest)
+    return jnp.stack(values, axis=-1), jnp.stack(picked, axis=-1)
 
 
-def moe_combine(buf, expert_idx, pos, keep, gate):
-    """Gather expert outputs back to token order, scaled by the gate."""
-    out = buf[expert_idx, jnp.where(keep, pos, 0)]
-    return out * (gate * keep)[:, None]
+def route(x, router_w, top_k, norm_topk=True):
+    """``(expert indices [T, k], weights [T, k] float32)``: a float32 softmax
+    over all the router's outputs, the ``top_k`` largest, and (with
+    ``norm_topk``) their probabilities divided by the sum of the k."""
+    logits = jnp.einsum("td,de->te", x, router_w.astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, idx = top_k_of(p, top_k)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx, w
 
 
-def expert_parallel_ffn(x, gate_w, w_in, w_out, axis_name):
-    """MoE feed-forward over the expert mesh axis.
+def pack_held(idx, first_expert, experts_held, rows_bound):
+    """Pack the assignments ``idx`` ``[T, k]`` that go to the held experts,
+    sorted by expert and, within one, by token.
+
+    Returns ``(flat, group_sizes, counts)``: ``flat`` ``[rows_bound]`` the
+    positions in ``idx.reshape(-1)`` of the packed assignments (rows past the
+    last one hold ``T * k``), ``group_sizes`` ``[experts_held]`` the rows of
+    each expert in the buffer (they add up to at most ``rows_bound``) and
+    ``counts`` the assignments each held expert was sent, packed or not.
+
+    One sort of an integer key, ``expert * (T * k) + position``, with the
+    assignments that go elsewhere keyed past every held expert.
+    """
+    n = idx.size
+    if (experts_held + 1) * n >= 2 ** 31:
+        raise ValueError(f"{n} assignments over {experts_held} held experts "
+                         "do not fit a 32-bit sort key")
+    local = idx.reshape(-1) - first_expert
+    held = (local >= 0) & (local < experts_held)
+    key = jnp.where(held, local, experts_held) * n \
+        + jnp.arange(n, dtype=jnp.int32)
+    key = jax.lax.sort(key)[:rows_bound]
+    if rows_bound > n:
+        key = jnp.pad(key, (0, rows_bound - n),
+                      constant_values=experts_held * n)
+    flat = jnp.where(key < experts_held * n, key % n, n)
+    counts = jnp.sum(
+        held[:, None] & (local[:, None] == jnp.arange(experts_held)),
+        axis=0, dtype=jnp.int32)
+    ends = jnp.minimum(jnp.cumsum(counts), rows_bound)
+    group_sizes = jnp.diff(ends, prepend=0)
+    return flat, group_sizes, counts
+
+
+def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k,
+                 first_expert=0, rows_bound=None, norm_topk=True,
+                 axis_name=None, tokens_sharded=False):
+    """The held experts' part of a routed SwiGLU feed-forward.
 
     Args:
-      x: (T, D) local tokens.
-      gate_w: (D, E_total) router weights (replicated).
-      w_in: (E_local, D, H), w_out: (E_local, H, D) — this device's expert
-        group (storage: stacked (E_total_over_axis...) sliced per device by
-        the caller, or passed already-local inside shard_map).
-      axis_name: the expert mesh axis.
+      x: ``[T, D]`` tokens.
+      router_w: ``[D, experts_total]``, the whole router.
+      w_gate, w_up: ``[experts_held, D, F]``; w_down: ``[experts_held, F,
+        D]``: the experts ``first_expert ...`` of the layer.
+      top_k: experts a token takes; norm_topk: divide their probabilities
+        by their sum.
+      rows_bound: rows of the packed buffer; ``None`` is the worst case.
+      axis_name: inside ``shard_map`` over an expert mesh axis, the axis:
+        device ``i`` holds the experts from ``i * experts_held`` and the
+        parts are summed over the axis.  With ``tokens_sharded`` each device
+        brings its own tokens: they are all-gathered before and the sum is
+        scattered back after.
 
-    Routing: tokens are bucketed per GLOBAL expert, all_to_all sends each
-    device its experts' tokens, experts run locally (batched einsum — one
-    MXU matmul per projection), all_to_all returns outputs.
+    Returns ``(y, stats)``: ``y`` ``[T, D]`` in ``x.dtype`` and ``stats``, a
+    dict of float32 scalars: ``rows_here`` (assignments to held experts),
+    ``load_max_over_mean`` (the fullest held expert's assignments over their
+    mean) and ``overflow_rows`` (assignments past ``rows_bound``, not
+    computed).
     """
-    T, D = x.shape
-    n_dev = jax.lax.axis_size(axis_name)
-    e_local = w_in.shape[0]
-    n_exp = n_dev * e_local
-    capacity = max(1, (T * 2) // n_exp)  # capacity factor 2
+    experts_held = w_gate.shape[0]
+    if axis_name is not None:
+        # what every device of the axis holds alike enters through a copy
+        # whose backward pass sums the devices' gradients (and the sum
+        # leaves through a reduction whose backward pass is the identity):
+        # a gradient taken inside the ``shard_map`` is then the whole
+        # layer's, and alike on every device (parallel/tensor_parallel.py)
+        first_expert = jax.lax.axis_index(axis_name) * experts_held
+        router_w = copy_to_tp(router_w, axis_name)
+        x = (jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
+             if tokens_sharded else copy_to_tp(x, axis_name))
+    t, d = x.shape
+    if rows_bound is None:
+        rows_bound = t * min(top_k, experts_held)
+    with jax.named_scope("moe.route"):
+        idx, weights = route(x, router_w, top_k, norm_topk)
+        flat, group_sizes, counts = pack_held(idx, first_expert,
+                                              experts_held, rows_bound)
+        token = flat // top_k                   # t for an empty row: dropped
+        w_row = jnp.take(weights.reshape(-1), flat, mode="fill",
+                         fill_value=0.0)
+        rows = jnp.take(x, token, axis=0, mode="fill", fill_value=0)
+    with jax.named_scope("moe.experts"):
+        def grouped(a, w):
+            return jax.lax.ragged_dot(a, w.astype(a.dtype), group_sizes,
+                                      preferred_element_type=jnp.float32)
 
-    if gate_w.shape[-1] != n_exp:
-        raise ValueError(
-            f"gate_w has {gate_w.shape[-1]} experts but the mesh provides "
-            f"{n_dev} devices x {e_local} local experts = {n_exp}")
-    logits = x @ gate_w                                   # (T, E_total)
-    expert_idx, gate, pos, keep = top1_gating(logits, n_exp, capacity)
-    buf = moe_dispatch(x, expert_idx, pos, keep, n_exp, capacity)
-    # (E_total, C, D) -> exchange so device d holds ITS experts' tokens from
-    # every peer: (E_local, n_dev, C, D) after the all_to_all + reshape
-    buf = buf.reshape(n_dev, e_local, capacity, D)
-    buf = jax.lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=2,
-                             tiled=True)        # -> (1, e_local, n_dev*C, D)
-    buf = buf.reshape(e_local, n_dev * capacity, D)
-    # run local experts: batched matmuls
-    h = jax.nn.gelu(jnp.einsum("ecd,edh->ech", buf, w_in))
-    y = jnp.einsum("ech,ehd->ecd", h, w_out)              # (E_local, n_dev*C, D)
-    # send results back
-    y = y.reshape(e_local, n_dev, capacity, D)
-    y = jax.lax.all_to_all(y, axis_name, split_axis=1, concat_axis=0,
-                           tiled=True)
-    y = y.reshape(n_exp, capacity, D)
-    out = moe_combine(y, expert_idx, pos, keep, gate)
-    # auxiliary load-balance loss (Switch-style)
-    density = jnp.mean(jax.nn.one_hot(expert_idx, n_exp), axis=0)
-    router_prob = jnp.mean(jax.nn.softmax(logits, axis=-1), axis=0)
-    aux_loss = n_exp * jnp.sum(density * router_prob)
-    return out, aux_loss
+        h = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+        y = grouped(h.astype(x.dtype), w_down) * w_row[:, None]
+    with jax.named_scope("moe.route"):
+        # rows past the packed ones carry weight 0, but what a grouped
+        # product leaves in them is not promised to be finite
+        y = jnp.where((flat < idx.size)[:, None], y, 0.0)
+        out = jnp.zeros((t, d), jnp.float32).at[token].add(y, mode="drop")
+    if axis_name is not None:
+        out = (jax.lax.psum_scatter(out, axis_name, scatter_dimension=0,
+                                    tiled=True) if tokens_sharded
+               else reduce_from_tp(out, axis_name))
+    total = jnp.sum(counts).astype(jnp.float32)
+    stats = {
+        "rows_here": total,
+        "load_max_over_mean": jnp.max(counts).astype(jnp.float32)
+        * experts_held / jnp.maximum(total, 1.0),
+        "overflow_rows": jnp.maximum(total - rows_bound, 0.0)}
+    return out.astype(x.dtype), stats

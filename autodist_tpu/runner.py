@@ -8,6 +8,7 @@ TPU equivalent: a global batch array is sharded over the replica mesh axis
 ``host_local_array_to_global_array``), the jitted SPMD step runs, and
 metrics come back replicated (fetch contraction = reading any shard).
 """
+import collections
 import contextlib
 import os
 import signal
@@ -127,6 +128,9 @@ class DistributedSession:
         # would fetch from the device) and compiled variants of the step
         self._dispatches = 0
         self._variants = 0
+        # a loss with auxiliary outputs (``has_aux``): the newest dispatches'
+        # auxiliary metrics, until one has finished and rides on ``ad.run``
+        self._aux_pending = collections.deque(maxlen=4)
 
     # -- feeds (reference remapper._remap_feed analog) ---------------------
 
@@ -392,7 +396,8 @@ class DistributedSession:
             else:
                 metrics = self._dispatch(gbatch)
             variants = self._step._cache_size()
-            run_span.set_metadata(variants=variants)
+            run_span.set_metadata(variants=variants,
+                                  **self._finished_aux(metrics))
             if variants > self._variants:
                 self._note_compiled(variants)
             self._dispatches += 1
@@ -403,6 +408,30 @@ class DistributedSession:
             metrics = dict(metrics)
             metrics["trace_dir"] = path
         return metrics
+
+    def _finished_aux(self, metrics):
+        """Arguments for ``ad.run`` from a loss's auxiliary outputs (scalars
+        the model counts, e.g. ``moe_rows_here``): those of the newest
+        earlier dispatch whose step has finished, with ``aux_step`` saying
+        which.  A step is still running when its ``run`` returns, so its own
+        are not there yet; nothing here waits for the device, and a loss
+        without auxiliary outputs costs one comparison."""
+        aux = {k: v for k, v in metrics.items()
+               if k not in ("loss", "step", "grad_norm")
+               and getattr(v, "ndim", None) == 0} \
+            if isinstance(metrics, dict) else {}
+        if not aux and not self._aux_pending:
+            return {}
+        done = None
+        while self._aux_pending and all(
+                v.is_ready() for v in self._aux_pending[0][1].values()):
+            done = self._aux_pending.popleft()
+        if aux:
+            self._aux_pending.append((self._dispatches, aux))
+        if done is None:
+            return {}
+        return {"aux_step": done[0],
+                **{k: float(v) for k, v in done[1].items()}}
 
     def _dispatch(self, gbatch):
         with self._span("ad.dispatch"):

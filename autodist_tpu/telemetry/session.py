@@ -314,6 +314,14 @@ class SessionTelemetry:
                 pass
         self._publish(frame)
         self.registry.histogram("session.step_wall_s", wall)
+        # a loss's auxiliary scalars (``has_aux``; e.g. the routing counters
+        # ``moe_rows_here`` ...): the step is synced already, so reading
+        # them waits for nothing
+        for name, value in (metrics.items() if isinstance(metrics, dict)
+                            else ()):
+            if name not in ("loss", "step", "grad_norm", "trace_dir") \
+                    and getattr(value, "ndim", None) == 0:
+                self.registry.gauge("step." + name, float(value))
         if self.health is not None:
             grad_norm = None
             if isinstance(metrics, dict) and "grad_norm" in metrics:

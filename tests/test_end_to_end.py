@@ -134,7 +134,7 @@ def test_error_feedback_residual_carries():
     sess = ad.distribute(lambda p_, b: jnp.mean(b @ p_["w"]), p, optax.sgd(0.01))
     b = np.full((8, 32), 1.0 + 2**-10, np.float32)  # value bf16 cannot represent
     for _ in range(64):
-        sess.run(b)
+        jax.block_until_ready(sess.run(b))   # see test_powersgd's docstring
     got = sess.params()["w"]
     exp = -0.01 * 64 * b.mean(0)
     # with EF the accumulated error stays bounded; without it, the 2**-10
@@ -185,7 +185,7 @@ def test_multi_step_convergence():
         sess = ad.distribute(loss_fn, {"w": jnp.zeros(5), "b": jnp.zeros(())},
                              optax.sgd(0.05))
         for _ in range(200):
-            m = sess.run({"x": X, "y": y})
+            m = jax.block_until_ready(sess.run({"x": X, "y": y}))
         assert float(m["loss"]) < 0.01, type(builder).__name__
         np.testing.assert_allclose(sess.params()["w"], true_w, atol=0.1)
 

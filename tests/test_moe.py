@@ -1,80 +1,250 @@
-"""Expert-parallel MoE: all_to_all routing vs a single-device reference."""
+"""The expert layer (``parallel/moe.py``) against the plain reference's routed
+feed-forward (``tests/qwen3_next_reference.py``: a loop over the held experts
+with a mask), on one device and over an ``expert`` mesh axis.
+
+float32 on both sides; what differs is the order of the sums (packed grouped
+products and a scatter-add against masked dense products), so 1e-5 of the
+largest entry.  The cases of the top-1 capacity layer this file used to test
+are all here in the new layer's terms: the expert-parallel result against the
+dense one (tokens replicated and tokens sharded over the axis, now both
+exact), and what happens past the capacity (nothing is dropped silently: a
+count, and a non-finite loss).
+"""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+import qwen3_next_reference as R
+from autodist_tpu.models import qwen3_next as Q
 from autodist_tpu.parallel.mesh import build_mesh
-from autodist_tpu.parallel.moe import (
-    expert_parallel_ffn, moe_combine, moe_dispatch, top1_gating,
-)
+from autodist_tpu.parallel.moe import (expert_layer, pack_held, route,
+                                       top_k_of)
 
-E, D, H, T = 8, 16, 32, 64
+E, D, F, T, K = 8, 16, 32, 64, 2
 
 
-def _weights(seed=0):
+def weights(seed=0, experts=E):
     r = np.random.RandomState(seed)
-    return (jnp.asarray(r.randn(D, E), jnp.float32) * 0.5,
-            jnp.asarray(r.randn(E, D, H), jnp.float32) * 0.1,
-            jnp.asarray(r.randn(E, H, D), jnp.float32) * 0.1)
+
+    def w(*shape, scale):
+        return jnp.asarray(r.randn(*shape) * scale, jnp.float32)
+
+    return {"router": w(D, E, scale=0.5),
+            "gate": w(experts, D, F, scale=0.3),
+            "up": w(experts, D, F, scale=0.3),
+            "down": w(experts, F, D, scale=0.3),
+            "shared_gate": w(D, F, scale=0.3), "shared_up": w(D, F, scale=0.3),
+            "shared_down": w(F, D, scale=0.3),
+            "shared_router": w(D, 1, scale=0.5)}
 
 
-def _dense_reference(x, gate_w, w_in, w_out, capacity):
-    """Same MoE math with all experts on one device."""
-    logits = x @ gate_w
-    idx, gate, pos, keep = top1_gating(logits, E, capacity)
-    buf = moe_dispatch(x, idx, pos, keep, E, capacity)
-    h = jax.nn.gelu(jnp.einsum("ecd,edh->ech", buf, w_in))
-    y = jnp.einsum("ech,ehd->ecd", h, w_out)
-    return moe_combine(y, idx, pos, keep, gate)
+def tokens(seed=1, t=T):
+    return jnp.asarray(np.random.RandomState(seed).randn(t, D), jnp.float32)
 
 
-def test_expert_parallel_matches_dense():
+def reference(p, x, first=0):
+    """``(routed part, shared part, counts)`` of the reference's layer."""
+    cfg = {"num_experts_per_tok": K, "first_expert": first}
+    whole, counts = R.routed_feed_forward(p, x, cfg)
+    shared = jax.nn.sigmoid(x @ p["shared_router"]) * R.swiglu(
+        x, p["shared_gate"], p["shared_up"], p["shared_down"])
+    return whole - shared, shared, counts
+
+
+def layer(p, x, **kw):
+    return expert_layer(x, p["router"], p["gate"], p["up"], p["down"],
+                        top_k=K, **kw)
+
+
+def share(p, lo, hi):
+    return {**p, **{k: p[k][lo:hi] for k in ("gate", "up", "down")}}
+
+
+def close(got, want, rtol=1e-5):
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=rtol * max(np.abs(want).max(), 1e-30))
+
+
+# --------------------------------------------------------- one device ----
+
+@pytest.mark.parametrize("first,held", [(0, 8), (4, 4), (6, 2)])
+def test_expert_layer_against_the_reference(first, held):
+    p, x = share(weights(), first, first + held), tokens()
+
+    def run(f):
+        return jax.jit(jax.value_and_grad(
+            lambda p, x: jnp.sum(f(p, x) ** 2), argnums=(0, 1)))(p, x)
+
+    keys = ("router", "gate", "up", "down")
+    got, (gp, gx) = run(lambda p, x: layer(p, x, first_expert=first)[0])
+    want, (wp, wx) = run(lambda p, x: reference(p, x, first)[0])
+    close(got, want)
+    close(gx, wx)
+    for k in keys:
+        close(gp[k], wp[k])
+    stats = jax.jit(lambda p, x: layer(p, x, first_expert=first)[1])(p, x)
+    counts = np.asarray(jax.jit(lambda p, x: reference(p, x, first)[2])(p, x))
+    assert float(stats["rows_here"]) == counts.sum()
+    assert float(stats["load_max_over_mean"]) == pytest.approx(
+        counts.max() / counts.mean())
+    assert float(stats["overflow_rows"]) == 0
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The share test: what both halves of the experts give, with the shared
+    expert (which every chip computes alike) counted once, is what the uncut
+    reference gives for the whole layer."""
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 24, 64), jnp.float32)
+    c = dataclasses.replace(Q.QWEN3_NEXT_TINY, experts_held=None)
+    whole = Q.SparseMoE(c)
+    p = jax.jit(whole.init)(jax.random.PRNGKey(0), x)["params"]
+    p = jax.tree.map(lambda w: w * 8, p)            # a router with opinions
+    cfg = {"num_experts_per_tok": c.num_experts_per_tok}
+    want = jax.jit(jax.vmap(
+        lambda t: R.routed_feed_forward(p, t, cfg)[0]))(x)
+    flat = x.reshape(-1, 64)
+    shared = (jax.nn.sigmoid(flat @ p["shared_router"]) * R.swiglu(
+        flat, p["shared_gate"], p["shared_up"], p["shared_down"])
+    ).reshape(x.shape)
+    halves, rows = [], 0
+    for first in (0, 4):
+        half = dataclasses.replace(c, experts_held=4, first_expert=first)
+        y, stats = jax.jit(Q.SparseMoE(half).apply)(
+            {"params": share(p, first, first + 4)}, x)
+        halves.append(y)
+        rows += float(stats[0])
+    close(halves[0] + halves[1] - shared, want)
+    close(jax.jit(whole.apply)({"params": p}, x)[0], want)
+    # every assignment lands on exactly one of the two shares
+    assert rows == x.shape[0] * x.shape[1] * c.num_experts_per_tok
+
+
+def test_top_k_by_argmax_is_lax_top_k():
+    r = np.random.RandomState(4)
+    p = jnp.asarray(r.rand(50, 16), jnp.float32)
+    p = p.at[:, 5].set(p[:, 2])                     # ties: lower index first
+    p = p.at[7].set(0.25)                           # a whole row of ties
+    want_v, want_i = jax.lax.top_k(p, 4)
+    got_v, got_i = top_k_of(p, 4)
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    w = jnp.asarray(r.randn(50, 4), jnp.float32)
+    close(jax.grad(lambda p: jnp.sum(top_k_of(p, 4)[0] * w))(p),
+          jax.grad(lambda p: jnp.sum(jax.lax.top_k(p, 4)[0] * w))(p))
+
+
+def test_routing_weights_are_normalised_over_all_the_chosen():
+    p, x = weights(), tokens()
+    idx, w = route(x, p["router"], K)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-6)
+    probs = jax.nn.softmax(x @ p["router"], -1)
+    _, raw = route(x, p["router"], K, norm_topk=False)
+    close(raw, jnp.take_along_axis(probs, idx, -1))
+    assert np.all(np.asarray(idx[:, 0]) != np.asarray(idx[:, 1]))
+
+
+@pytest.mark.parametrize("rows_bound", [200, 20, 7])
+def test_packed_rows_are_sorted_by_expert_then_token(rows_bound):
+    idx = jnp.asarray(np.random.RandomState(5).randint(0, 8, (40, 3)),
+                      jnp.int32)
+    flat, sizes, counts = (np.asarray(a) for a in pack_held(
+        idx, 2, 4, rows_bound))
+    ids = np.asarray(idx).reshape(-1)
+    held = [i for e in range(2, 6) for i in np.flatnonzero(ids == e)]
+    n = min(len(held), rows_bound)
+    assert list(flat[:n]) == held[:n]
+    assert np.all(flat[n:] == ids.size)
+    assert list(counts) == [np.sum(ids == e) for e in range(2, 6)]
+    assert sizes.sum() == n and np.all(sizes <= counts)
+    assert list(np.repeat(np.arange(4), sizes)) == list(ids[flat[:n]] - 2)
+
+
+# ------------------------------------------- past the bound of the rows ----
+
+def test_overflow_is_counted_and_the_kept_rows_are_right():
+    """What the old layer's capacity dropped in silence: assignments past
+    ``rows_bound`` are counted, and what is computed is exactly the
+    assignments that fit (the first experts' rows)."""
+    p, x = weights(), tokens()
+    full, stats = jax.jit(layer)(p, x)
+    counts = np.asarray(jax.jit(lambda p, x: reference(p, x)[2])(p, x))
+    bound = int(counts[:3].sum())                   # room for three experts
+    cut, cut_stats = jax.jit(
+        lambda p, x: layer(p, x, rows_bound=bound))(p, x)
+    assert float(cut_stats["overflow_rows"]) == counts.sum() - bound
+    assert float(cut_stats["rows_here"]) == float(stats["rows_here"])
+    three = share(p, 0, 3)
+    close(cut, jax.jit(layer)(three, x)[0])
+    assert not np.allclose(np.asarray(cut), np.asarray(full))
+
+
+def test_overflow_makes_the_loss_non_finite():
+    from autodist_tpu.models.train_lib import qwen3_next_capture
+
+    s = 24
+    batch = {"tokens": jnp.zeros((2, s), jnp.int32),
+             "targets": jnp.ones((2, s), jnp.int32)}
+    for bound, finite in ((None, True), (8, False)):
+        c = dataclasses.replace(Q.QWEN3_NEXT_TINY, rows_bound=bound,
+                                num_layers=1, full_attention_interval=1)
+        made = {}
+
+        def init(key):
+            made["loss_fn"], params, _ = qwen3_next_capture(c, s, rng=key)
+            return params
+
+        params = jax.jit(init)(jax.random.PRNGKey(0))
+        loss, aux = jax.jit(made["loss_fn"])(params, batch)
+        assert bool(np.isfinite(float(loss))) is finite
+        assert (float(aux["moe_overflow_rows"]) == 0) is finite
+        assert float(aux["moe_rows_here"]) > 8
+
+
+# ------------------------------------------------ over an expert axis ----
+
+@pytest.mark.parametrize("tokens_sharded", [False, True],
+                         ids=["tokens_replicated", "tokens_sharded"])
+def test_expert_parallel_matches_dense(tokens_sharded):
+    """Eight devices, one expert each: the parts summed over the axis are
+    the whole layer, whether every device brings all the tokens or an
+    eighth of them, and so are the gradients taken inside the
+    ``shard_map`` (the router's alike on every device)."""
     mesh = build_mesh(axes={"expert": 8})
-    gate_w, w_in, w_out = _weights()
-    r = np.random.RandomState(1)
-    x = jnp.asarray(r.randn(T, D), jnp.float32)
+    p, x = weights(), tokens()
+    keys = ("router", "gate", "up", "down")
 
-    capacity = max(1, (T * 2) // E)
-    want = _dense_reference(x, gate_w, w_in, w_out, capacity)
+    def dense(p, x):
+        y = reference(p, x)[0]
+        return jnp.sum(y ** 2), y
 
-    def f(x_, gw, wi, wo):
-        out, aux = expert_parallel_ffn(x_, gw, wi, wo, "expert")
-        return out, aux
+    def mine(p, x):
+        y, stats = expert_layer(x, p["router"], p["gate"], p["up"],
+                                p["down"], top_k=K, axis_name="expert",
+                                tokens_sharded=tokens_sharded)
+        return jnp.sum(y ** 2), (y, stats["rows_here"])
 
-    got, aux = jax.jit(jax.shard_map(
-        f, mesh=mesh,
-        in_specs=(jax.P(), jax.P(), jax.P("expert"), jax.P("expert")),
-        out_specs=(jax.P(), jax.P()),
+    def on_a_device(x, *ws):
+        (_, (y, rows)), (gp, gx) = jax.value_and_grad(
+            mine, argnums=(0, 1), has_aux=True)(dict(zip(keys, ws)), x)
+        return (y, jax.lax.psum(rows, "expert"), gx) \
+            + tuple(gp[k] for k in keys)
+
+    spec = jax.P("expert") if tokens_sharded else jax.P()
+    held = jax.P("expert")
+    got, rows, gx, *gp = jax.jit(jax.shard_map(
+        on_a_device, mesh=mesh,
+        in_specs=(spec, jax.P(), held, held, held),
+        out_specs=(spec, jax.P(), spec, jax.P(), held, held, held),
         check_vma=False,
-    ))(x, gate_w, w_in, w_out)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-    assert float(aux) > 0  # load-balance loss well-defined
-
-
-def test_expert_parallel_sharded_tokens():
-    """Tokens distributed over the expert axis: per-device routing, finite
-    outputs, correct shapes."""
-    mesh = build_mesh(axes={"expert": 8})
-    gate_w, w_in, w_out = _weights()
-    r = np.random.RandomState(2)
-    x = jnp.asarray(r.randn(T, D), jnp.float32)
-
-    def f(x_, gw, wi, wo):
-        out, aux = expert_parallel_ffn(x_, gw, wi, wo, "expert")
-        return out, jax.lax.pmean(aux, "expert")
-
-    got, aux = jax.jit(jax.shard_map(
-        f, mesh=mesh,
-        in_specs=(jax.P("expert"), jax.P(), jax.P("expert"), jax.P("expert")),
-        out_specs=(jax.P("expert"), jax.P()),
-        check_vma=False,
-    ))(x, gate_w, w_in, w_out)
-    assert got.shape == x.shape
-    assert np.isfinite(np.asarray(got)).all()
-
-
-def test_gating_capacity_drops_overflow():
-    logits = jnp.zeros((10, 2)).at[:, 0].set(1.0)  # all tokens pick expert 0
-    idx, gate, pos, keep = top1_gating(logits, 2, capacity=4)
-    assert int(keep.sum()) == 4  # only capacity tokens kept
-    assert np.all(np.asarray(idx) == 0)
+    ))(x, *(p[k] for k in keys))
+    (_, want), (wp, wx) = jax.value_and_grad(
+        dense, argnums=(0, 1), has_aux=True)(p, x)
+    close(got, want)
+    assert float(rows) == T * K
+    close(gx, wx)
+    for k, g in zip(keys, gp):
+        close(g, wp[k])

@@ -1,4 +1,10 @@
-"""PowerSGD compressor: low-rank fidelity + error-feedback convergence."""
+"""PowerSGD compressor: low-rank fidelity + error-feedback convergence.
+
+The long loops wait for every step: on a loaded host a long queue of
+unread steps starves the CPU backend's collectives (one of the eight device
+threads misses its rendezvous for 40 s and XLA aborts the interpreter).
+"""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -22,7 +28,7 @@ def test_rank1_gradient_captured_exactly():
     sess = ad.distribute(loss, p, optax.sgd(0.01))
     b = np.random.RandomState(0).randn(16, 64).astype(np.float32)
     for _ in range(20):
-        sess.run(b)
+        jax.block_until_ready(sess.run(b))
     got = sess.params()["w"]
     exp = -0.01 * 20 * np.outer(b.mean(0), np.ones(32))  # true SGD trajectory
     rel = np.abs(got - exp).max() / np.abs(exp).max()
@@ -45,7 +51,7 @@ def test_error_feedback_recovers_full_rank():
     sess = ad.distribute(loss, {"w": jnp.zeros((32, 16))}, optax.sgd(0.1))
     b = np.zeros((8, 1), np.float32)
     for _ in range(200):
-        sess.run(b)
+        jax.block_until_ready(sess.run(b))
     got = sess.params()["w"]
     exp = 0.1 * 200 * target
     rel = np.abs(got - exp).max() / np.abs(exp).max()

@@ -323,6 +323,48 @@ def test_second_variant_is_counted_and_warned_once(tmp_path):
     assert len(warned) == 1 and "dispatch 2" in warned[0]
 
 
+def _counting_loss(p, batch):
+    """A loss with auxiliary outputs, as a model with routing counters."""
+    return _loss(p, batch), {"rows_seen": jnp.sum(batch["y"] > 0) * 1.0,
+                             "a_vector": batch["y"][:2]}
+
+
+def test_auxiliary_counters_ride_on_a_later_run_span(tmp_path):
+    """A step's auxiliary scalars are written on the ``ad.run`` span of a
+    LATER dispatch, once the step has finished (its own ``run`` returns
+    while it still runs), with ``aux_step`` naming the step they are of;
+    with telemetry on they are registry gauges besides."""
+    telemetry.enable(run_dir=str(tmp_path / "run"))
+    ad = AutoDist(resource_spec=ResourceSpec.from_num_chips(8),
+                  strategy_builder=AllReduce())
+    sess = ad.distribute(_counting_loss, _params(), optax.adam(1e-2),
+                         has_aux=True)
+    batches = [_batch() for _ in range(4)]
+    seen = []
+
+    def go():
+        for b in batches:
+            metrics = sess.run(b)
+            jax.block_until_ready(metrics)      # so the next span has them
+            seen.append(float(metrics["rows_seen"]))
+
+    spans = _profiled_spans(tmp_path, go)
+    runs = [s for s in spans if s[0] == "ad.run"]
+    assert "aux_step" not in runs[0][3] and "rows_seen" not in runs[0][3]
+    for i, run in enumerate(runs[1:], start=1):
+        assert run[3]["aux_step"] == i - 1
+        assert run[3]["rows_seen"] == pytest.approx(seen[i - 1])
+        assert "a_vector" not in run[3]         # scalars only
+    # the engine's mean over the eight devices of each one's count
+    assert seen == [float(np.sum(b["y"] > 0)) / 8 for b in batches]
+    assert telemetry.get_registry().gauge_value("step.rows_seen") \
+        == pytest.approx(seen[-1])
+    # a loss without auxiliary outputs keeps nothing and writes nothing
+    plain = ad.distribute(_loss, _params(), optax.adam(1e-2))
+    plain.run(_batch())
+    assert not plain._aux_pending
+
+
 def test_loader_ready_counts_the_ring(tmp_path):
     import time
 

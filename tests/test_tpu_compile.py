@@ -117,6 +117,47 @@ def test_flash_attention_benchmark_shapes_and_signatures(one_chip, config,
     assert _kernel_results(text) == sorted(want)
 
 
+# the one softmax-attention layer of the benchmark's Qwen3-Next cell:
+# (B, S, query heads, head size) on QWEN3_NEXT_KV_HEADS K/V heads
+QWEN3_NEXT_ATTN = (4, 8192, 16, 256)
+QWEN3_NEXT_KV_HEADS = 2
+
+
+def test_flash_attention_qwen3_next_shape_and_signatures(one_chip):
+    """D = 256, eight query heads to a K/V head, S = 8,192: one head's K and
+    V rows, double-buffered, pass the 14 MiB budget, so by their own rule
+    the kernels take one head a program under the raised limit and walk the
+    k tiles in a loop (sixteen tiles are too many static cases).  The result
+    signatures are what ``benchmark/layer_metrics/full_attn_roofline.py``
+    tells the kernels apart by: forward two results of different shapes,
+    dq one, dk/dv two of one shape (float32, one per query head)."""
+    b, s, h, d = QWEN3_NEXT_ATTN
+    assert F._pick_heads(b * h, h, h // QWEN3_NEXT_KV_HEADS, False, s, s, d,
+                         2, 512, 512) == 1
+    assert not F._prefix(True, s, s, 512, s)
+    assert F._VMEM_BUDGET < F._vmem_bytes(1, s, s, d, 2, 512, 512) \
+        <= F._VMEM_LIMIT * 3 // 4
+    q = _aval(one_chip, (b, s, h, d), jnp.bfloat16)
+    kv = _aval(one_chip, (b, s, QWEN3_NEXT_KV_HEADS, d), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = F.flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    shapes = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            result = line.split(" = ", 1)[1].split(" custom-call(", 1)[0]
+            shapes.append([p.split("{")[0]
+                           for p in result.strip("()").split("}, ")])
+    bh = b * h
+    want = [[f"bf16[{bh},{s},{d}]", f"f32[{bh},1,{s}]"],
+            [f"bf16[{bh},{s},{d}]"],
+            [f"f32[{bh},{s},{d}]", f"f32[{bh},{s},{d}]"]]
+    assert sorted(shapes) == sorted(want)
+
+
 def test_flash_block_update_ring_step(one_chip):
     # one ring step of a device that holds S=1024 positions of 2 x 12 heads
     bh, s, d = 2 * GPT_ATTN[2], 1024, GPT_ATTN[3]

@@ -657,15 +657,16 @@ def main():
         return {"mesh": "replica2 x model2"}
 
     def expert_parallel():
-        """MoE expert parallelism — expert-sharded FFN weights, tokens
-        all_to_all-routed over the expert axis — as an engine step for 4
-        v5e targets, the all-to-all asserted in the HLO."""
+        """MoE expert parallelism: each device of the expert axis holds its
+        share of the routed experts (``parallel/moe.py``), computes its part
+        with grouped matrix products and the parts are summed over the axis,
+        as an engine step for 4 v5e targets."""
         import optax
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         from autodist_tpu.kernel.graph_transformer import GraphTransformer
         from autodist_tpu.model_item import ModelItem
-        from autodist_tpu.parallel.moe import expert_parallel_ffn
+        from autodist_tpu.parallel.moe import expert_layer
         from autodist_tpu.resource_spec import ResourceSpec
         from autodist_tpu.strategy import AllReduce
         from autodist_tpu.strategy.base import StrategyCompiler
@@ -677,14 +678,15 @@ def main():
             "mesh": {"replica": 4 // ep, "expert": ep}})
         rr = np.random.RandomState(5)
         params = {
-            "gate": jnp.asarray(rr.randn(D, E) * 0.3, jnp.float32),
-            "w_in": jnp.asarray(rr.randn(E, D, H) * 0.2, jnp.float32),
-            "w_out": jnp.asarray(rr.randn(E, H, D) * 0.2, jnp.float32)}
+            "router": jnp.asarray(rr.randn(D, E) * 0.3, jnp.float32),
+            "gate": jnp.asarray(rr.randn(E, D, H) * 0.2, jnp.float32),
+            "up": jnp.asarray(rr.randn(E, D, H) * 0.2, jnp.float32),
+            "down": jnp.asarray(rr.randn(E, H, D) * 0.2, jnp.float32)}
 
         def loss(p, b):
-            out, aux = expert_parallel_ffn(b, p["gate"], p["w_in"],
-                                           p["w_out"], "expert")
-            return jnp.mean(out ** 2) + 0.01 * aux
+            out, _ = expert_layer(b, p["router"], p["gate"], p["up"],
+                                  p["down"], top_k=2, axis_name="expert")
+            return jnp.mean(out ** 2)
 
         item = ModelItem(loss, params, optax.sgd(0.05))
         strat = StrategyCompiler(item, spec).compile(
@@ -692,15 +694,16 @@ def main():
         mesh = Mesh(np.array(topo.devices).reshape(4 // ep, ep),
                     ("replica", "expert"))
         t = GraphTransformer(strat, item, mesh, data_axes=("replica",),
-                             param_specs={"w_in": P("expert"),
-                                          "w_out": P("expert")})
+                             param_specs={k: P("expert")
+                                          for k in ("gate", "up", "down")})
         bsh = NamedSharding(mesh, P("replica"))
-        bav = jax.ShapeDtypeStruct((16, D), jnp.float32, sharding=bsh)
+        bav = jax.ShapeDtypeStruct((256, D), jnp.float32, sharding=bsh)
         step = t.make_train_step(donate=False)
         lowered = step.trace(t.abstract_state(), bav).lower(
             lowering_platforms=("tpu",))
         txt = lowered.compile().as_text()
-        assert "all-to-all" in txt, "no all-to-all token routing in HLO"
+        assert "ragged-dot" in txt, "no grouped matrix product in the HLO"
+        assert "all-reduce" in txt, "the parts are not summed over the axis"
         return {"experts": E, "expert_axis": ep}
 
     check("flash_attention_fwd", flash_fwd)
