@@ -32,11 +32,23 @@ the chunks carries the state in float32 and does four small products a step.
 The matrix products take their operands in ``dtype`` (bfloat16 in training)
 and accumulate in float32; the decays, the triangular inverse and the
 carried state are float32 whatever ``dtype`` is.
+
+That scan form is the definition the tests hold to the recurrence, and what
+runs on a CPU and at head widths a TPU kernel does not tile.  On a TPU, at
+heads whose width is a multiple of 128 and chunks of 64,
+``chunk_gated_delta_rule`` hands the same arguments to the Pallas kernels of
+``ops/pallas/gated_delta.py``: the same equations with a chunk's operands,
+the inverse and the state in VMEM, forward and backward (the tests run them
+in the Pallas interpreter against the same recurrence).  Nothing selects the
+path but the backend and the shapes.
 """
 import math
 
 import jax
 import jax.numpy as jnp
+
+from autodist_tpu.ops.pallas import flash_attention
+from autodist_tpu.ops.pallas import gated_delta as kernels
 
 # float32 products as three bfloat16 passes: 2**-16 a product, where one
 # pass (the TPU's default for float32) would leave the inverse at 2**-8
@@ -103,7 +115,14 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk_size=64, dtype=None):
 
     Returns ``o`` of ``[B, S, H_v, d_v]`` in ``dtype``.
     """
+    # the kernels on a TPU (asked as the flash kernels ask, so that a
+    # deviceless compile for a TPU takes the same path) where they tile
+    # the heads and the chunk inside VMEM; this scan form everywhere else
     dtype = jnp.dtype(dtype or v.dtype)
+    if flash_attention._on_tpu() and kernels.tiles(
+            chunk_size, q.shape[-1], v.shape[-1], v.shape[2] // q.shape[2],
+            dtype.itemsize):
+        return kernels.gated_delta_rule(q, k, v, g, beta, chunk_size, dtype)
     b, s, h_v, _ = v.shape
     rep = h_v // q.shape[2]
     if rep * q.shape[2] != h_v:

@@ -34,6 +34,10 @@ def _rand(shape, dtype=jnp.float32, seed=0):
     return jnp.asarray(np.random.RandomState(seed).randn(*shape), dtype)
 
 
+def jit_grad(f, **kw):
+    return jax.jit(jax.grad(f, **kw))
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_forward_matches_xla(causal):
     q, k, v = (_rand((2, 64, 2, 32), seed=i) for i in range(3))
@@ -82,8 +86,8 @@ def test_gradients_match_xla(causal, masked):
         return jnp.sum(jnp.sin(ref_attn(q, k, v, causal=causal,
                                         kv_mask=kv_mask)))
 
-    g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    g1 = jit_grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    g2 = jit_grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=1e-4)
 
@@ -118,8 +122,8 @@ def test_folds_tiles_and_dtypes_match_xla(b, h, s, dtype, causal):
 
     np.testing.assert_allclose(flash(q, k, v).astype(np.float32),
                                ref(q, k, v).astype(np.float32), atol=atol_out)
-    g1 = jax.grad(f(flash), argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(f(ref), argnums=(0, 1, 2))(q, k, v)
+    g1 = jit_grad(f(flash), argnums=(0, 1, 2))(q, k, v)
+    g2 = jit_grad(f(ref), argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g1, g2):
         assert a.dtype == dtype
         np.testing.assert_allclose(a.astype(np.float32),
@@ -176,8 +180,8 @@ def test_rectangular_causal_matches_xla():
         return ref_attn(q, k, v, causal=True)
 
     np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=1e-5)
-    for a, b in zip(jax.grad(f(flash), argnums=(0, 1, 2))(q, k, v),
-                    jax.grad(f(ref), argnums=(0, 1, 2))(q, k, v)):
+    for a, b in zip(jit_grad(f(flash), argnums=(0, 1, 2))(q, k, v),
+                    jit_grad(f(ref), argnums=(0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(a, b, atol=1e-4)
 
 
@@ -198,8 +202,8 @@ def test_no_mask_build_equals_all_true_mask_build(causal):
         flash_attention(q, k, v, causal=causal, block_q=32, block_k=32),
         flash_attention(q, k, v, causal=causal, kv_mask=mask, block_q=32,
                         block_k=32))
-    for a, b in zip(jax.grad(f(None), argnums=(0, 1, 2))(q, k, v),
-                    jax.grad(f(mask), argnums=(0, 1, 2))(q, k, v)):
+    for a, b in zip(jit_grad(f(None), argnums=(0, 1, 2))(q, k, v),
+                    jit_grad(f(mask), argnums=(0, 1, 2))(q, k, v)):
         np.testing.assert_array_equal(a, b)
 
 
@@ -280,8 +284,8 @@ def test_gqa_matches_repeated_heads(kv_heads):
     np.testing.assert_allclose(
         flash_attention(q, k, v, causal=True, block_q=32, block_k=32),
         ref_attn(q, rep(k), rep(v), causal=True), atol=1e-5)
-    g1 = jax.grad(f_gqa, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(f_rep, argnums=(0, 1, 2))(q, k, v)
+    g1 = jit_grad(f_gqa, argnums=(0, 1, 2))(q, k, v)
+    g2 = jit_grad(f_rep, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=1e-4)
 
@@ -302,8 +306,9 @@ def test_gpt_gqa_decode_matches_full_forward():
     out = np.asarray(gpt.generate(cfg, params, prompt, max_new_tokens=4))
     # oracle: recompute each next token with the full (cache-free) forward
     seq = prompt.copy()
+    forward = jax.jit(lambda p, s: gpt.GPT(cfg).apply({"params": p}, s))
     for _ in range(4):
-        logits = gpt.GPT(cfg).apply({"params": params}, jnp.asarray(seq))
+        logits = forward(params, jnp.asarray(seq))
         nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))[:, None]
         seq = np.concatenate([seq, nxt.astype(np.int32)], axis=1)
     np.testing.assert_array_equal(out, seq)
@@ -321,8 +326,8 @@ def test_gpt_flash_matches_xla():
         logits = gpt.GPT(cfg).apply({"params": p}, tokens)
         return gpt.gpt_loss(logits, tokens)
 
-    lx, gx = jax.value_and_grad(lambda p: loss(cfg_x, p))(params)
-    lf, gf = jax.value_and_grad(lambda p: loss(cfg_f, p))(params)
+    lx, gx = jax.jit(jax.value_and_grad(lambda p: loss(cfg_x, p)))(params)
+    lf, gf = jax.jit(jax.value_and_grad(lambda p: loss(cfg_f, p)))(params)
     np.testing.assert_allclose(lf, lx, rtol=1e-5)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=1e-4),
                  gf, gx)
@@ -347,8 +352,8 @@ def test_bert_flash_matches_xla_with_padding_mask():
         # compare only valid positions (padded-query rows differ by design)
         return jnp.sum(jnp.sin(x) * mask[:, :, None])
 
-    vx, gx = jax.value_and_grad(lambda p: pooled(model_x, p))(params)
-    vf, gf = jax.value_and_grad(lambda p: pooled(model_f, p))(params)
+    vx, gx = jax.jit(jax.value_and_grad(lambda p: pooled(model_x, p)))(params)
+    vf, gf = jax.jit(jax.value_and_grad(lambda p: pooled(model_f, p)))(params)
     np.testing.assert_allclose(vf, vx, rtol=1e-5)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-3,
                                                          atol=1e-3),

@@ -34,6 +34,10 @@ def _mk(shape, dtype, seed=0):
     return jnp.asarray(r.randn(*shape), dtype)
 
 
+def jit_grad(f, **kw):
+    return jax.jit(jax.grad(f, **kw))
+
+
 # -- fused batch norm: forward equivalence -----------------------------------
 
 
@@ -84,9 +88,9 @@ def test_fused_bn_grad_matches_reference(act, residual):
         y = fn(x, s, b, act=act, residual=r)[0]
         return jnp.sum(y * w)
 
-    g_fused = jax.grad(lambda *a: loss(fused_batch_norm, *a),
+    g_fused = jit_grad(lambda *a: loss(fused_batch_norm, *a),
                        argnums=(0, 1, 2))(x, scale, bias, res)
-    g_ref = jax.grad(lambda *a: loss(batch_norm_reference, *a),
+    g_ref = jit_grad(lambda *a: loss(batch_norm_reference, *a),
                      argnums=(0, 1, 2))(x, scale, bias, res)
     for gf, gr in zip(g_fused, g_ref):
         np.testing.assert_allclose(gf, gr, atol=5e-4, rtol=5e-4)
@@ -100,8 +104,8 @@ def test_fused_bn_grad_bf16_tracks_reference():
     def loss(fn, x):
         return jnp.sum(fn(x, scale, bias, act="relu")[0].astype(jnp.float32))
 
-    gf = jax.grad(lambda x: loss(fused_batch_norm, x))(x)
-    gr = jax.grad(lambda x: loss(batch_norm_reference, x))(x)
+    gf = jit_grad(lambda x: loss(fused_batch_norm, x))(x)
+    gr = jit_grad(lambda x: loss(batch_norm_reference, x))(x)
     np.testing.assert_allclose(np.asarray(gf, np.float32),
                                np.asarray(gr, np.float32),
                                atol=5e-2, rtol=5e-2)
@@ -115,8 +119,8 @@ def test_fused_bn_residual_cotangent_flows():
     def loss(fn, r):
         return jnp.sum(fn(x, scale, bias, act="relu", residual=r)[0])
 
-    gf = jax.grad(lambda r: loss(fused_batch_norm, r))(res)
-    gr = jax.grad(lambda r: loss(batch_norm_reference, r))(res)
+    gf = jit_grad(lambda r: loss(fused_batch_norm, r))(res)
+    gr = jit_grad(lambda r: loss(batch_norm_reference, r))(res)
     np.testing.assert_allclose(gf, gr, atol=5e-4, rtol=5e-4)
 
 
@@ -145,9 +149,9 @@ def test_fused_gn_grad_matches_reference():
     def loss(fn, x, s, b):
         return jnp.sum(fn(x, s, b, 8, act="relu") * w)
 
-    g_fused = jax.grad(lambda *a: loss(fused_group_norm, *a),
+    g_fused = jit_grad(lambda *a: loss(fused_group_norm, *a),
                        argnums=(0, 1, 2))(x, scale, bias)
-    g_ref = jax.grad(lambda *a: loss(group_norm_reference, *a),
+    g_ref = jit_grad(lambda *a: loss(group_norm_reference, *a),
                      argnums=(0, 1, 2))(x, scale, bias)
     for gf, gr in zip(g_fused, g_ref):
         np.testing.assert_allclose(gf, gr, atol=5e-4, rtol=5e-4)

@@ -99,8 +99,9 @@ def test_generate_kv_cache_matches_full_forward():
 
     # naive rollout: full forward over the sequence so far, argmax last
     seq = prompt.copy()
+    forward = jax.jit(lambda p, s: model.apply({"params": p}, s))
     for _ in range(NEW):
-        logits = model.apply({"params": params}, jnp.asarray(seq))
+        logits = forward(params, jnp.asarray(seq))
         nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
         seq = np.concatenate([seq, nxt[:, None]], axis=1)
 

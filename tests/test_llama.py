@@ -60,8 +60,9 @@ def test_decode_cache_matches_full_forward():
     prompt = _batch()["tokens"][:2, :4]
     out = np.asarray(llama.generate(CFG, params, prompt, 5))
     seq = np.asarray(prompt).copy()
+    forward = jax.jit(lambda p, s: llama.Llama(CFG).apply({"params": p}, s))
     for _ in range(5):
-        lg = llama.Llama(CFG).apply({"params": params}, jnp.asarray(seq))
+        lg = forward(params, jnp.asarray(seq))
         nxt = np.asarray(jnp.argmax(lg[:, -1], axis=-1))[:, None]
         seq = np.concatenate([seq, nxt.astype(np.int32)], axis=1)
     np.testing.assert_array_equal(out, seq)
@@ -78,8 +79,8 @@ def test_flash_matches_xla():
         return llama.llama_loss(
             llama.Llama(cfg).apply({"params": p}, toks), toks)
 
-    lx, gx = jax.value_and_grad(lambda p: loss(CFG, p))(params)
-    lf, gf = jax.value_and_grad(lambda p: loss(cfg_f, p))(params)
+    lx, gx = jax.jit(jax.value_and_grad(lambda p: loss(CFG, p)))(params)
+    lf, gf = jax.jit(jax.value_and_grad(lambda p: loss(cfg_f, p)))(params)
     np.testing.assert_allclose(lf, lx, rtol=1e-5)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-3,
                                                          atol=1e-4), gf, gx)

@@ -12,6 +12,14 @@ from autodist_tpu.ops.losses import streaming_softmax_xent
 N, D, V = 24, 16, 96
 
 
+def jit_grad(f, **kw):
+    return jax.jit(jax.grad(f, **kw))
+
+
+def jit_value_and_grad(f, **kw):
+    return jax.jit(jax.value_and_grad(f, **kw))
+
+
 def dense_xent(hidden, table, targets, valid=None, bias=None):
     """Reference: materialized (N, V) logits, weighted-mean NLL with the
     dense ``gpt_loss`` mask semantics (weights multiply numerator AND
@@ -57,9 +65,9 @@ def test_dv_layout_matches(data, chunk):
     got = streaming_softmax_xent(h, table_dv, t, chunk=chunk, layout="dv")
     want = dense_xent(h, table, t)
     np.testing.assert_allclose(got, want, rtol=1e-6)
-    g_s = jax.grad(lambda w: streaming_softmax_xent(
+    g_s = jit_grad(lambda w: streaming_softmax_xent(
         h, w, t, chunk=chunk, layout="dv"))(table_dv)
-    g_d = jax.grad(lambda w: dense_xent(h, w, t))(table)
+    g_d = jit_grad(lambda w: dense_xent(h, w, t))(table)
     np.testing.assert_allclose(g_s, np.asarray(g_d).T, rtol=2e-5, atol=1e-6)
 
 
@@ -67,10 +75,10 @@ def test_dv_layout_matches(data, chunk):
 def test_grads_match_dense(data, chunk):
     h, table, t = data
 
-    g_s = jax.grad(lambda hh, w: streaming_softmax_xent(hh, w, t,
+    g_s = jit_grad(lambda hh, w: streaming_softmax_xent(hh, w, t,
                                                         chunk=chunk),
                    argnums=(0, 1))(h, table)
-    g_d = jax.grad(lambda hh, w: dense_xent(hh, w, t),
+    g_d = jit_grad(lambda hh, w: dense_xent(hh, w, t),
                    argnums=(0, 1))(h, table)
     np.testing.assert_allclose(g_s[0], g_d[0], rtol=2e-5, atol=1e-6)
     np.testing.assert_allclose(g_s[1], g_d[1], rtol=2e-5, atol=1e-6)
@@ -82,9 +90,9 @@ def test_bias_variant(data):
     got = streaming_softmax_xent(h, table, t, bias=bias, chunk=32)
     want = dense_xent(h, table, t, bias=bias)
     np.testing.assert_allclose(got, want, rtol=1e-6)
-    g_s = jax.grad(lambda hh: streaming_softmax_xent(
+    g_s = jit_grad(lambda hh: streaming_softmax_xent(
         hh, table, t, bias=bias, chunk=32))(h)
-    g_d = jax.grad(lambda hh: dense_xent(hh, table, t, bias=bias))(h)
+    g_d = jit_grad(lambda hh: dense_xent(hh, table, t, bias=bias))(h)
     np.testing.assert_allclose(g_s, g_d, rtol=2e-5, atol=1e-6)
 
 
@@ -147,8 +155,8 @@ def test_gpt_capture_streaming_matches_dense():
     chex = jax.tree_util.tree_structure(params)
     assert chex == jax.tree_util.tree_structure(params_s)
 
-    ld, gd = jax.value_and_grad(loss_d)(params, batch, rng)
-    ls, gs = jax.value_and_grad(loss_s)(params, batch, rng)
+    ld, gd = jit_value_and_grad(loss_d)(params, batch, rng)
+    ls, gs = jit_value_and_grad(loss_s)(params, batch, rng)
     np.testing.assert_allclose(ld, ls, rtol=1e-5)
     for (kd, vd), (ks, vs) in zip(
             jax.tree_util.tree_leaves_with_path(gd),
@@ -168,8 +176,8 @@ def test_llama_capture_streaming_matches_dense():
     loss_s, _, _ = train_lib.llama_capture(LLAMA_TINY, 16,
                                            streaming_loss=True,
                                            loss_chunk=64)
-    ld, gd = jax.value_and_grad(loss_d)(params, batch)
-    ls, gs = jax.value_and_grad(loss_s)(params, batch)
+    ld, gd = jit_value_and_grad(loss_d)(params, batch)
+    ls, gs = jit_value_and_grad(loss_s)(params, batch)
     np.testing.assert_allclose(ld, ls, rtol=1e-5)
     for (kd, vd), (ks, vs) in zip(
             jax.tree_util.tree_leaves_with_path(gd),

@@ -49,7 +49,8 @@ def test_bf16_bn_stats_close_to_f32():
         model = ResNet18(num_classes=10, num_filters=8, dtype=jnp.bfloat16,
                          bn_f32_stats=f32)
         v = model.init(jax.random.PRNGKey(0), x, train=True)
-        y, _ = model.apply(v, x, train=True, mutable=["batch_stats"])
+        y, _ = jax.jit(lambda v, x: model.apply(
+            v, x, train=True, mutable=["batch_stats"]))(v, x)
         outs[f32] = np.asarray(y, np.float32)
     # same function up to bf16 stats rounding
     np.testing.assert_allclose(outs[True], outs[False], atol=0.15)
@@ -155,17 +156,18 @@ def test_space_to_depth_stem_is_exact_reparametrization():
     m_s2d = ResNet50(num_classes=10, dtype=jnp.float32,
                      stem="space_to_depth")
     v = m_conv.init(jax.random.PRNGKey(0), x, train=False)
-    v2 = m_s2d.init(jax.random.PRNGKey(0), x, train=False)
+    v2 = jax.eval_shape(lambda: m_s2d.init(jax.random.PRNGKey(0), x,
+                                           train=False))
     # copy every param; replace the stem kernel with its reindexing
     p2 = jax.tree.map(lambda a: a, v["params"])
     assert v2["params"]["conv_init"]["kernel"].shape == (4, 4, 12, 64)
     p2["conv_init"] = {"kernel": conv7_to_s2d_kernel(
         v["params"]["conv_init"]["kernel"])}
-    y1 = m_conv.apply({"params": v["params"], **{k: w for k, w in v.items()
-                                                if k != "params"}}, x,
-                      train=False)
-    y2 = m_s2d.apply({"params": p2, **{k: w for k, w in v.items()
-                                       if k != "params"}}, x, train=False)
+    stats = {k: w for k, w in v.items() if k != "params"}
+    y1 = jax.jit(lambda p: m_conv.apply({"params": p, **stats}, x,
+                                        train=False))(v["params"])
+    y2 = jax.jit(lambda p: m_s2d.apply({"params": p, **stats}, x,
+                                       train=False))(p2)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                atol=2e-4, rtol=2e-4)
 
@@ -195,8 +197,8 @@ def test_remat_is_value_exact():
     def loss(cfg, p):
         return gpt.gpt_loss(gpt.GPT(cfg).apply({"params": p}, tokens), tokens)
 
-    l0, g0 = jax.value_and_grad(lambda p: loss(cfg0, p))(params)
-    l1, g1 = jax.value_and_grad(lambda p: loss(cfg1, p))(params)
+    l0, g0 = jax.jit(jax.value_and_grad(lambda p: loss(cfg0, p)))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(lambda p: loss(cfg1, p)))(params)
     assert float(jnp.abs(l0 - l1)) == 0.0
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=1e-5,
                                                          rtol=1e-4),
@@ -213,8 +215,8 @@ def test_remat_is_value_exact():
 
     def f1(p_):
         return jnp.sum(jnp.sin(m1.apply({"params": p_}, ids)[0]))
-    v0, gg0 = jax.value_and_grad(f0)(p)
-    v1, gg1 = jax.value_and_grad(f1)(p)
+    v0, gg0 = jax.jit(jax.value_and_grad(f0))(p)
+    v1, gg1 = jax.jit(jax.value_and_grad(f1))(p)
     assert float(jnp.abs(v0 - v1)) == 0.0
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=1e-5,
                                                          rtol=1e-4),

@@ -12,7 +12,15 @@ delta rule kept in bfloat16 (2**-9 = 2e-3 a rounding, forgotten again at the
 rate the state decays) is wrong by 1.4e-3 at this size, fourteen times the
 tolerance, and ``test_a_bfloat16_state_would_fail`` holds the tolerance to
 that.
+
+The rule's Pallas kernels (``ops/pallas/gated_delta.py``) run here in the
+Pallas interpreter, at sizes no TPU would tile, against the same recurrence
+at the same tolerance, output and every gradient; their inverse's float32
+products are three bfloat16 passes here as on the chip, and the cases at the
+end show that one pass, or a bfloat16 state, is out of the tolerance.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,6 +32,7 @@ from autodist_tpu.models import qwen3_next as Q
 from autodist_tpu.models.train_lib import qwen3_next_capture
 from autodist_tpu.ops.gated_delta import (chunk_gated_delta_rule,
                                           inv_unit_lower)
+from autodist_tpu.ops.pallas import gated_delta as K
 
 C = Q.QWEN3_NEXT_TINY          # hidden 64, 4 layers, 8 experts of which 4
 S = 48                         # held, top-2, vocabulary 128, chunks of 16
@@ -117,21 +126,46 @@ def test_chunked_rule_against_the_recurrence(s, chunk):
     close(got, jax.jit(rule_reference)(*x))
 
 
-@pytest.mark.parametrize("s,chunk", [(70, 4)])
-def test_chunked_rule_gradients_against_the_recurrence(s, chunk):
-    x = rule_inputs(3, s)
-    w = jnp.asarray(np.random.RandomState(4).randn(2, s, 4, 8), jnp.float32)
+_RULE = {}         # (path, seed, s, chunk, shape, dtype) -> (o, gradients)
 
-    def run(f):
-        return jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * w),
-                                argnums=range(5)))(*x)
 
-    got = run(lambda *a: chunk_gated_delta_rule(*a, chunk_size=chunk))
-    for g, want, name in zip(got, run(rule_reference), "q k v g beta".split()):
+def rule_and_gradients(path, seed, s, chunk, dtype=jnp.float32, **shape):
+    """``o`` and the gradients of ``sum(o * w)`` by ``q, k, v, g, beta``
+    through one of the three paths, computed once a module."""
+    key = (path, seed, s, chunk, jnp.dtype(dtype).name,
+           tuple(sorted(shape.items())))
+    if key not in _RULE:
+        x = rule_inputs(seed, s, **shape)
+        w = jnp.asarray(np.random.RandomState(4).randn(*x[2].shape),
+                        jnp.float32)
+        f = {"reference": rule_reference,
+             "scan": functools.partial(chunk_gated_delta_rule,
+                                       chunk_size=chunk, dtype=dtype),
+             "kernels": functools.partial(K.gated_delta_rule,
+                                          chunk_size=chunk, dtype=dtype,
+                                          interpret=True)}[path]
+
+        def run(*a):
+            o, back = jax.vjp(f, *a)
+            return o, back(w.astype(o.dtype))
+
+        _RULE[key] = jax.jit(run)(*x)
+    return _RULE[key]
+
+
+def rules_close(got, want, rtol=RTOL):
+    close(got[0], want[0], rtol)
+    for g, w, name in zip(got[1], want[1], "q k v g beta".split()):
         try:
-            close(g, want)
+            close(g, w, rtol)
         except AssertionError as e:
             raise AssertionError("d" + name + str(e)) from e
+
+
+@pytest.mark.parametrize("s,chunk", [(70, 4)])
+def test_chunked_rule_gradients_against_the_recurrence(s, chunk):
+    rules_close(rule_and_gradients("scan", 3, s, chunk),
+                rule_and_gradients("reference", 3, s, chunk))
 
 
 def test_a_bfloat16_state_would_fail():
@@ -168,6 +202,89 @@ def test_unit_lower_inverse_and_its_gradient():
     want = jax.grad(lambda a: jnp.sum(
         jnp.linalg.inv(eye + jnp.tril(a, -1)) * w))(a)
     close(got, want)
+
+
+# ------------------------------------------- the delta rule's kernels ----
+
+KERNEL_CASES = [
+    # S not a whole number of chunks, one block
+    pytest.param(41, 16, dict(h_k=1, h_v=1, b=1), id="ragged"),
+    # eighteen chunks: two blocks (the second padded), the state and its
+    # cotangent carried from grid step to grid step; two value heads on
+    # each of two key heads
+    pytest.param(70, 4, dict(h_k=2, h_v=4, b=1), id="two-blocks"),
+    # shorter than a chunk, a value head a key head, two sequences
+    pytest.param(7, 16, dict(h_k=2, h_v=2, b=2), id="short")]
+
+
+@pytest.mark.parametrize("s,chunk,shape", KERNEL_CASES)
+def test_rule_kernels_against_the_recurrence(s, chunk, shape):
+    rules_close(rule_and_gradients("kernels", 3, s, chunk, **shape),
+                rule_and_gradients("reference", 3, s, chunk, **shape))
+
+
+@pytest.mark.parametrize("dtype,rtol", [(jnp.float32, RTOL),
+                                        (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_rule_kernels_against_the_scan_form(dtype, rtol):
+    """The two paths of ``chunk_gated_delta_rule`` against each other: in
+    float32 at the tolerance each holds against the recurrence; with
+    bfloat16 operands in their products (the benchmark's cell) to a few
+    bfloat16 roundings, since the two round different intermediates."""
+    shape = dict(h_k=2, h_v=4, b=1)
+    got = rule_and_gradients("kernels", 3, 70, 4, dtype, **shape)
+    want = rule_and_gradients("scan", 3, 70, 4, dtype, **shape)
+    assert got[0].dtype == want[0].dtype == dtype
+    rules_close(jax.tree.map(lambda t: t.astype(jnp.float32), got),
+                jax.tree.map(lambda t: t.astype(jnp.float32), want), rtol)
+
+
+@pytest.mark.parametrize("what", ["state", "inverse"])
+def test_rule_kernels_with_a_bfloat16_state_or_inverse_would_fail(
+        what, monkeypatch):
+    """The tolerance sees the kernels' precision: with the state rounded to
+    bfloat16 after every chunk, or the inverse's float32 products made in one
+    bfloat16 pass instead of three, they are out of it."""
+    if what == "state":
+        whole = K._next_state
+        monkeypatch.setattr(K, "_next_state", lambda *a: whole(*a).astype(
+            jnp.bfloat16).astype(jnp.float32))
+    else:
+        monkeypatch.setattr(K, "_split", lambda x: (
+            x.astype(jnp.bfloat16), jnp.zeros(x.shape, jnp.bfloat16)))
+    shape = dict(h_k=1, h_v=1, b=1)
+    x = rule_inputs(3, 41, **shape)
+    w = jnp.asarray(np.random.RandomState(4).randn(*x[2].shape), jnp.float32)
+
+    @jax.jit
+    def run(*a):
+        o, back = jax.vjp(functools.partial(K.gated_delta_rule, chunk_size=16,
+                                            interpret=True), *a)
+        return (o,) + back(w)
+
+    want = rule_and_gradients("reference", 3, 41, 16, **shape)
+    worst = max(float(jnp.max(jnp.abs(g - t)) / jnp.max(jnp.abs(t)))
+                for g, t in zip(run(*x), (want[0],) + want[1]))
+    assert worst > 1.5 * RTOL, worst
+
+
+def test_rule_kernels_are_for_heads_of_whole_lane_tiles():
+    """Who takes which path: the kernels take heads of whole 128-lane tiles
+    and chunks of 64 where a block fits their VMEM; everything else, and
+    every CPU run, is the scan form's (``chunk_gated_delta_rule`` asks the
+    backend as the flash kernels do)."""
+    assert K.tiles(64, 128, 128) and K.tiles(64, 128, 256)
+    assert not K.tiles(64, 8, 8) and not K.tiles(64, 128, 64)
+    assert not K.tiles(16, 128, 128) and not K.tiles(128, 128, 128)
+    # the benchmark's cell, and what would not fit the backward kernel's VMEM
+    assert K.tiles(64, 128, 128, rep=2, itemsize=2)
+    assert not K.tiles(64, 256, 256, rep=2) and not K.tiles(64, 128, 128, 4)
+    assert jax.default_backend() == "cpu"
+    with jax.ensure_compile_time_eval():
+        text = jax.jit(functools.partial(
+            chunk_gated_delta_rule, chunk_size=64)).lower(
+                *rule_inputs(3, 128, h_k=1, h_v=1, d=128, b=1)).as_text()
+    assert "while" in text and "custom_call" not in text
 
 
 # ------------------------------------------------------------- mixers ----

@@ -86,8 +86,9 @@ def test_ring_flash_gradients_match_xla_ring(causal):
                                               causal=causal, impl=impl),
             mesh=mesh, in_specs=(jax.P(None, "replica"),) * 3,
             out_specs=jax.P(None, "replica"), check_vma=False)
-        return jax.grad(lambda q_, k_, v_: jnp.sum(jnp.sin(f(q_, k_, v_))),
-                        argnums=(0, 1, 2))
+        return jax.jit(jax.grad(
+            lambda q_, k_, v_: jnp.sum(jnp.sin(f(q_, k_, v_))),
+            argnums=(0, 1, 2)))
 
     g_flash = make("flash")(q, k, v)
     g_xla = make("xla")(q, k, v)

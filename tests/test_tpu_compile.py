@@ -22,6 +22,7 @@ from autodist_tpu.models import FusedBatchNorm
 from autodist_tpu.models.llama import LlamaConfig
 from autodist_tpu.ops.pallas import flash_attention as F
 from autodist_tpu.ops.pallas import fused_norm as N
+from autodist_tpu.ops.pallas import gated_delta as G
 from autodist_tpu.ops.pallas import quantize as Q
 
 # GPT-2-small's training shape in chip_smoke.py: (B, S, H, D)
@@ -60,8 +61,15 @@ def _aval(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+# tests/conftest.py turns the backend's optimisations off for the CPU's sake;
+# the compiler for the chip runs as it does on the chip
+TPU_DEFAULTS = {"xla_backend_optimization_level": 3,
+                "xla_llvm_disable_expensive_passes": False}
+
+
 def _compile(fn, *avals):
-    text = jax.jit(fn).lower(*avals).compile().as_text()
+    text = jax.jit(fn).lower(*avals).compile(
+        compiler_options=TPU_DEFAULTS).as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the executable"
     return text
 
@@ -155,6 +163,43 @@ def test_flash_attention_qwen3_next_shape_and_signatures(one_chip):
     want = [[f"bf16[{bh},{s},{d}]", f"f32[{bh},1,{s}]"],
             [f"bf16[{bh},{s},{d}]"],
             [f"f32[{bh},{s},{d}]", f"f32[{bh},{s},{d}]"]]
+    assert sorted(shapes) == sorted(want)
+
+
+# the delta rule of the benchmark's Qwen3-Next cell: (B, S, key heads, value
+# heads, head size), chunks of 64
+QWEN3_NEXT_RULE = (4, 8192, 16, 32, 128)
+
+
+def test_gated_delta_rule_qwen3_next_shape_and_signatures(one_chip):
+    """The rule's two kernels at the cell's shape, bfloat16, inside the VMEM
+    an operation may scope by default (no limit is passed: a kernel that
+    asks for more takes it from its neighbours, PERF.md PR 26).  Results:
+    forward ``o`` in the ``[B, S, H_v * d]`` view and the float32 state at
+    each block's start; backward ``dq``, ``dk`` (summed over the value heads
+    of a key head), ``dv`` and the gate rows' cotangent."""
+    b, s, h_k, h_v, d = QWEN3_NEXT_RULE
+    assert G.tiles(64, d, d, h_v // h_k)
+    assert G._PARAMS.vmem_limit_bytes is None
+    qk = _aval(one_chip, (b, s, h_k, d), jnp.bfloat16)
+    v = _aval(one_chip, (b, s, h_v, d), jnp.bfloat16)
+    gate = _aval(one_chip, (b, s, h_v), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        out = G.gated_delta_rule(q, k, v, g, beta, chunk_size=64)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=range(5)), qk, qk, v, gate, gate)
+    shapes = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            result = line.split(" = ", 1)[1].split(" custom-call(", 1)[0]
+            shapes.append([p.split("{")[0]
+                           for p in result.strip("()").split("}, ")])
+    blocks, rows = s // (16 * 64), s // 128
+    want = [[f"bf16[{b},{s},{h_v * d}]", f"f32[{b},{h_v},{blocks},{d},{d}]"],
+            [f"bf16[{b},{s},{h_k * d}]", f"bf16[{b},{s},{h_k * d}]",
+             f"bf16[{b},{s},{h_v * d}]", f"f32[{b},{h_v},{rows},8,128]"]]
     assert sorted(shapes) == sorted(want)
 
 
@@ -279,7 +324,8 @@ def test_fused_batch_norm_module_over_the_guard_runs_the_reference(
     avals = jax.tree.map(lambda a: _aval(one_chip, a.shape, a.dtype),
                          variables)
     text = jax.jit(fwd).lower(
-        avals, _aval(one_chip, shape, jnp.bfloat16)).compile().as_text()
+        avals, _aval(one_chip, shape, jnp.bfloat16)).compile(
+            compiler_options=TPU_DEFAULTS).as_text()
     assert "tpu_custom_call" not in text
     assert any(str(shape) in s and "reference" in s for s in said), said
 
@@ -306,4 +352,5 @@ def test_fused_group_norm_largest_admitted_resnet50_site(one_chip):
     over = _aval(one_chip, (256, 112 * 112, 64), jnp.bfloat16)
     sb64 = _aval(one_chip, (64,), jnp.float32)
     with pytest.raises(Exception, match="vmem|VMEM"):
-        jax.jit(loss).lower(over, sb64, sb64).compile()
+        jax.jit(loss).lower(over, sb64, sb64).compile(
+            compiler_options=TPU_DEFAULTS)

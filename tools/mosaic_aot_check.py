@@ -17,6 +17,8 @@ Checks (all against a ``v5e:2x2`` topology, bf16):
      the flash kernel inside, GSPMD-partitioned for real TPU devices
   5. the driver's ``entry()`` flagship (GPT-2-small @ S=1024, flash
      attention auto-selected ON TPU, streaming vocab loss)
+  6. the gated delta rule's forward and backward kernels at the shape of
+     the benchmark's Qwen3-Next cell
 
 Writes MOSAIC_AOT.json at the repo root and exits nonzero on any
 failure.  Run via ``make mosaic-aot``.
@@ -706,6 +708,28 @@ def main():
         assert "all-reduce" in txt, "the parts are not summed over the axis"
         return {"experts": E, "expert_axis": ep}
 
+    def gated_delta_rule():
+        """The delta rule's two kernels at the shape of the benchmark's
+        Qwen3-Next cell (4 x 8,192 positions, 16 key heads on 32 value
+        heads of 128, chunks of 64, bfloat16), taken by
+        ``chunk_gated_delta_rule``'s own rule and compiled inside the
+        default scoped VMEM."""
+        from autodist_tpu.ops.gated_delta import chunk_gated_delta_rule
+
+        b, s, h_k, h_v, d = 4, 8192, 16, 32, 128
+        qk = jax.ShapeDtypeStruct((b, s, h_k, d), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((b, s, h_v, d), jnp.bfloat16)
+        gate = jax.ShapeDtypeStruct((b, s, h_v), jnp.float32)
+
+        def loss(q, k, v, g, beta):
+            return jnp.sum(chunk_gated_delta_rule(
+                q, k, v, g, beta, chunk_size=64).astype(jnp.float32))
+
+        exe, txt = _compile(jax.grad(loss, argnums=range(5)), qk, qk, v,
+                            gate, gate)
+        assert txt.count('custom_call_target="tpu_custom_call"') == 2
+        return {"shape": [b, s, h_k, h_v, d], **_xla_stats(exe)}
+
     check("flash_attention_fwd", flash_fwd)
     check("flash_attention_bwd", flash_bwd)
     check("int8_quantize", quantize)
@@ -721,6 +745,7 @@ def main():
     check("gpt_decode_rollout_serving", gpt_decode_rollout)
     check("tensor_parallel_2x2", tensor_parallel)
     check("expert_parallel_moe_2x2", expert_parallel)
+    check("gated_delta_rule_qwen3_next_fwd_bwd", gated_delta_rule)
 
     results["ok"] = ok
     results["total_seconds"] = round(time.time() - t0, 1)
