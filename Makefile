@@ -2,7 +2,7 @@
 # lint, local tests, distributed tests, benchmarks).
 PY ?= python
 
-.PHONY: test test-all test-dist native proto bench lint clean mosaic-aot aot-fused-norm verify audit telemetry-check timeline-check monitor-check chaos perf-gate serve-check postmortem-check fleet-check check
+.PHONY: test test-all test-dist native proto lint clean mosaic-aot aot-fused-norm verify audit telemetry-check timeline-check monitor-check chaos perf-gate serve-check postmortem-check fleet-check check
 
 test:
 	$(PY) -m pytest tests/ -x -q
@@ -19,9 +19,6 @@ native:
 proto:
 	bash autodist_tpu/proto/gen.sh
 
-bench:
-	$(PY) bench.py
-
 # Pallas surface through the REAL Mosaic/XLA:TPU compiler, no chip needed
 # (libtpu deviceless topology compile); writes MOSAIC_AOT.json
 mosaic-aot:
@@ -32,32 +29,15 @@ mosaic-aot:
 aot-sweep:
 	$(PY) tools/aot_sweep.py
 
-# HBM capacity proof for the headline bench configs (several minutes);
+# HBM capacity proof for ResNet-50 B=256 and GPT-2-small S=1024 (several minutes);
 # writes records/v5e_aot/capacity.json
 aot-capacity:
 	$(PY) tools/aot_capacity.py
-
-# ResNet-50 MFU-lever analysis via per-variant v5e compiles (minutes per
-# variant); writes records/v5e_aot/resnet_levers.json
-aot-levers:
-	$(PY) tools/aot_levers.py
-
-# barrier-vs-overlap sync-schedule compiles (latency-hiding scheduler
-# flags) + the cost model's serialized/overlapped estimates; writes
-# records/v5e_aot/overlap_lever.json — the BENCH_OVERLAP lever's evidence
-aot-overlap:
-	$(PY) tools/aot_overlap.py
 
 # GPT flagship batch/remat lever sweep for v5e (minutes per variant);
 # writes records/v5e_aot/gpt_levers.json
 aot-gpt-levers:
 	$(PY) tools/aot_gpt_levers.py
-
-# EQuARX fused-hop lever proof: the Pallas kernel's deviceless Mosaic
-# compile for v5e + the cost model's DCN-bottleneck step-time delta vs
-# the unfused int8 pattern; writes records/v5e_aot/equarx_lever.json
-aot-equarx:
-	$(PY) tools/aot_equarx.py
 
 # fused-normalization lever proof (the F008 remediation): the fused
 # Pallas batch norm's deviceless Mosaic compile for v5e vs the unfused

@@ -31,9 +31,8 @@ prices the MFU ceiling statically:
                model/realized FLOPs, per-class + per-region attribution,
                recompute groups, f32-contraction volume, and the
                predicted MFU ceiling from the calibrated cost model —
-               consumed by ``tools/telemetry_report.py --compute``,
-               AutoStrategy's ``predicted_mfu_ceiling`` gauges and
-               ``bench.py``'s cpu_proxy records
+               consumed by ``tools/telemetry_report.py --compute`` and
+               AutoStrategy's ``predicted_mfu_ceiling`` gauges
   F007 INFO    machine-readable HBM-traffic table (``Finding.data``):
                fusion-aware per-region bytes
                (``cost_model.hbm_traffic_from_ops``), arithmetic

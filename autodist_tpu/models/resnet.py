@@ -95,17 +95,17 @@ class ResNet(nn.Module):
     stem: str = "conv"
     # True: batch-norm reduces mean/var in float32 (flax default; exact).
     # False: stats reduce in the compute dtype (bf16 here) — halves the
-    # BN-stat HBM traffic that profiling showed at ~30% of the forward
-    # pass (docs/performance.md), at a small stats-precision cost.  A perf
-    # lever for bench sweeps (BENCH_BN_STATS=bf16), not the default.
+    # BN-stat HBM traffic (the norm passes are the largest item of the
+    # ResNet-50 step, PERF.md section 5), at a small stats-precision
+    # cost.  Never measured on a chip (ROADMAP D11); not the default.
     bn_f32_stats: bool = True
     # "bn": flax nn.BatchNorm (XLA's multi-pass lowering; exact default).
     # "bn_fused": the single-VMEM-pass Pallas batch norm
     #   (ops/pallas/fused_norm.py) — one activation HBM read instead of
     #   three, the F008 memory-bound remediation knob.
     # "gn": fused GroupNorm — per-sample stats, no batch-stats traffic
-    #   or running-average state at all (BENCH_NORM=fused|gn in bench.py,
-    #   ":fused_norm"/":gn" strategy variants in examples/benchmark.py).
+    #   or running-average state at all (the ":fused_norm"/":gn"
+    #   strategy variants of examples/benchmark.py set this argument).
     norm: str = "bn"
 
     @nn.compact

@@ -13,8 +13,7 @@ against its blessed baseline across hosts (``make perf-gate``), keeping
 the serving tier's tokens/sec overhead trajectory observable between
 chip windows — the same role ``cpu_mesh_engine_overhead`` plays for
 training.  Entry points: ``examples/benchmark.py --serve`` (writes the
-record), ``bench.py --serve-proxy`` (prints it, labelled CPU),
-``tools/perf_gate.py`` (re-measures and gates).
+record), ``tools/perf_gate.py`` (re-measures and gates).
 """
 import time
 from autodist_tpu.utils.rng import host_key

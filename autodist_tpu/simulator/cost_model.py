@@ -366,8 +366,8 @@ def jaxpr_flops(jaxpr):
     math, control flow folded in structurally (``scan`` multiplies by its
     trip count, ``cond`` takes the max branch, ``while`` counts its body
     once — trip counts are data-dependent).  Elementwise ops are ignored:
-    this is the MODEL-FLOPs numerator an MFU wants (the convention
-    bench.py's model-FLOPs figures follow), not XLA's emitted-op count.
+    this is the MODEL-FLOPs numerator an MFU wants (the convention of
+    ``benchmark/harness/flops.py``), not XLA's emitted-op count.
 
     Counted on the jaxpr the engine traces, the ``shard_map`` body
     carries per-device shapes — so the returned count is per-device work
@@ -1022,10 +1022,10 @@ def measure_and_record(session, batch, resource_yaml="", steps=10, warmup=2):
     :class:`RuntimeRecord` — the reference dataset's (model, resource,
     strategy, runtime) tuple (``simulator/dataset/README.md``).
 
-    Timing uses :func:`autodist_tpu.utils.timing.measure_per_step`
-    (chain-differenced, one scalar fetch per window).  ``steps`` bounds the total executed step count:
-    the two differenced windows run ~steps/3 and ~2*steps/3 steps."""
-    from autodist_tpu.utils.timing import fetch_scalar, measure_per_step
+    Timing uses :func:`autodist_tpu.utils.timing.seconds_per_step`: one
+    window of ``steps`` dependent steps after ``warmup``, closed by one
+    scalar fetch."""
+    from autodist_tpu.utils.timing import fetch_scalar, seconds_per_step
 
     if steps < 1:
         raise ValueError("steps must be >= 1")
@@ -1041,7 +1041,7 @@ def measure_and_record(session, batch, resource_yaml="", steps=10, warmup=2):
             m = session.run(batch)
         return m["loss"]
 
-    dt, _ = measure_per_step(run_steps, k=max(1, steps // 3), repeats=1)
+    dt = seconds_per_step(run_steps, k=steps)
     import jax
 
     t = session._t

@@ -1,21 +1,16 @@
-"""Step timing under asynchronous dispatch.
+"""Step timing under asynchronous dispatch: a window closed by a host fetch.
 
 JAX dispatch is async: a timed window has to end at a point where the
-device has really finished.  ``measure_per_step`` closes its windows with
-a host fetch of one device scalar — the bytes cannot arrive before the
-program producing them finishes — and differences two windows so that
-whatever a window costs once (the fetch, the dispatch ramp) cancels:
+device has really finished.  ``seconds_per_step`` runs K *dependent* steps
+(each consuming the previous state, so the device cannot reorder or elide
+them) and closes the window with a host fetch of one device scalar from the
+last of them: the bytes cannot arrive before the program producing them
+finishes.  What the window costs once (the fetch, the dispatch ramp) is
+spread over K steps; on the chip it read within 0.2-0.6 % of the step
+(PERF.md, PR 21).
 
-  1. run K *dependent* steps (each consuming the previous state, so the
-     device cannot reorder or elide them), fetch ONE scalar -> T(K);
-  2. run 2K steps the same way -> T(2K);
-  3. per-step = (T(2K) - T(K)) / K.
-
-``chip_smoke.py`` prints this next to the plain K-steps-then-
-``block_until_ready`` reading; ROADMAP S1 keeps one of the two.  The
-reference's benchmark harness could time with wall clock because TF
-session.run is synchronous (``examples/benchmark/utils/...``); this module
-is the async-dispatch analog of that timing discipline.
+This is a timer for tools and smoke checks.  A training speed is stated by
+``benchmark/`` alone (``BENCHMARK.json``, ``PERF.md``, the ledger).
 """
 import time
 
@@ -56,33 +51,15 @@ def fetch_scalar(x):
     return float(np.asarray(jax.device_get(x)).ravel()[0])
 
 
-def measure_per_step(run_steps, k=10, repeats=2, fetch=fetch_scalar):
-    """Steady-state seconds/step of a step function, by differencing.
+def seconds_per_step(run_steps, k):
+    """Seconds per step over one window of ``k`` dependent steps.
 
-    ``run_steps(n)`` must execute ``n`` *dependent* steps (state threaded
+    ``run_steps(k)`` must execute ``k`` *dependent* steps (state threaded
     through, so none can be elided) and return a device scalar handle from
-    the final step.  Returns ``(per_step_s, diagnostics)`` where
-    diagnostics records the raw T(K)/T(2K) minima and whether the
-    differencing had to fall back to the naive upper bound.
+    the final step; fetching it closes the window.
     """
     if k < 1:
         raise ValueError("k must be >= 1")
-    t_k = t_2k = float("inf")
-    for _ in range(max(1, repeats)):
-        t0 = time.perf_counter()
-        fetch(run_steps(k))
-        t_k = min(t_k, time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        fetch(run_steps(2 * k))
-        t_2k = min(t_2k, time.perf_counter() - t0)
-    per_step = (t_2k - t_k) / k
-    fallback = per_step <= 0
-    if fallback:
-        # noise swamped the difference (steps far cheaper than the jitter of
-        # a window's fixed cost): the naive bound still contains that cost
-        # once, so flag it as an upper bound
-        per_step = t_2k / (2 * k)
-    return per_step, {
-        "t_k_s": t_k, "t_2k_s": t_2k, "k": k,
-        "naive_fallback": fallback,
-    }
+    t0 = time.perf_counter()
+    fetch_scalar(run_steps(k))
+    return (time.perf_counter() - t0) / k

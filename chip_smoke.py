@@ -39,7 +39,7 @@ sys.path.insert(0, REPO)
 # f32-accumulated mean loss by a few bf16 ulps (2**-8 relative each).
 LOSS_RTOL = 2e-2
 TRAIN_STEPS = 8        # checked steps per training phase
-TIMING_K = 4           # K of the two step timings
+TIMING_K = 4           # steps in the timed window
 DP_STEPS = 4           # steps per variant of the four-chip phase
 
 
@@ -151,7 +151,7 @@ class TokenStream:
 def gpt_setup(cfg, *, batch, seq_len, seed, out_dir):
     """What both GPT phases start from: the seeded corpus on disk, and the
     seeded capture ``(corpus path, loss_fn, params, sparse_vars,
-    optimizer)`` that ``bench.py`` builds."""
+    optimizer)``."""
     import optax
 
     from autodist_tpu.models.train_lib import gpt_capture
@@ -241,12 +241,9 @@ def compile_step_again(sess, gbatch, events):
 
 
 def time_steps(sess, next_batch, k):
-    """Seconds per step, taken two ways (ROADMAP S1 asks which one holds on
-    this machine): K steps closed by ``block_until_ready``, and
-    ``measure_per_step``'s K-against-2K differencing."""
-    import jax
-
-    from autodist_tpu.utils.timing import measure_per_step
+    """Seconds per step over ``k`` steps of ``sess.run``, the window closed
+    by a host fetch of the last step's loss."""
+    from autodist_tpu.utils.timing import seconds_per_step
 
     def run_steps(n):
         m = None
@@ -254,14 +251,7 @@ def time_steps(sess, next_batch, k):
             m = sess.run(next_batch())
         return m["loss"]
 
-    t0 = time.perf_counter()
-    jax.block_until_ready(run_steps(k))
-    blocked = (time.perf_counter() - t0) / k
-    differenced, diag = measure_per_step(run_steps, k=k, repeats=1)
-    return {"k": k, "s_per_step_block_until_ready": blocked,
-            "s_per_step_k_vs_2k": differenced,
-            "t_k_s": diag["t_k_s"], "t_2k_s": diag["t_2k_s"],
-            "naive_fallback": diag["naive_fallback"]}
+    return {"k": k, "s_per_step": seconds_per_step(run_steps, k)}
 
 
 def release(devices):

@@ -341,7 +341,7 @@ def run_one(args, strategy_name, cap, n_chips):
         # same step, batches arriving through the full input pipeline;
         # compares against the device-resident number to report whether
         # the run is input-bound (r2 verdict item 9)
-        from autodist_tpu.utils.timing import fetch_scalar, measure_per_step
+        from autodist_tpu.utils.timing import fetch_scalar, seconds_per_step
 
         pre = _real_pipeline(args, cap, B, sess)
         fetch_scalar(sess.run(next(pre))["loss"])  # warm
@@ -352,8 +352,7 @@ def run_one(args, strategy_name, cap, n_chips):
                 m = sess.run(next(pre))
             return m["loss"]
 
-        real_dt, _ = measure_per_step(
-            run_steps, k=max(1, args.steps // 3), repeats=1)
+        real_dt = seconds_per_step(run_steps, k=args.steps)
         overhead = real_dt / record.step_time_s - 1.0
         extra = (f" real_eps={B / real_dt:.1f} "
                  f"input_overhead={100 * overhead:.1f}% "

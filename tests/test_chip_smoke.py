@@ -58,7 +58,7 @@ def test_gpt_train_phase_tiny(tmp_path, events):
     assert len(rec["reference_losses"]) == 2
     # off the chip "auto" attention is XLA's: what main() would refuse
     assert rec["tpu_custom_call"] is False
-    assert rec["timing"]["s_per_step_block_until_ready"] > 0
+    assert rec["timing"]["s_per_step"] > 0
     assert os.path.exists(tmp_path / "gpt_corpus.bin")
 
 

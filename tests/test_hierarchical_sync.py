@@ -597,28 +597,6 @@ def test_telemetry_records_hierarchy_and_per_hop_bytes(tmp_path):
     assert "sync.dcn_hop_bytes" in gauges and "sync.ici_hop_bytes" in gauges
 
 
-# -- bench lever -------------------------------------------------------------
-
-def test_bench_hierarchy_lever(monkeypatch):
-    """``BENCH_HIERARCHY=two_level`` factors the bench spec (host count on
-    multi-process runs, BENCH_DCN_SLICES single-host) and falls back flat
-    — with the reason in the label — when the chips do not factor."""
-    import bench
-
-    monkeypatch.setenv("BENCH_HIERARCHY", "two_level")
-    spec, h = bench._bench_hierarchy_spec(8)
-    assert h == "two_level"
-    assert spec.mesh_request == {AXIS_REPLICA_DCN: 2, AXIS_REPLICA_ICI: 4}
-    monkeypatch.setenv("BENCH_DCN_SLICES", "4")
-    spec, h = bench._bench_hierarchy_spec(8)
-    assert spec.mesh_request == {AXIS_REPLICA_DCN: 4, AXIS_REPLICA_ICI: 2}
-    _, h = bench._bench_hierarchy_spec(7)
-    assert h.startswith("flat (cannot factor")
-    monkeypatch.setenv("BENCH_HIERARCHY", "flat")
-    spec, h = bench._bench_hierarchy_spec(8)
-    assert h == "flat" and spec.mesh_request is None
-
-
 # -- Parallax inherits the knob ---------------------------------------------
 
 def test_parallax_two_level_builds_factored():

@@ -39,7 +39,7 @@ def test_resnet18_trains_with_batch_stats():
 
 
 def test_bf16_bn_stats_close_to_f32():
-    """The BENCH_BN_STATS=bf16 perf lever (reduce BN stats in the compute
+    """``ResNet(bn_f32_stats=False)`` (reduce BN stats in the compute
     dtype) stays numerically close to the exact f32-stats model at init
     and still trains."""
     r = np.random.RandomState(1)

@@ -23,8 +23,8 @@ calibrated per-hop bandwidths.  Pinned here:
   over TWO_LEVEL, and AutoStrategy ranking a searched candidate first,
 - analysis: Y010 (malformed IR / unknown axis), Y011 (block codec on a
   fast hop), Y012 (searched summary), and the AD07 lint rule,
-- levers: ``BENCH_SCHEDULE=searched`` (bench.py) and the
-  ``AllReduce:searched_schedule`` benchmark variant.
+- levers: ``AllReduce(schedule="searched")`` through the
+  ``AllReduce:searched_schedule`` variant of ``examples/benchmark.py``.
 """
 import importlib.util
 import os
@@ -664,24 +664,6 @@ def test_repo_is_ad07_clean():
 
 
 # -- levers ------------------------------------------------------------------
-
-def test_bench_searched_lever(monkeypatch):
-    import bench
-
-    monkeypatch.setenv("BENCH_SCHEDULE", "searched")
-    spec, kwargs, extras = bench._bench_sync(8)
-    assert extras["sync_hierarchy"] == "searched"
-    assert kwargs["schedule_ir"] and ";" in kwargs["schedule_ir"]
-    assert spec.mesh_request == {AXIS_REPLICA_DCN: 2, AXIS_REPLICA_ICI: 4}
-    # non-factoring chip count degrades gracefully, reason in the label
-    _, kw7, ex7 = bench._bench_sync(7)
-    assert "schedule_ir" not in kw7
-    assert "searched requested" in ex7["sync_hierarchy"]
-    monkeypatch.delenv("BENCH_SCHEDULE")
-    _, kw_off, ex_off = bench._bench_sync(8)
-    assert "schedule_ir" not in kw_off
-    assert ex_off["sync_hierarchy"] == "flat"
-
 
 def test_benchmark_searched_schedule_variant():
     spec = importlib.util.spec_from_file_location(

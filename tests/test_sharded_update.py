@@ -597,24 +597,6 @@ def test_telemetry_records_sharded_update(tmp_path):
     assert "sync.param_gather_bytes" in gauges
 
 
-# -- bench CPU-mesh proxy (satellite) ---------------------------------------
-
-def test_bench_cpu_proxy_contract():
-    """The named CPU-mesh proxy emits the documented record shape: an
-    engine-vs-raw overhead ratio (never a hardware claim) including the
-    sharded-update variant's step time."""
-    import bench
-
-    rec = bench._cpu_proxy(steps=2)
-    assert rec["metric"] == bench.CPU_PROXY_METRIC == \
-        "cpu_mesh_engine_overhead"
-    assert rec["backend"] == "cpu"
-    assert rec["value"] == pytest.approx(
-        rec["engine_step_ms"] / rec["raw_step_ms"], rel=0.01)
-    assert rec["engine_sharded_update_step_ms"] > 0
-    assert "never a hardware throughput claim" in rec["note"]
-
-
 def test_parallax_inherits_sharded_update():
     item = _item()
     s = Parallax(sharded_update="sharded").build(item, SPEC_FLAT4)

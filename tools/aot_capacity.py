@@ -1,6 +1,6 @@
-"""HBM capacity proof for the benchmark configurations, no chip needed.
+"""HBM capacity proof for full-size training steps, no chip needed.
 
-Compiles the two headline bench configs (bench.py) — ResNet-50 @224
+Compiles the models ``chip_smoke.py`` runs — ResNet-50 @224
 B=256 bf16 AllReduce, and GPT-2-small S=1024 flash + streaming vocab
 loss + remat adamw — as FULL training steps through the engine against
 the deviceless v5e topology, with donated state (the session's real
@@ -60,7 +60,7 @@ def main():
 
     os.environ.setdefault("AUTODIST_IS_TESTING", "True")
     topo = topologies.get_topology_desc(TOPOLOGY, "tpu")
-    # single-chip configs: bench.py measures per-chip throughput on 1 chip
+    # single-chip configs: one chip holds the whole state
     mesh = Mesh(np.array(topo.devices[:1]), ("replica",))
     bsh = NamedSharding(mesh, P("replica"))
     results = {"topology": TOPOLOGY, "hbm_bytes": HBM_BYTES, "configs": {}}

@@ -431,8 +431,7 @@ def main():
         collectives, reverse-topological issue order) compiled WITH the
         latency-hiding-scheduler + combine-threshold flags — recording
         XLA's stats next to the cost model's serialized vs overlapped
-        estimates (the deviceless form of the BENCH_OVERLAP lever; full
-        record: tools/aot_overlap.py)."""
+        estimates: ``AllReduce(schedule="overlap")`` without a chip."""
         import optax
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -3,10 +3,9 @@
 
 For every strategy record under ``records/cpu_mesh`` this rebuilds the
 case on a virtual CPU mesh and measures the *machine-normalized*
-engine overhead (engine SPMD step / raw single-jit step — the same
-``cpu_mesh_engine_overhead`` metric ``bench.py`` records every round),
-audits the lowering (F006 ``predicted_mfu_ceiling``, X006 realized comm
-bytes), and runs the cross-run REGRESSION tier
+engine overhead (engine SPMD step / raw single-jit step), audits the
+lowering (F006 ``predicted_mfu_ceiling``, X006 realized comm bytes), and
+runs the cross-run REGRESSION tier
 (:mod:`autodist_tpu.analysis.regression_audit`) against the blessed
 baseline in ``records/baselines/<name>.json``:
 
@@ -84,7 +83,7 @@ def _engine_overhead(strategy, item, mesh, R):
 
     from autodist_tpu.kernel.graph_transformer import GraphTransformer
     from autodist_tpu.runner import DistributedSession
-    from autodist_tpu.utils.timing import fetch_scalar, measure_per_step
+    from autodist_tpu.utils.timing import fetch_scalar, seconds_per_step
 
     rs = np.random.RandomState(0)
     batch = {"x": rs.randn(2 * R, 4).astype(np.float32)}
@@ -100,9 +99,9 @@ def _engine_overhead(strategy, item, mesh, R):
             m = sess.run(g)
         return m["loss"]
 
-    # min-over-repeats differencing: the ratio's noise floor must sit
-    # well under the gate tolerance or the committed baselines flake
-    eng_dt, _ = measure_per_step(run_engine, k=STEPS, repeats=3)
+    # the least of three windows: the ratio's noise floor must sit well
+    # under the gate tolerance or the committed baselines flake
+    eng_dt = min(seconds_per_step(run_engine, STEPS) for _ in range(3))
 
     opt = item.optimizer
     state = [item.params, opt.init(item.params)]
@@ -126,7 +125,7 @@ def _engine_overhead(strategy, item, mesh, R):
     # the raw step is microseconds on these tiny models — a k this small
     # would put scheduler jitter straight into the ratio's denominator,
     # so run many more of them (they cost ~nothing)
-    raw_dt, _ = measure_per_step(run_raw, k=20 * STEPS, repeats=3)
+    raw_dt = min(seconds_per_step(run_raw, 20 * STEPS) for _ in range(3))
     overhead = eng_dt / max(raw_dt, 1e-9)
     info = {"engine_step_ms": round(eng_dt * 1e3, 3),
             "raw_step_ms": round(raw_dt * 1e3, 3)}
