@@ -16,7 +16,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from autodist_tpu.ops.pallas.flash_attention import flash_attention, use_flash
+from autodist_tpu.ops.pallas.flash_attention import (flash_attention_packed,
+                                                     use_flash)
 from autodist_tpu.ops.sparse import embedding_lookup
 
 
@@ -122,8 +123,10 @@ class CausalSelfAttention(nn.Module):
             y = ring_attention(q, repeat_kv(k), repeat_kv(v), seq_axis,
                                causal=True, impl=c.attention_impl)
         elif use_flash(c.attention_impl):
-            # the kernel handles GQA natively (shared-block index maps)
-            y = flash_attention(q, k, v, causal=True)
+            # the kernels read q, k and v where the projection wrote them
+            # (no slice, no transpose) and handle GQA natively
+            y = flash_attention_packed(qkv, c.num_heads, kv_heads,
+                                       causal=True)
         else:
             pos = jnp.arange(S)
             bias = jnp.where(pos[:, None] >= pos[None, :], 0.0,

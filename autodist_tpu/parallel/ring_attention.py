@@ -98,8 +98,6 @@ def _make_ring_flash(axis_name, causal, b, h, sq, d, bq, bk, scale,
         idx = jax.lax.axis_index(axis_name)
         q_off = idx * sq
         perm = ring_perm(r)
-        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                        axis=-1)
         args = dict(sm_scale=scale, causal=causal, block_q=bq, block_k=bk,
                     interpret=interpret)
 
@@ -107,11 +105,10 @@ def _make_ring_flash(axis_name, causal, b, h, sq, d, bq, bk, scale,
             k_blk, v_blk, dk, dv, dq = carry
             blk = jnp.mod(idx - step, r)
             k_off = blk * sq
-            dq_p = F._dq_call(qf, k_blk, v_blk, None, do, lse, delta, h,
+            dq_p = F._dq_call(qf, k_blk, v_blk, None, do, out, lse, h,
                               q_off=q_off, k_off=k_off, **args)
-            dk_p, dv_p = F._dkdv_call(qf, k_blk, v_blk, None, do, lse,
-                                      delta, h, q_off=q_off, k_off=k_off,
-                                      **args)
+            dk_p, dv_p = F._dkdv_call(qf, k_blk, v_blk, None, do, out, lse,
+                                      h, q_off=q_off, k_off=k_off, **args)
             dq = dq + dq_p.astype(jnp.float32)
             dk = dk + dk_p.astype(jnp.float32)
             dv = dv + dv_p.astype(jnp.float32)

@@ -153,8 +153,7 @@ def test_loop_form_matches_prefix_form(monkeypatch, causal, masked, dtype):
             block_k=32).astype(jnp.float32)))
 
     want = jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setattr(F, "_SLAB_BUDGET", 0)
-    F._make_flash.cache_clear()
+    _force_loop_form(monkeypatch)
     got = jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
     F._make_flash.cache_clear()
     tol = dict(rtol=1e-5, atol=1e-5) if dtype == jnp.float32 \
@@ -207,36 +206,70 @@ def test_no_mask_build_equals_all_true_mask_build(causal):
         np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.parametrize("bh,h,group,biased,want", [
-    (512, 16, 1, False, 8),     # gpt2_medium.train_fed's fold
-    (80, 20, 1, False, 8),      # gpt2_large over four chips, a chip
-    (80, 20, 1, True, 5),       # a per-example bias keeps a program in one
-    (6, 3, 1, False, 6),
-    (7, 7, 1, False, 7),
-    (11, 11, 1, False, 1),      # a prime fold over the preference
-    (64, 32, 4, False, 4),      # GQA: the query heads of one K/V head
+@pytest.mark.parametrize("d,pack,bh,h,group,biased,want", [
+    # the projections' layout: a program stays inside one example and takes
+    # whole lane blocks (pairs of heads at D = 64)
+    (64, 2, 512, 16, 1, False, 8),   # gpt2_medium.train_fed
+    (64, 2, 384, 12, 1, False, 6),   # GPT-2-small in chip_smoke.py
+    (64, 2, 80, 20, 1, True, 4),     # gpt2_large: 10 pairs, in 2s
+    (64, 2, 28, 14, 1, False, 2),    # 7 pairs: one at a time
+    (64, 2, 2, 2, 1, False, 2),
+    (128, 1, 64, 32, 4, False, 4),   # GQA: the query heads of one K/V head
+    (256, 1, 64, 16, 8, False, 8),   # Qwen3-Next's heads, at short rows
+    # folded (ring attention's step; head sizes that fit no lane block)
+    (64, 0, 512, 16, 1, False, 8),
+    (80, 0, 80, 20, 1, False, 8),
+    (80, 0, 80, 20, 1, True, 5),     # a per-example bias keeps a program in one
+    (16, 0, 6, 3, 1, False, 6),
+    (16, 0, 7, 7, 1, False, 7),
+    (16, 0, 11, 11, 1, False, 1),    # a prime fold over the preference
+    (64, 0, 64, 32, 4, False, 4),    # GQA under 128 lanes
 ])
-def test_heads_per_program_follow_the_fold(bh, h, group, biased, want):
+def test_heads_per_program_follow_the_layout(d, pack, bh, h, group, biased,
+                                             want):
+    heads = F._Heads(d, pack)
     # rows short enough that VMEM binds nothing
-    assert F._pick_heads(bh, h, group, biased, 256, 256, 64, 2,
-                         256, 256) == want
+    assert F._pick_heads(heads, F._together(heads, bh, h, group, biased),
+                         128, 128, 2, 128, 128) == want
 
 
-@pytest.mark.parametrize("s,itemsize,want,raised", [
-    (1024, 2, 4, False),        # the benchmark's rows: 4 of the 8 heads fit
-    (1024, 4, 2, False),        # what the default scoped limit leaves
-    (8192, 2, 1, False),
-    (32768, 2, 1, True),        # one head's rows: Mosaic is told a limit
-    (65536, 2, 0, None),        # the XLA fallback's case
+@pytest.mark.parametrize("pack,s,itemsize,want,raised", [
+    (2, 1024, 2, 4, False),     # the benchmark's rows: 4 of the 8 heads fit
+    (2, 1024, 4, 2, False),     # what the default scoped limit leaves
+    (2, 8192, 2, 2, True),      # one lane block's rows: Mosaic is told a limit
+    (2, 32768, 2, 2, True),
+    (2, 65536, 2, 0, None),     # the XLA fallback's case
+    (0, 1024, 2, 2, False),     # folded: a head's rows are padded to 128 lanes
+    (0, 8192, 2, 1, True),
+    (0, 65536, 2, 0, None),
 ])
-def test_heads_per_program_shrink_to_the_vmem_budget(s, itemsize, want,
+def test_heads_per_program_shrink_to_the_vmem_budget(pack, s, itemsize, want,
                                                      raised):
-    shape = (s, s, 64, itemsize, 512, 512)
-    assert F._pick_heads(512, 16, 1, False, *shape) == want
+    heads = F._Heads(64, pack)
+    shape = (s, s, itemsize, 512, 512)
+    assert F._pick_heads(heads, F._together(heads, 512, 16, 1, False),
+                         *shape) == want
     if want:
-        assert (F._vmem_bytes(want, *shape) > F._VMEM_BUDGET) == raised
-        limit = F._tpu_params(F._vmem_bytes(want, *shape)).vmem_limit_bytes
+        need = F._vmem_bytes(heads, want, *shape)
+        assert (need > F._VMEM_BUDGET) == raised
+        limit = F._tpu_params(need).vmem_limit_bytes
         assert limit == (F._VMEM_LIMIT if raised else None)
+
+
+@pytest.mark.parametrize("h,group,d,align,want", [
+    (16, 1, 64, 128, 2),        # GPT-2: pairs of heads in 128 lanes
+    (12, 1, 64, 128, 2),
+    (16, 8, 256, 128, 1),       # Qwen3-Next: a head is two lane blocks
+    (32, 4, 128, 128, 1),       # Llama
+    (8, 1, 16, 128, 8),
+    (20, 1, 80, 128, 0),        # no lane block holds heads of 80
+    (12, 3, 64, 128, 0),        # grouped K/V heads under 128 lanes
+    (3, 1, 64, 128, 0),         # pairs do not divide 3 heads
+    (3, 1, 16, 1, 3),           # the interpreter takes any block width
+    (2, 1, 32, 1, 2),
+])
+def test_layout_follows_the_head_size(h, group, d, align, want):
+    assert F._pack(h, group, d, align) == want
 
 
 @pytest.mark.parametrize("bq,bk", [(32, 32), (64, 32), (32, 64), (1, 1)])
@@ -260,6 +293,171 @@ def test_causal_loop_bounds_are_the_visible_tiles(bq, bk, q_off, k_off):
         i_vis, i_full = F._q_bounds(k_off + j * bk, q_off, bq, bk, nq, True)
         assert every[i_full:, j].all() and not every[:i_full, j].any()
         assert some[i_vis:, j].all() and not some[:i_vis, j].any()
+
+
+def _force_loop_form(monkeypatch):
+    monkeypatch.setattr(F, "_SLAB_BUDGET", 0)
+    F._make_flash.cache_clear()
+
+
+# the projections' layout: (B, S, H*D) read and written as it lies, a head
+# found in its lanes by D alone.  (d, query heads, K/V heads) with the route
+# each takes, then what the kernels are built for
+PROJECTION_LAYOUT = [
+    # d, h, h_kv, causal, masked, dtype, loop
+    (16, 8, 8, True, False, jnp.float32, False),     # 8 heads a lane block
+    (16, 8, 8, False, True, jnp.float32, True),
+    (16, 16, 16, True, False, jnp.bfloat16, False),  # two lane blocks
+    (64, 2, 2, True, False, jnp.float32, False),     # GPT-2: a pair
+    (64, 2, 2, True, False, jnp.bfloat16, False),
+    (64, 4, 4, True, False, jnp.float32, True),      # two pairs, k tiles looped
+    (64, 2, 2, False, True, jnp.float32, False),     # BERT: padding mask
+    (64, 4, 4, False, False, jnp.bfloat16, True),
+    (128, 2, 2, True, False, jnp.float32, False),    # a head a lane block
+    (128, 4, 2, True, False, jnp.float32, False),    # Llama: grouped K/V
+    (128, 4, 2, False, True, jnp.bfloat16, True),
+    (128, 2, 1, True, False, jnp.float32, True),
+    (256, 2, 2, True, False, jnp.float32, False),    # a head two lane blocks
+    (256, 4, 1, True, False, jnp.bfloat16, True),    # Qwen3-Next: 4 on 1, looped
+    (256, 2, 1, False, False, jnp.float32, False),
+    (256, 2, 2, False, True, jnp.float32, True),
+]
+
+
+@pytest.mark.parametrize(
+    "d,h,h_kv,causal,masked,dtype,loop", PROJECTION_LAYOUT,
+    ids=[f"d{d}-{h}on{kv}-{'causal' if c else 'full'}"
+         f"{'-masked' if m else ''}-{jnp.dtype(t).name}-"
+         f"{'loop' if lp else 'prefix'}"
+         for d, h, kv, c, m, t, lp in PROJECTION_LAYOUT])
+def test_projection_layout_matches_xla(monkeypatch, d, h, h_kv, causal,
+                                       masked, dtype, loop):
+    """Forward and all three gradients against XLA attention, and the packed
+    entry against the three-operand one, over what chooses the head-to-lane
+    rule and the form of the tile program."""
+    b, s = 2, 64
+    assert F._pack(h, h // h_kv, d, 1) == max(1, min(128 // d, h))
+    assert F._prefix(causal, s, s, 32, s)
+    if loop:
+        _force_loop_form(monkeypatch)
+        assert not F._prefix(causal, s, s, 32, s)
+    q = _rand((b, s, h, d), dtype, 0)
+    k, v = (_rand((b, s, h_kv, d), dtype, i) for i in (1, 2))
+    w = _rand((b, s, h, d), jnp.float32, 3)
+    kv_mask = None
+    if masked:
+        m = np.ones((b, s), bool)
+        m[0, 40:] = False
+        kv_mask = jnp.asarray(m)
+    kw = dict(causal=causal, kv_mask=kv_mask, block_q=32, block_k=32)
+
+    def rep(t):
+        return jnp.repeat(t, h // h_kv, axis=2)
+
+    def f(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, **kw)
+
+    def ref(q, k, v):
+        return ref_attn(q, rep(k), rep(v), causal=causal, kv_mask=kv_mask)
+
+    def packed(q, k, v):
+        qkv = jnp.concatenate([t.reshape(b, s, -1) for t in (q, k, v)], -1)
+        return F.flash_attention_packed(qkv, h, h_kv, **kw)
+
+    # bf16: one ulp of an output or gradient of size 2-4 is 1.6e-2; grouped
+    # K/V heads sum several such gradients
+    tol = dict(atol=2e-5, rtol=1e-5) if dtype == jnp.float32 else \
+        dict(atol=5e-2 * (h // h_kv), rtol=2e-2)
+    args = (q, k, v)
+    out = jax.jit(flash)(*args)
+    assert out.shape == q.shape and out.dtype == dtype
+    np.testing.assert_allclose(out.astype(np.float32),
+                               ref(*args).astype(np.float32), **tol)
+    got = jit_grad(f(flash), argnums=(0, 1, 2))(*args)
+    want = jit_grad(f(ref), argnums=(0, 1, 2))(*args)
+    for a, b_, t in zip(got, want, args):
+        assert a.shape == t.shape and a.dtype == dtype
+        np.testing.assert_allclose(a.astype(np.float32),
+                                   b_.astype(np.float32), **tol)
+    # the packed entry runs the same kernels on the same numbers
+    np.testing.assert_array_equal(jax.jit(packed)(*args), out)
+    for a, b_ in zip(jit_grad(f(packed), argnums=(0, 1, 2))(*args), got):
+        np.testing.assert_array_equal(a, b_)
+    F._make_flash.cache_clear()
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it, but for the
+    bodies of the Pallas kernels (which transpose tiles in VMEM)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("shape,kv_heads,packed", [
+    ((2, 256, 16, 64), 16, False),      # gpt2_medium's heads
+    ((2, 256, 16, 64), 16, True),
+    ((1, 256, 16, 256), 2, False),      # Qwen3-Next's
+    ((2, 256, 12, 64), 12, True),       # GPT-2-small's
+], ids=["gpt2_medium", "gpt2_medium_packed", "qwen3_next", "gpt2_small"])
+def test_served_shapes_are_neither_transposed_nor_sliced(shape, kv_heads,
+                                                         packed):
+    """Where the kernels read the projections' layout, nothing round them
+    moves data: no transpose of a rank-4 operand, forward or backward, and
+    under the packed entry no slice of the projection's output either."""
+    b, s, h, d = shape
+    assert F._pack(h, h // kv_heads, d, 128)
+    q = jnp.zeros(shape, jnp.bfloat16)
+    kv = jnp.zeros((b, s, kv_heads, d), jnp.bfloat16)
+    if packed:
+        def f(qkv):
+            return jnp.sum(F.flash_attention_packed(
+                qkv, h, kv_heads, causal=True).astype(jnp.float32))
+        args = (jnp.zeros((b, s, 3 * h * d), jnp.bfloat16),)
+    else:
+        def f(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, causal=True)
+                           .astype(jnp.float32))
+        args = (q, kv, kv)
+    jaxpr = jax.make_jaxpr(jax.grad(f, argnums=tuple(range(len(args)))))(
+        *args)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 3
+    for e in eqns:
+        if e.primitive.name == "transpose":
+            assert all(len(x.aval.shape) < 4 for x in e.invars), e
+        assert not (packed and e.primitive.name in ("slice", "dynamic_slice"))
+
+
+def test_a_head_size_that_fits_no_lane_block_is_folded():
+    """D = 80 neither divides nor is a multiple of 128: the kernels read the
+    folded (B*H, S, D) layout, made by a transpose, as before."""
+    shape = (1, 64, 2, 80)
+    q, k, v = (_rand(shape, seed=i) for i in range(3))
+    assert F._pack(2, 1, 80, 1) == 0
+
+    def f(q, k, v):
+        return jnp.sum(jnp.sin(flash_attention(q, k, v, causal=True,
+                                               block_q=32, block_k=32)))
+
+    jaxpr = jax.make_jaxpr(f)(q, k, v)
+    assert any(e.primitive.name == "transpose"
+               and len(e.invars[0].aval.shape) == 4
+               for e in _eqns(jaxpr.jaxpr))
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, causal=True, block_q=32, block_k=32),
+        ref_attn(q, k, v, causal=True), atol=1e-5)
+    qkv = jnp.concatenate([t.reshape(1, 64, -1) for t in (q, k, v)], -1)
+    np.testing.assert_array_equal(
+        F.flash_attention_packed(qkv, 2, causal=True, block_q=32,
+                                 block_k=32),
+        flash_attention(q, k, v, causal=True, block_q=32, block_k=32))
 
 
 @pytest.mark.parametrize("kv_heads", [1, 2])

@@ -12,6 +12,8 @@ fixture: the process that does it holds the TPU library's lock until it exits.
 are neither written to it nor (unreadably, without a chip) looked up in it.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -91,16 +93,22 @@ def test_flash_attention_gpt_small_train_shape(one_chip, kv_heads, grad):
     _compile(jax.grad(loss, argnums=(0, 1, 2)) if grad else loss, q, kv, kv)
 
 
-def _kernel_results(text):
-    """The result types of each ``tpu_custom_call`` of a compiled text, split
-    as ``benchmark/layer_metrics/flash_attn_roofline.py`` splits them."""
+def _kernel_shapes(text):
+    """The result shapes of each ``tpu_custom_call`` of a compiled text."""
     out = []
     for line in text.splitlines():
         if 'custom_call_target="tpu_custom_call"' in line:
             result = line.split(" = ", 1)[1].split(" custom-call(", 1)[0]
-            out.append([p.split("[")[0]
+            out.append([p.split("{")[0]
                         for p in result.strip("()").split("}, ")])
-    return sorted(out)
+    return out
+
+
+def _kernel_results(text):
+    """The result types of each ``tpu_custom_call`` of a compiled text, split
+    as ``benchmark/layer_metrics/flash_attn_roofline.py`` splits them."""
+    return sorted([p.split("[")[0] for p in call]
+                  for call in _kernel_shapes(text))
 
 
 @pytest.mark.parametrize("config", sorted(BENCHMARK_ATTN))
@@ -125,6 +133,54 @@ def test_flash_attention_benchmark_shapes_and_signatures(one_chip, config,
     assert _kernel_results(text) == sorted(want)
 
 
+_BYTES = {"bf16": 2, "f32": 4}
+_SHAPE = re.compile(r"\b(bf16|f32)\[([0-9,]+)\]")
+
+
+def test_gpt2_medium_block_lays_nothing_out_anew_round_the_kernels(
+        one_chip, monkeypatch):
+    """One ``nn.remat`` ``GPTBlock`` of the benchmark's GPT cell, forward,
+    recomputation and backward: the kernels take the ``qkv`` projection's
+    result as it is and hand ``out``, dq, dk and dv over in the projections'
+    layout, so no ``copy`` under ``attn/`` moves a whole activation (the
+    folded layout had 18 of 64 MB each, PERF.md PR 31), and no kernel
+    operand or result has a minor dimension of one head's 64 lanes."""
+    import flax.linen as nn
+
+    from autodist_tpu.models import gpt
+
+    b, s, h, d = BENCHMARK_ATTN["gpt2_medium"]
+    monkeypatch.setattr(F, "_on_tpu", lambda: True)     # compiled kernels
+    cfg = gpt.GPTConfig(vocab_size=512, hidden_size=h * d, num_layers=1,
+                        num_heads=h, intermediate_size=4 * h * d,
+                        max_position=s, dtype=jnp.bfloat16)
+    block = nn.remat(gpt.GPTBlock, static_argnums=(2,))(cfg, name="h_0")
+    params = jax.eval_shape(lambda: block.init(
+        jax.random.key(0), jnp.zeros((1, 8, h * d), jnp.bfloat16), True))
+    params = jax.tree.map(lambda a: _aval(one_chip, a.shape, a.dtype), params)
+
+    def loss(p, x):
+        return jnp.sum(block.apply(p, x, True).astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(loss), params,
+                    _aval(one_chip, (b, s, h * d), jnp.bfloat16))
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 4        # forward, recomputed forward, dq, dk/dv
+    for line in kernels:
+        assert "/attn/" in line
+        for _, dims in _SHAPE.findall(line.split(", metadata=")[0]):
+            assert int(dims.split(",")[-1]) != d, line[:200]
+    whole = b * s * h * d * 2
+    for line in text.splitlines():
+        m = re.search(r" = (\S+) copy\(", line)
+        if m and "/attn/" in line:
+            dtype, dims = _SHAPE.search(m.group(1)).groups()
+            size = _BYTES[dtype] * int(np.prod([int(x) for x in
+                                               dims.split(",")]))
+            assert size < whole, line[:300]
+
+
 # the one softmax-attention layer of the benchmark's Qwen3-Next cell:
 # (B, S, query heads, head size) on QWEN3_NEXT_KV_HEADS K/V heads
 QWEN3_NEXT_ATTN = (4, 8192, 16, 256)
@@ -132,18 +188,23 @@ QWEN3_NEXT_KV_HEADS = 2
 
 
 def test_flash_attention_qwen3_next_shape_and_signatures(one_chip):
-    """D = 256, eight query heads to a K/V head, S = 8,192: one head's K and
-    V rows, double-buffered, pass the 14 MiB budget, so by their own rule
-    the kernels take one head a program under the raised limit and walk the
-    k tiles in a loop (sixteen tiles are too many static cases).  The result
-    signatures are what ``benchmark/layer_metrics/full_attn_roofline.py``
-    tells the kernels apart by: forward two results of different shapes,
-    dq one, dk/dv two of one shape (float32, one per query head)."""
+    """D = 256, eight query heads to a K/V head, S = 8,192: a head is two
+    whole lane blocks of the projections' (B, S, H*D) layout; one head's K
+    and V rows, double-buffered, pass the 14 MiB budget, so by their own
+    rule the kernels take one head a program under the raised limit and walk
+    the k tiles in a loop (sixteen tiles are too many static cases).  The
+    result signatures are what
+    ``benchmark/layer_metrics/full_attn_roofline.py`` tells the kernels apart
+    by: forward two results of different shapes, dq one, dk/dv two of one
+    shape (float32, one per query head)."""
     b, s, h, d = QWEN3_NEXT_ATTN
-    assert F._pick_heads(b * h, h, h // QWEN3_NEXT_KV_HEADS, False, s, s, d,
-                         2, 512, 512) == 1
+    group = h // QWEN3_NEXT_KV_HEADS
+    heads = F._Heads(d, F._pack(h, group, d, 128))
+    assert heads.pack == 1
+    assert F._pick_heads(heads, F._together(heads, b * h, h, group, False),
+                         s, s, 2, 512, 512) == 1
     assert not F._prefix(True, s, s, 512, s)
-    assert F._VMEM_BUDGET < F._vmem_bytes(1, s, s, d, 2, 512, 512) \
+    assert F._VMEM_BUDGET < F._vmem_bytes(heads, 1, s, s, 2, 512, 512) \
         <= F._VMEM_LIMIT * 3 // 4
     q = _aval(one_chip, (b, s, h, d), jnp.bfloat16)
     kv = _aval(one_chip, (b, s, QWEN3_NEXT_KV_HEADS, d), jnp.bfloat16)
@@ -153,17 +214,10 @@ def test_flash_attention_qwen3_next_shape_and_signatures(one_chip):
         return jnp.sum(out.astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    shapes = []
-    for line in text.splitlines():
-        if 'custom_call_target="tpu_custom_call"' in line:
-            result = line.split(" = ", 1)[1].split(" custom-call(", 1)[0]
-            shapes.append([p.split("{")[0]
-                           for p in result.strip("()").split("}, ")])
-    bh = b * h
-    want = [[f"bf16[{bh},{s},{d}]", f"f32[{bh},1,{s}]"],
-            [f"bf16[{bh},{s},{d}]"],
-            [f"f32[{bh},{s},{d}]", f"f32[{bh},{s},{d}]"]]
-    assert sorted(shapes) == sorted(want)
+    want = [[f"bf16[{b},{s},{h * d}]", f"f32[{b * h},1,{s}]"],
+            [f"bf16[{b},{s},{h * d}]"],
+            [f"f32[{b},{s},{h * d}]", f"f32[{b},{s},{h * d}]"]]
+    assert sorted(_kernel_shapes(text)) == sorted(want)
 
 
 # the delta rule of the benchmark's Qwen3-Next cell: (B, S, key heads, value
@@ -190,12 +244,7 @@ def test_gated_delta_rule_qwen3_next_shape_and_signatures(one_chip):
         return jnp.sum(out.astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=range(5)), qk, qk, v, gate, gate)
-    shapes = []
-    for line in text.splitlines():
-        if 'custom_call_target="tpu_custom_call"' in line:
-            result = line.split(" = ", 1)[1].split(" custom-call(", 1)[0]
-            shapes.append([p.split("{")[0]
-                           for p in result.strip("()").split("}, ")])
+    shapes = _kernel_shapes(text)
     blocks, rows = s // (16 * 64), s // 128
     want = [[f"bf16[{b},{s},{h_v * d}]", f"f32[{b},{h_v},{blocks},{d},{d}]"],
             [f"bf16[{b},{s},{h_k * d}]", f"bf16[{b},{s},{h_k * d}]",
