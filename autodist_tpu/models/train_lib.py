@@ -172,22 +172,22 @@ def llama_capture(config, seq_len, rng=None, streaming_loss=False,
     return loss_fn, params, ["embed"]
 
 
-def qwen3_next_capture(config, seq_len, rng=None, loss_chunk=8192):
-    """Init a Qwen3-Next causal LM; returns (loss_fn, params, sparse_vars).
+def _routed_lm_capture(model, seq_len, rng, loss_chunk):
+    """``(loss_fn, params, sparse_vars)`` of a causal LM whose ``apply``
+    returns ``(hidden states, stats)`` with ``stats`` the routed layers'
+    counters (``models/qwen3_next.py:routing_counters``), under an untied
+    ``lm_head`` that the loss streams (``ops/losses.py``, "dv" layout).
 
     ``loss_fn(params, batch) -> (loss, counters)``: pass ``has_aux=True`` to
     ``distribute``; the step's metrics then carry ``moe_rows_here``,
-    ``moe_load_max_over_mean`` and ``moe_overflow_rows``
-    (``models/qwen3_next.py:routing_counters``).  The loss streams the
-    untied head (``ops/losses.py``, "dv" layout).  If any layer was sent
-    more assignments than its ``rows_bound`` holds, the loss is ``inf``: the
-    surplus was not computed, and nobody should train on without knowing.
-    """
-    from autodist_tpu.models.qwen3_next import Qwen3Next, routing_counters
+    ``moe_load_max_over_mean`` and ``moe_overflow_rows``.  If any layer was
+    sent more assignments than its ``rows_bound`` holds, the loss is
+    ``inf``: the surplus was not computed, and nobody should train on
+    without knowing."""
+    from autodist_tpu.models.qwen3_next import routing_counters
     from autodist_tpu.ops.losses import streaming_softmax_xent
 
     rng = rng if rng is not None else host_key(0)
-    model = Qwen3Next(config)
     dummy = jnp.zeros((1, seq_len), jnp.int32)
     params = model.init(rng, dummy, return_hidden=True)["params"]
 
@@ -204,6 +204,23 @@ def qwen3_next_capture(config, seq_len, rng=None, loss_chunk=8192):
                          loss), counters
 
     return loss_fn, params, []
+
+
+def qwen3_next_capture(config, seq_len, rng=None, loss_chunk=8192):
+    """Init a Qwen3-Next causal LM; returns (loss_fn, params, sparse_vars)
+    as ``_routed_lm_capture`` describes them."""
+    from autodist_tpu.models.qwen3_next import Qwen3Next
+
+    return _routed_lm_capture(Qwen3Next(config), seq_len, rng, loss_chunk)
+
+
+def nemotron_h_capture(config, seq_len, rng=None, loss_chunk=8192):
+    """Init a Nemotron-H causal LM (``models/nemotron_h.py``); returns
+    (loss_fn, params, sparse_vars) as ``_routed_lm_capture`` describes
+    them.  The pattern needs a routed layer."""
+    from autodist_tpu.models.nemotron_h import NemotronH
+
+    return _routed_lm_capture(NemotronH(config), seq_len, rng, loss_chunk)
 
 
 def lm_capture(config, seq_len, rng=None):

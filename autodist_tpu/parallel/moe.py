@@ -7,9 +7,13 @@ computes the part of the result that its own experts give: the experts
 ``first_expert ... first_expert + experts_held`` whose weights it was handed.
 The weights of a token's ``top_k`` are normalised over all of them, held here
 or not, so the parts that the shares of a layer give add up to the whole
-layer (``tests/test_moe.py``).  One chip that holds a share runs it as it is;
-under an ``expert`` mesh axis (``axis_name``) every device holds its own
-share and the parts are summed over the axis.
+layer (``tests/test_moe.py``).  How the scores are made is the model's and
+comes in as data (``route``: a softmax over the experts; a sigmoid an expert
+with a bias that only the choice sees and a scale on the weights), and so is
+an expert's body (``expert_layer``: gate and up around an activation, or up
+alone).  One chip that holds a share runs it as it is; under an ``expert``
+mesh axis (``axis_name``) every device holds its own share and the parts are
+summed over the axis.
 
 Static shapes throughout.  The assignments to held experts are packed,
 sorted by expert, into a buffer of ``rows_bound`` rows (default: the worst
@@ -18,8 +22,8 @@ case ``T * min(top_k, experts_held)``), run through grouped matrix products
 kernel) and added back onto their tokens with their weights.  If more
 assignments arrive than ``rows_bound`` the surplus is NOT computed and
 ``overflow_rows`` counts it: the caller makes the step's loss non-finite
-(``models/train_lib.py:qwen3_next_capture``), so a bound set too low is seen
-at once and never trains on silently.
+(``models/train_lib.py``, the models' captures), so a bound set too low is
+seen at once and never trains on silently.
 
 What is still missing for expert parallelism as a strategy dimension is in
 ROADMAP R1: the expert axis in the strategy space, an all-to-all exchange
@@ -35,15 +39,16 @@ from autodist_tpu.parallel.tensor_parallel import copy_to_tp, reduce_from_tp
 
 
 @functools.partial(jax.checkpoint, static_argnums=(1,))
-def top_k_of(p, k):
+def top_k_of(p, k, chosen_by=None):
     """``(values, indices)`` of the ``k`` largest of each row of ``p``
     ``[T, E]``, largest first, the lower index first among equals (as
     ``lax.top_k``): ``k`` passes of ``argmax`` and a masked sum, all in
     the vector unit, where a sort of every row, or a gather of the values
     and its scatter going backward, is not (PERF.md, PR 28: the gather
     alone took 3.3 ms a layer).  Differentiable in the values; the
-    backward pass makes the masks again from ``p``."""
-    rest = jax.lax.stop_gradient(p)
+    backward pass makes the masks again from ``p``.  With ``chosen_by``
+    (``[T, E]``) the ``k`` are its largest and the values still ``p``'s."""
+    rest = jax.lax.stop_gradient(p if chosen_by is None else chosen_by)
     lanes = jnp.arange(p.shape[-1], dtype=jnp.int32)
     values, picked = [], []
     for _ in range(k):
@@ -55,17 +60,24 @@ def top_k_of(p, k):
     return jnp.stack(values, axis=-1), jnp.stack(picked, axis=-1)
 
 
-def route(x, router_w, top_k, norm_topk=True):
-    """``(expert indices [T, k], weights [T, k] float32)``: a float32 softmax
-    over all the router's outputs, the ``top_k`` largest, and (with
-    ``norm_topk``) their probabilities divided by the sum of the k."""
+def route(x, router_w, top_k, norm_topk=True, score=jax.nn.softmax,
+          select_bias=None, scale=None, norm_eps=None):
+    """``(expert indices [T, k], weights [T, k] float32)``: ``score`` of the
+    router's float32 outputs over all the experts (a softmax over them, or
+    ``jax.nn.sigmoid`` an expert), the ``top_k`` largest of the scores (of
+    ``scores + select_bias`` ``[E]`` where one is given: the bias decides who
+    is chosen and never enters a weight, nor gets a gradient), and as
+    weights the chosen scores, with ``norm_topk`` divided by the sum of the
+    k (``+ norm_eps``), times ``scale``."""
     logits = jnp.einsum("td,de->te", x, router_w.astype(x.dtype),
                         preferred_element_type=jnp.float32)
-    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, idx = top_k_of(p, top_k)
+    p = score(logits.astype(jnp.float32))
+    w, idx = top_k_of(p, top_k,
+                      None if select_bias is None else p + select_bias)
     if norm_topk:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return idx, w
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        w = w / (total if norm_eps is None else total + norm_eps)
+    return idx, (w if scale is None else w * scale)
 
 
 def pack_held(idx, first_expert, experts_held, rows_bound):
@@ -104,16 +116,20 @@ def pack_held(idx, first_expert, experts_held, rows_bound):
 
 def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k,
                  first_expert=0, rows_bound=None, norm_topk=True,
-                 axis_name=None, tokens_sharded=False):
-    """The held experts' part of a routed SwiGLU feed-forward.
+                 axis_name=None, tokens_sharded=False,
+                 activation=jax.nn.silu, **scoring):
+    """The held experts' part of a routed feed-forward.
 
     Args:
       x: ``[T, D]`` tokens.
       router_w: ``[D, experts_total]``, the whole router.
       w_gate, w_up: ``[experts_held, D, F]``; w_down: ``[experts_held, F,
-        D]``: the experts ``first_expert ...`` of the layer.
-      top_k: experts a token takes; norm_topk: divide their probabilities
-        by their sum.
+        D]``: the experts ``first_expert ...`` of the layer.  An expert is
+        ``(activation(x w_gate) * (x w_up)) w_down``, or with ``w_gate``
+        ``None`` ``activation(x w_up) w_down``: two grouped products.
+      top_k: experts a token takes; norm_topk: divide their scores by their
+        sum; scoring: ``route``'s ``score``, ``select_bias``, ``scale`` and
+        ``norm_eps``.
       rows_bound: rows of the packed buffer; ``None`` is the worst case.
       axis_name: inside ``shard_map`` over an expert mesh axis, the axis:
         device ``i`` holds the experts from ``i * experts_held`` and the
@@ -127,7 +143,7 @@ def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k,
     mean) and ``overflow_rows`` (assignments past ``rows_bound``, not
     computed).
     """
-    experts_held = w_gate.shape[0]
+    experts_held = w_up.shape[0]
     if axis_name is not None:
         # what every device of the axis holds alike enters through a copy
         # whose backward pass sums the devices' gradients (and the sum
@@ -142,7 +158,7 @@ def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k,
     if rows_bound is None:
         rows_bound = t * min(top_k, experts_held)
     with jax.named_scope("moe.route"):
-        idx, weights = route(x, router_w, top_k, norm_topk)
+        idx, weights = route(x, router_w, top_k, norm_topk, **scoring)
         flat, group_sizes, counts = pack_held(idx, first_expert,
                                               experts_held, rows_bound)
         token = flat // top_k                   # t for an empty row: dropped
@@ -154,7 +170,10 @@ def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k,
             return jax.lax.ragged_dot(a, w.astype(a.dtype), group_sizes,
                                       preferred_element_type=jnp.float32)
 
-        h = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+        if w_gate is None:
+            h = activation(grouped(rows, w_up))
+        else:
+            h = activation(grouped(rows, w_gate)) * grouped(rows, w_up)
         y = grouped(h.astype(x.dtype), w_down) * w_row[:, None]
     with jax.named_scope("moe.route"):
         # rows past the packed ones carry weight 0, but what a grouped

@@ -1,6 +1,10 @@
-"""The expert layer (``parallel/moe.py``) against the plain reference's routed
-feed-forward (``tests/qwen3_next_reference.py``: a loop over the held experts
-with a mask), on one device and over an ``expert`` mesh axis.
+"""The expert layer (``parallel/moe.py``) against the plain references' routed
+feed-forwards (a loop over the held experts with a mask), on one device and
+over an ``expert`` mesh axis, under both scoring rules and both expert bodies
+the layer knows: a softmax over the experts with SwiGLU experts and a gated
+shared expert (``tests/qwen3_next_reference.py``), and a sigmoid an expert
+with a selection bias, scaled weights, ``relu(.)^2`` experts of two matrices
+and an ungated shared expert (``tests/nemotron_h_reference.py``).
 
 float32 on both sides; what differs is the order of the sums (packed grouped
 products and a scatter-add against masked dense products), so 1e-5 of the
@@ -17,28 +21,38 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import nemotron_h_reference as RN
 import qwen3_next_reference as R
+from autodist_tpu.models import nemotron_h as N
 from autodist_tpu.models import qwen3_next as Q
 from autodist_tpu.parallel.mesh import build_mesh
 from autodist_tpu.parallel.moe import (expert_layer, pack_held, route,
                                        top_k_of)
 
 E, D, F, T, K = 8, 16, 32, 64, 2
+SCALE = 2.5
+RULES = ["softmax_swiglu", "sigmoid_relu2"]
 
 
-def weights(seed=0, experts=E):
+def weights(seed=0, experts=E, rule="softmax_swiglu"):
     r = np.random.RandomState(seed)
 
     def w(*shape, scale):
         return jnp.asarray(r.randn(*shape) * scale, jnp.float32)
 
-    return {"router": w(D, E, scale=0.5),
-            "gate": w(experts, D, F, scale=0.3),
-            "up": w(experts, D, F, scale=0.3),
-            "down": w(experts, F, D, scale=0.3),
-            "shared_gate": w(D, F, scale=0.3), "shared_up": w(D, F, scale=0.3),
-            "shared_down": w(F, D, scale=0.3),
-            "shared_router": w(D, 1, scale=0.5)}
+    p = {"router": w(D, E, scale=0.5),
+         "gate": w(experts, D, F, scale=0.3),
+         "up": w(experts, D, F, scale=0.3),
+         "down": w(experts, F, D, scale=0.3),
+         "shared_gate": w(D, F, scale=0.3), "shared_up": w(D, F, scale=0.3),
+         "shared_down": w(F, D, scale=0.3),
+         "shared_router": w(D, 1, scale=0.5)}
+    if rule == "sigmoid_relu2":
+        # a bias the size of the scores' spread: it changes the choice
+        p = {k: v for k, v in p.items()
+             if k not in ("gate", "shared_gate", "shared_router")}
+        p["router_bias"] = w(E, scale=0.2)
+    return p
 
 
 def tokens(seed=1, t=T):
@@ -46,21 +60,33 @@ def tokens(seed=1, t=T):
 
 
 def reference(p, x, first=0):
-    """``(routed part, shared part, counts)`` of the reference's layer."""
-    cfg = {"num_experts_per_tok": K, "first_expert": first}
-    whole, counts = R.routed_feed_forward(p, x, cfg)
-    shared = jax.nn.sigmoid(x @ p["shared_router"]) * R.swiglu(
-        x, p["shared_gate"], p["shared_up"], p["shared_down"])
+    """``(routed part, shared part, counts)`` of the reference's layer, the
+    rule read off the weights."""
+    if "router_bias" in p:
+        cfg = {"num_experts_per_tok": K, "first_expert": first,
+               "routed_scaling_factor": SCALE}
+        whole, counts = RN.routed_feed_forward(p, x, cfg)
+        shared = RN.expert(x, p["shared_up"], p["shared_down"])
+    else:
+        cfg = {"num_experts_per_tok": K, "first_expert": first}
+        whole, counts = R.routed_feed_forward(p, x, cfg)
+        shared = jax.nn.sigmoid(x @ p["shared_router"]) * R.swiglu(
+            x, p["shared_gate"], p["shared_up"], p["shared_down"])
     return whole - shared, shared, counts
 
 
 def layer(p, x, **kw):
-    return expert_layer(x, p["router"], p["gate"], p["up"], p["down"],
+    if "router_bias" in p:
+        kw = dict(activation=N.relu2, score=jax.nn.sigmoid,
+                  select_bias=p["router_bias"], scale=SCALE, norm_eps=1e-20,
+                  **kw)
+    return expert_layer(x, p["router"], p.get("gate"), p["up"], p["down"],
                         top_k=K, **kw)
 
 
 def share(p, lo, hi):
-    return {**p, **{k: p[k][lo:hi] for k in ("gate", "up", "down")}}
+    return {**p, **{k: p[k][lo:hi] for k in ("gate", "up", "down")
+                    if k in p}}
 
 
 def close(got, want, rtol=1e-5):
@@ -71,21 +97,23 @@ def close(got, want, rtol=1e-5):
 
 # --------------------------------------------------------- one device ----
 
+@pytest.mark.parametrize("rule", RULES)
 @pytest.mark.parametrize("first,held", [(0, 8), (4, 4), (6, 2)])
-def test_expert_layer_against_the_reference(first, held):
-    p, x = share(weights(), first, first + held), tokens()
+def test_expert_layer_against_the_reference(first, held, rule):
+    p, x = share(weights(rule=rule), first, first + held), tokens()
 
     def run(f):
         return jax.jit(jax.value_and_grad(
             lambda p, x: jnp.sum(f(p, x) ** 2), argnums=(0, 1)))(p, x)
 
-    keys = ("router", "gate", "up", "down")
     got, (gp, gx) = run(lambda p, x: layer(p, x, first_expert=first)[0])
     want, (wp, wx) = run(lambda p, x: reference(p, x, first)[0])
     close(got, want)
     close(gx, wx)
-    for k in keys:
+    for k in set(p) & {"router", "gate", "up", "down"}:
         close(gp[k], wp[k])
+    if "router_bias" in p:          # only the choice reads it
+        assert not np.any(np.asarray(gp["router_bias"]))
     stats = jax.jit(lambda p, x: layer(p, x, first_expert=first)[1])(p, x)
     counts = np.asarray(jax.jit(lambda p, x: reference(p, x, first)[2])(p, x))
     assert float(stats["rows_here"]) == counts.sum()
@@ -123,6 +151,38 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert rows == x.shape[0] * x.shape[1] * c.num_experts_per_tok
 
 
+def test_the_sixteen_shares_of_eight_add_up_to_the_uncut_layer():
+    """The same for the sigmoid router's layer at the deployment's cut: the
+    parts that all 16 shares of 8 of the 128 experts give, with the ungated
+    shared expert counted once, are the uncut reference's layer; every
+    share scores all 128 and takes six, under a seeded selection bias."""
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 24, 64), jnp.float32)
+    c = dataclasses.replace(N.NEMOTRON_H_TINY, n_routed_experts=128,
+                            num_experts_per_tok=6, experts_held=None)
+    whole = N.RoutedFFN(c)
+    p = jax.jit(whole.init)(jax.random.PRNGKey(0), x)["params"]
+    p = jax.tree.map(lambda w: w * 8, p)            # a router with opinions
+    p["router_bias"] = 0.2 * jnp.asarray(
+        np.random.RandomState(4).randn(128), jnp.float32)
+    cfg = {"num_experts_per_tok": 6, "routed_scaling_factor": 2.5}
+    want = jax.jit(jax.vmap(
+        lambda t: RN.routed_feed_forward(p, t, cfg)[0]))(x)
+    shared = RN.expert(x.reshape(-1, 64), p["shared_up"],
+                       p["shared_down"]).reshape(x.shape)
+    total, rows = -15 * shared, 0
+    for first in range(0, 128, 8):
+        part = dataclasses.replace(c, experts_held=8, first_expert=first)
+        y, stats = jax.jit(N.RoutedFFN(part).apply)(
+            {"params": {**p, "up": p["up"][first:first + 8],
+                        "down": p["down"][first:first + 8]}}, x)
+        total = total + y
+        rows += float(stats[0])
+    close(total, want)
+    close(jax.jit(whole.apply)({"params": p}, x)[0], want)
+    # every assignment lands on exactly one of the sixteen shares
+    assert rows == x.shape[0] * x.shape[1] * 6
+
+
 def test_top_k_by_argmax_is_lax_top_k():
     r = np.random.RandomState(4)
     p = jnp.asarray(r.rand(50, 16), jnp.float32)
@@ -147,6 +207,40 @@ def test_routing_weights_are_normalised_over_all_the_chosen():
     assert np.all(np.asarray(idx[:, 0]) != np.asarray(idx[:, 1]))
 
 
+def test_sigmoid_scores_are_normalised_and_scaled():
+    p, x = weights(rule="sigmoid_relu2"), tokens()
+    rule = dict(score=jax.nn.sigmoid, norm_eps=1e-20)
+    idx, w = route(x, p["router"], K, scale=SCALE, **rule)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), SCALE, rtol=1e-6)
+    scores = jax.nn.sigmoid(x @ p["router"])
+    _, raw = route(x, p["router"], K, norm_topk=False, **rule)
+    close(raw, jnp.take_along_axis(scores, idx, -1))
+    # every expert on its own: the scores of a row do not add up to one
+    assert float(jnp.max(jnp.abs(scores.sum(-1) - 1.0))) > 0.5
+
+
+def test_the_selection_bias_changes_who_is_chosen_and_not_the_weights():
+    p, x = weights(rule="sigmoid_relu2"), tokens()
+    rule = dict(score=jax.nn.sigmoid, scale=SCALE, norm_eps=1e-20)
+    plain_i, plain_w = route(x, p["router"], K, **rule)
+    idx, w = route(x, p["router"], K, select_bias=p["router_bias"], **rule)
+    scores = jax.nn.sigmoid(x @ p["router"])
+    _, want_i = jax.lax.top_k(scores + p["router_bias"], K)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_i))
+    moved = np.any(np.asarray(idx) != np.asarray(plain_i), axis=-1)
+    assert 0 < moved.sum() < T
+    # the weights are the chosen experts' scores as they are, without the
+    # bias: where the choice is the same so are they, to the last bit
+    chosen = jnp.take_along_axis(scores, idx, -1)
+    close(w, SCALE * chosen / chosen.sum(-1, keepdims=True))
+    np.testing.assert_array_equal(np.asarray(w)[~moved],
+                                  np.asarray(plain_w)[~moved])
+    # and no gradient reaches it
+    g = jax.grad(lambda b: jnp.sum(route(
+        x, p["router"], K, select_bias=b, **rule)[1] ** 2))(p["router_bias"])
+    assert not np.any(np.asarray(g))
+
+
 @pytest.mark.parametrize("rows_bound", [200, 20, 7])
 def test_packed_rows_are_sorted_by_expert_then_token(rows_bound):
     idx = jnp.asarray(np.random.RandomState(5).randint(0, 8, (40, 3)),
@@ -165,11 +259,12 @@ def test_packed_rows_are_sorted_by_expert_then_token(rows_bound):
 
 # ------------------------------------------- past the bound of the rows ----
 
-def test_overflow_is_counted_and_the_kept_rows_are_right():
+@pytest.mark.parametrize("rule", RULES)
+def test_overflow_is_counted_and_the_kept_rows_are_right(rule):
     """What the old layer's capacity dropped in silence: assignments past
     ``rows_bound`` are counted, and what is computed is exactly the
     assignments that fit (the first experts' rows)."""
-    p, x = weights(), tokens()
+    p, x = weights(rule=rule), tokens()
     full, stats = jax.jit(layer)(p, x)
     counts = np.asarray(jax.jit(lambda p, x: reference(p, x)[2])(p, x))
     bound = int(counts[:3].sum())                   # room for three experts
@@ -182,19 +277,24 @@ def test_overflow_is_counted_and_the_kept_rows_are_right():
     assert not np.allclose(np.asarray(cut), np.asarray(full))
 
 
-def test_overflow_makes_the_loss_non_finite():
-    from autodist_tpu.models.train_lib import qwen3_next_capture
+@pytest.mark.parametrize("rule", RULES)
+def test_overflow_makes_the_loss_non_finite(rule):
+    from autodist_tpu.models import train_lib
 
     s = 24
     batch = {"tokens": jnp.zeros((2, s), jnp.int32),
              "targets": jnp.ones((2, s), jnp.int32)}
+    capture, tiny = {
+        "softmax_swiglu": (train_lib.qwen3_next_capture, dataclasses.replace(
+            Q.QWEN3_NEXT_TINY, num_layers=1, full_attention_interval=1)),
+        "sigmoid_relu2": (train_lib.nemotron_h_capture, dataclasses.replace(
+            N.NEMOTRON_H_TINY, pattern="E"))}[rule]
     for bound, finite in ((None, True), (8, False)):
-        c = dataclasses.replace(Q.QWEN3_NEXT_TINY, rows_bound=bound,
-                                num_layers=1, full_attention_interval=1)
+        c = dataclasses.replace(tiny, rows_bound=bound)
         made = {}
 
         def init(key):
-            made["loss_fn"], params, _ = qwen3_next_capture(c, s, rng=key)
+            made["loss_fn"], params, _ = capture(c, s, rng=key)
             return params
 
         params = jax.jit(init)(jax.random.PRNGKey(0))
@@ -206,25 +306,27 @@ def test_overflow_makes_the_loss_non_finite():
 
 # ------------------------------------------------ over an expert axis ----
 
+@pytest.mark.parametrize("rule", RULES)
 @pytest.mark.parametrize("tokens_sharded", [False, True],
                          ids=["tokens_replicated", "tokens_sharded"])
-def test_expert_parallel_matches_dense(tokens_sharded):
+def test_expert_parallel_matches_dense(tokens_sharded, rule):
     """Eight devices, one expert each: the parts summed over the axis are
     the whole layer, whether every device brings all the tokens or an
     eighth of them, and so are the gradients taken inside the
     ``shard_map`` (the router's alike on every device)."""
     mesh = build_mesh(axes={"expert": 8})
-    p, x = weights(), tokens()
-    keys = ("router", "gate", "up", "down")
+    p, x = weights(rule=rule), tokens()
+    alike = [k for k in ("router", "router_bias") if k in p]
+    held = [k for k in ("gate", "up", "down") if k in p]
+    keys = alike + held
 
     def dense(p, x):
         y = reference(p, x)[0]
         return jnp.sum(y ** 2), y
 
     def mine(p, x):
-        y, stats = expert_layer(x, p["router"], p["gate"], p["up"],
-                                p["down"], top_k=K, axis_name="expert",
-                                tokens_sharded=tokens_sharded)
+        y, stats = layer(p, x, axis_name="expert",
+                         tokens_sharded=tokens_sharded)
         return jnp.sum(y ** 2), (y, stats["rows_here"])
 
     def on_a_device(x, *ws):
@@ -234,12 +336,10 @@ def test_expert_parallel_matches_dense(tokens_sharded):
             + tuple(gp[k] for k in keys)
 
     spec = jax.P("expert") if tokens_sharded else jax.P()
-    held = jax.P("expert")
+    specs = (jax.P(),) * len(alike) + (jax.P("expert"),) * len(held)
     got, rows, gx, *gp = jax.jit(jax.shard_map(
-        on_a_device, mesh=mesh,
-        in_specs=(spec, jax.P(), held, held, held),
-        out_specs=(spec, jax.P(), spec, jax.P(), held, held, held),
-        check_vma=False,
+        on_a_device, mesh=mesh, in_specs=(spec,) + specs,
+        out_specs=(spec, jax.P(), spec) + specs, check_vma=False,
     ))(x, *(p[k] for k in keys))
     (_, want), (wp, wx) = jax.value_and_grad(
         dense, argnums=(0, 1), has_aux=True)(p, x)

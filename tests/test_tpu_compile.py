@@ -252,6 +252,73 @@ def test_gated_delta_rule_qwen3_next_shape_and_signatures(one_chip):
     assert sorted(shapes) == sorted(want)
 
 
+# the attention layer and a Mamba-2 layer of the benchmark's Nemotron-H cell
+NEMOTRON_H_ATTN = (2, 8192, 32, 128)
+NEMOTRON_H_KV_HEADS = 2
+NEMOTRON_H_TOKENS = (2, 8192)
+
+
+def test_flash_attention_nemotron_h_shape_and_signatures(one_chip):
+    """D = 128, sixteen query heads to a K/V head, S = 8,192, no rotary and
+    no gate: a head is one whole lane block of the projections' (B, S, H*D)
+    layout, and a K/V head's 16 x 128 = 2,048 query lanes are what
+    Qwen3-Next's 8 x 256 are.  One head's K and V rows, double-buffered,
+    pass the 14 MiB budget (16.3 MB reckoned), so by their own rule the
+    kernels take one head a program under the raised limit and walk the k
+    tiles in a loop.  The result signatures are those
+    ``benchmark/layer_metrics/full_attn_roofline.py`` tells the kernels
+    apart by."""
+    b, s, h, d = NEMOTRON_H_ATTN
+    group = h // NEMOTRON_H_KV_HEADS
+    heads = F._Heads(d, F._pack(h, group, d, 128))
+    assert heads.pack == 1
+    assert F._pick_heads(heads, F._together(heads, b * h, h, group, False),
+                         s, s, 2, 512, 512) == 1
+    assert not F._prefix(True, s, s, 512, s)
+    assert F._VMEM_BUDGET < F._vmem_bytes(heads, 1, s, s, 2, 512, 512) \
+        == 16252928 <= F._VMEM_LIMIT * 3 // 4
+    q = _aval(one_chip, (b, s, h, d), jnp.bfloat16)
+    kv = _aval(one_chip, (b, s, NEMOTRON_H_KV_HEADS, d), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = F.flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    want = [[f"bf16[{b},{s},{h * d}]", f"f32[{b * h},1,{s}]"],
+            [f"bf16[{b},{s},{h * d}]"],
+            [f"f32[{b},{s},{h * d}]", f"f32[{b},{s},{h * d}]"]]
+    assert sorted(_kernel_shapes(text)) == sorted(want)
+
+
+def test_mamba2_layer_nemotron_h_fits_its_share_of_the_step(one_chip):
+    """One Mamba-2 mixer at the cell's 16,384 tokens and published widths,
+    forward and backward in bfloat16 (no kernel: the chunked scan is matrix
+    products and a ``lax.scan``): the compiler takes it, and its temporaries
+    stay under 2.5 GB, so the blocks of chunks of ``ops/ssd.py`` and the
+    checkpointed float32 stretches do what they are there for (the ``L``
+    tiles alone are 0.54 GB in float32 were they kept for the sequence)."""
+    from autodist_tpu.models.nemotron_h import Mamba2Mixer, NemotronHConfig
+
+    mixer = Mamba2Mixer(NemotronHConfig())
+    x = _aval(one_chip, NEMOTRON_H_TOKENS + (2688,), jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: _aval(one_chip, a.shape, a.dtype),
+        jax.eval_shape(mixer.init, jax.random.PRNGKey(0), x)["params"])
+
+    def loss(p, x):
+        return jnp.sum(mixer.apply({"params": p}, x).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile(compiler_options=TPU_DEFAULTS)
+    text = compiled.as_text()
+    assert "ssd.scan" in text and "ssd.proj" in text
+    assert "tpu_custom_call" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"Mamba-2 layer, 16,384 tokens: temp_size_in_bytes {temp}")
+    assert temp < 2.5e9, temp
+
+
 def test_flash_block_update_ring_step(one_chip):
     # one ring step of a device that holds S=1024 positions of 2 x 12 heads
     bh, s, d = 2 * GPT_ATTN[2], 1024, GPT_ATTN[3]
