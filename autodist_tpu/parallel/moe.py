@@ -18,8 +18,17 @@ summed over the axis.
 Static shapes throughout.  The assignments to held experts are packed,
 sorted by expert, into a buffer of ``rows_bound`` rows (default: the worst
 case ``T * min(top_k, experts_held)``), run through grouped matrix products
-(``lax.ragged_dot``, which the TPU compiler turns into a grouped-matmul
-kernel) and added back onto their tokens with their weights.  If more
+and added back onto their tokens with their weights.  ``lax.ragged_dot`` is
+the grouped product's definition and what runs on a CPU.  On a TPU the
+products, forward and both backward, are the Pallas kernels of
+``ops/pallas/grouped_matmul.py`` wherever their tile rule takes the shapes:
+the compiler's own grouped kernel skips the rows that are not there as
+these do, but takes its tiles from a table, and at widths that are no
+multiple of 256 (Nemotron-H's 2,688 and 1,856) it runs in 512 x 128 x 128
+tiles at 5.8 % of the products' roofline, where the kernels' tiles follow
+the widths (PERF.md, PR 34).  The visits of row tiles are listed once a
+layer for all its products.  Nothing selects the path but the backend and
+the shapes.  If more
 assignments arrive than ``rows_bound`` the surplus is NOT computed and
 ``overflow_rows`` counts it: the caller makes the step's loss non-finite
 (``models/train_lib.py``, the models' captures), so a bound set too low is
@@ -35,6 +44,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from autodist_tpu.ops.pallas import flash_attention
+from autodist_tpu.ops.pallas import grouped_matmul as kernels
 from autodist_tpu.parallel.tensor_parallel import copy_to_tp, reduce_from_tp
 
 
@@ -166,9 +177,20 @@ def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k,
                          fill_value=0.0)
         rows = jnp.take(x, token, axis=0, mode="fill", fill_value=0)
     with jax.named_scope("moe.experts"):
-        def grouped(a, w):
-            return jax.lax.ragged_dot(a, w.astype(a.dtype), group_sizes,
-                                      preferred_element_type=jnp.float32)
+        # the kernels on a TPU (asked as the flash kernels ask, so that a
+        # deviceless compile for a TPU takes the same path) where their tile
+        # rule takes both products' shapes; ``ragged_dot`` everywhere else
+        if flash_attention._on_tpu() and all(
+                kernels.tiles(rows_bound, *w.shape[1:], experts_held,
+                              x.dtype.itemsize) for w in (w_up, w_down)):
+            visits = kernels.row_tiles(group_sizes, rows_bound)
+
+            def grouped(a, w):
+                return kernels.grouped_matmul(a, w, visits)
+        else:
+            def grouped(a, w):
+                return jax.lax.ragged_dot(a, w.astype(a.dtype), group_sizes,
+                                          preferred_element_type=jnp.float32)
 
         if w_gate is None:
             h = activation(grouped(rows, w_up))

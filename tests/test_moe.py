@@ -304,6 +304,51 @@ def test_overflow_makes_the_loss_non_finite(rule):
         assert float(aux["moe_rows_here"]) > 8
 
 
+# ------------------------------------------------ through the kernels ----
+
+@pytest.mark.parametrize("rule", RULES)
+def test_the_kernels_path_is_the_ragged_dot_path(rule, monkeypatch):
+    """``expert_layer`` as a TPU runs it (the grouped products through the
+    Pallas kernels of ``ops/pallas/grouped_matmul.py``, here in the Pallas
+    interpreter) against the ``ragged_dot`` path: ``y``, ``stats`` and every
+    gradient, with room for three times the rows that are there, and what
+    lies past them (NaN by the kernels' leave) reaching nothing."""
+    from autodist_tpu.ops.pallas import flash_attention
+    from autodist_tpu.ops.pallas import grouped_matmul as G
+    from autodist_tpu.parallel import moe
+
+    p, x = weights(rule=rule), tokens()
+    bound = 3 * T * K
+
+    def run():
+        def loss(p, x):
+            y, stats = layer(p, x, rows_bound=bound)
+            return jnp.sum(y ** 2), (y, stats)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(p, x)
+
+    (_, (want, want_stats)), (wp, wx) = run()
+    calls, compiled = [], G.grouped_matmul
+
+    def interpreted(a, w, visits):
+        calls.append(a.shape)
+        # what the kernels leave past the packed rows is anything at all
+        past = (jnp.arange(a.shape[0]) >= visits[0][-1])[:, None]
+        return jnp.where(past, jnp.nan,
+                         compiled(a, w, visits, interpret=True))
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(moe.kernels, "grouped_matmul", interpreted)
+    (_, (got, got_stats)), (gp, gx) = run()
+    assert calls == [(bound, D)] * (2 if "gate" in p else 1) + [(bound, F)]
+    close(got, want)
+    close(gx, wx)
+    for k in set(p) & {"router", "gate", "up", "down"}:
+        close(gp[k], wp[k])
+    assert got_stats == want_stats
+    assert float(got_stats["rows_here"]) == T * K
+
+
 # ------------------------------------------------ over an expert axis ----
 
 @pytest.mark.parametrize("rule", RULES)

@@ -25,6 +25,7 @@ from autodist_tpu.models.llama import LlamaConfig
 from autodist_tpu.ops.pallas import flash_attention as F
 from autodist_tpu.ops.pallas import fused_norm as N
 from autodist_tpu.ops.pallas import gated_delta as G
+from autodist_tpu.ops.pallas import grouped_matmul as M
 from autodist_tpu.ops.pallas import quantize as Q
 
 # GPT-2-small's training shape in chip_smoke.py: (B, S, H, D)
@@ -250,6 +251,57 @@ def test_gated_delta_rule_qwen3_next_shape_and_signatures(one_chip):
             [f"bf16[{b},{s},{h_k * d}]", f"bf16[{b},{s},{h_k * d}]",
              f"bf16[{b},{s},{h_v * d}]", f"f32[{b},{h_v},{rows},8,128]"]]
     assert sorted(shapes) == sorted(want)
+
+
+# the routed layers of the benchmark's two routed cells: (rows_bound, hidden,
+# expert width, experts held)
+GROUPED = {"nemotron_h": (18432, 2688, 1856, 8),
+           "qwen3_next": (40960, 2048, 512, 16)}
+
+
+def _grouped_kernels(one_chip, m, k, n, g, dtype):
+    """The compiled text and the kernels' result shapes of the grouped
+    product ``[m, k] x [g, k, n]`` with both its gradients, rows in
+    ``dtype`` on float32 weights."""
+    def loss(a, w, sizes):
+        out = M.grouped_matmul(a, w, M.row_tiles(sizes, m))
+        return jnp.sum(out * out)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)),
+                    _aval(one_chip, (m, k), dtype),
+                    _aval(one_chip, (g, k, n), jnp.float32),
+                    _aval(one_chip, (g,), jnp.int32))
+    return text, sorted(_kernel_shapes(text))
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED))
+@pytest.mark.parametrize("down", [False, True], ids=["up", "down"])
+def test_grouped_matmul_benchmark_shapes_and_signatures(one_chip, cell, down):
+    """The grouped product and its two backward products at a routed
+    layer's shapes (``[m, hidden] x [g, hidden, width]`` and its transpose),
+    bfloat16 rows on float32 weights, inside the VMEM an operation may scope
+    by default (no limit is passed).  Results: the product in float32, the
+    rows' cotangent in bfloat16, the weights' gradient in float32."""
+    m, d, f, g = GROUPED[cell]
+    k, n = (f, d) if down else (d, f)
+    assert M.tiles(m, k, n, g, 2) is not None
+    assert M._PARAMS.vmem_limit_bytes is None
+    text, shapes = _grouped_kernels(one_chip, m, k, n, g, jnp.bfloat16)
+    assert shapes == sorted(
+        [[f"f32[{m},{n}]"], [f"bf16[{m},{k}]"], [f"f32[{g},{k},{n}]"]])
+    assert "vmem_limit_bytes" not in text
+    assert "ragged-dot" not in text
+
+
+def test_grouped_matmul_tiled_contraction_compiles(one_chip):
+    """A contraction too long to lie whole beside a row tile (float32,
+    8,192 wide) is summed over tiles in VMEM scratch: the one form of the
+    product no benchmark cell runs."""
+    m, k, n, g = 2048, 8192, 384, 4
+    assert M.tiles(m, k, n, g, 4).product[0] < k
+    _, shapes = _grouped_kernels(one_chip, m, k, n, g, jnp.float32)
+    assert shapes == sorted(
+        [[f"f32[{m},{n}]"], [f"f32[{m},{k}]"], [f"f32[{g},{k},{n}]"]])
 
 
 # the attention layer and a Mamba-2 layer of the benchmark's Nemotron-H cell

@@ -660,8 +660,10 @@ def main():
     def expert_parallel():
         """MoE expert parallelism: each device of the expert axis holds its
         share of the routed experts (``parallel/moe.py``), computes its part
-        with grouped matrix products and the parts are summed over the axis,
-        as an engine step for 4 v5e targets."""
+        with grouped matrix products (the Pallas kernels of
+        ``ops/pallas/grouped_matmul.py``, taken by the layer's own rule) and
+        the parts are summed over the axis, as an engine step for 4 v5e
+        targets."""
         import optax
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -700,10 +702,16 @@ def main():
         bsh = NamedSharding(mesh, P("replica"))
         bav = jax.ShapeDtypeStruct((256, D), jnp.float32, sharding=bsh)
         step = t.make_train_step(donate=False)
-        lowered = step.trace(t.abstract_state(), bav).lower(
-            lowering_platforms=("tpu",))
-        txt = lowered.compile().as_text()
-        assert "ragged-dot" in txt, "no grouped matrix product in the HLO"
+        with _pretend_on_tpu():
+            traced = step.trace(t.abstract_state(), bav)
+        txt = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
+        # the layer's own kernels (ops/pallas/grouped_matmul.py): three
+        # products forward, each one's weights' gradient, and the rows'
+        # cotangent of the down product alone (the tokens get no gradient)
+        kernels = txt.count('custom_call_target="tpu_custom_call"')
+        assert kernels == 7, \
+            f"{kernels} Pallas kernels where the grouped products are 7"
+        assert "ragged-dot" not in txt, "a grouped product left to XLA"
         assert "all-reduce" in txt, "the parts are not summed over the axis"
         return {"experts": E, "expert_axis": ep}
 
