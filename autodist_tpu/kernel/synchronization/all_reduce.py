@@ -679,11 +679,27 @@ def sync_bucketed(grads_by_name, buckets, comp_states, axis_name, hier=None):
                 grads_by_name, b, comp_states[b.key], axis_name, hier)
             _unpack_shard(b, row, grads_by_name, synced)
             continue
+        if _alone(b, axis_name):
+            synced.update((n, grads_by_name[n]) for n in b.var_names)
+            continue
         buf = _bucket_buf(grads_by_name, b)
         reduced, new_states[b.key] = _bucket_reduce(
             buf, comp_states[b.key], b, axis_name, hier)
         _unpack_bucket(b, reduced, grads_by_name, synced)
     return synced, new_states
+
+
+def _alone(bucket, axis_name):
+    """True where a FLAT bucket without a codec is reduced over axes of one
+    device: the mean is the gradients themselves, and they are left as they
+    are.  Packed all the same, the buffer does not always fold away: the
+    compiler turns the slice of a matrix whose rows divide the buffer (a
+    router's ``[hidden, experts]``) into a slice of a 2-D view of the whole
+    buffer, which keeps the concatenation and a re-tiled copy of it alive,
+    twice the gradients' bytes and their copies' time (PERF.md, PR 35)."""
+    return (bucket.hierarchy != _AR.TWO_LEVEL and not bucket.schedule_ir
+            and bucket.compressor == _AR.NoneCompressor
+            and jax.lax.psum(1, axis_name) == 1)
 
 
 def sync_hierarchical(grads_by_name, buckets, comp_states, axis_name, hier):

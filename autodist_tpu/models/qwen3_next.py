@@ -171,14 +171,20 @@ class GatedDeltaNet(nn.Module):
 # and runs the stretch again, where autodiff would keep every float32
 # intermediate (at 32,768 tokens each is 0.27 to 0.54 GB, PERF.md).
 
-@jax.checkpoint
-def _conv_silu(x, w, bias=None):
-    """``silu`` of the causal depthwise convolution over positions:
-    ``y_t = sum_i w_i x_{t - (K-1) + i} (+ bias)``; ``x`` ``[B, S, C]``,
-    ``w`` ``[K, C]``, ``bias`` ``[C]``."""
+def causal_conv(x, w):
+    """The causal depthwise convolution over positions, in ``x``'s dtype:
+    ``y_t = sum_i w_i x_{t - (K-1) + i}`` with zeros before the sequence;
+    ``x`` ``[B, S, C]``, ``w`` ``[K, C]``."""
     width, s = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-    y = sum(padded[:, i:i + s] * w[i].astype(x.dtype) for i in range(width))
+    return sum(padded[:, i:i + s] * w[i].astype(x.dtype)
+               for i in range(width))
+
+
+@jax.checkpoint
+def _conv_silu(x, w, bias=None):
+    """``silu`` of ``causal_conv(x, w) (+ bias)``; ``bias`` ``[C]``."""
+    y = causal_conv(x, w)
     if bias is not None:
         y = y + bias.astype(x.dtype)
     return jax.nn.silu(y)

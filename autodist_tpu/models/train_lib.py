@@ -172,11 +172,12 @@ def llama_capture(config, seq_len, rng=None, streaming_loss=False,
     return loss_fn, params, ["embed"]
 
 
-def _routed_lm_capture(model, seq_len, rng, loss_chunk):
+def _routed_lm_capture(model, seq_len, rng, loss_chunk, tied=False):
     """``(loss_fn, params, sparse_vars)`` of a causal LM whose ``apply``
     returns ``(hidden states, stats)`` with ``stats`` the routed layers'
-    counters (``models/qwen3_next.py:routing_counters``), under an untied
-    ``lm_head`` that the loss streams (``ops/losses.py``, "dv" layout).
+    counters (``models/qwen3_next.py:routing_counters``), under a head that
+    the loss streams (``ops/losses.py``): the untied ``lm_head`` (``[D,
+    V]``, "dv"), or with ``tied`` the embedding ``embed`` (``[V, D]``, "vd").
 
     ``loss_fn(params, batch) -> (loss, counters)``: pass ``has_aux=True`` to
     ``distribute``; the step's metrics then carry ``moe_rows_here``,
@@ -190,15 +191,16 @@ def _routed_lm_capture(model, seq_len, rng, loss_chunk):
     rng = rng if rng is not None else host_key(0)
     dummy = jnp.zeros((1, seq_len), jnp.int32)
     params = model.init(rng, dummy, return_hidden=True)["params"]
+    head, layout = ("embed", "vd") if tied else ("lm_head", "dv")
 
     def loss_fn(p, batch):
         hidden, stats = model.apply({"params": p}, batch["tokens"],
                                     return_hidden=True)
         t = batch["targets"]
         loss = streaming_softmax_xent(
-            hidden, p["lm_head"], t,
+            hidden, p[head], t,
             valid=_positional_mask(t, batch.get(BATCH_MASK_KEY)),
-            chunk=loss_chunk, layout="dv")
+            chunk=loss_chunk, layout=layout)
         counters = routing_counters(jax.lax.stop_gradient(stats))
         return jnp.where(counters["moe_overflow_rows"] > 0, jnp.inf,
                          loss), counters
@@ -221,6 +223,16 @@ def nemotron_h_capture(config, seq_len, rng=None, loss_chunk=8192):
     from autodist_tpu.models.nemotron_h import NemotronH
 
     return _routed_lm_capture(NemotronH(config), seq_len, rng, loss_chunk)
+
+
+def lfm2_capture(config, seq_len, rng=None, loss_chunk=8192):
+    """Init an LFM2 causal LM (``models/lfm2.py``); returns (loss_fn, params,
+    sparse_vars) as ``_routed_lm_capture`` describes them, the loss streamed
+    over the tied embedding.  The layers kept need a routed one."""
+    from autodist_tpu.models.lfm2 import Lfm2
+
+    return _routed_lm_capture(Lfm2(config), seq_len, rng, loss_chunk,
+                              tied=True)
 
 
 def lm_capture(config, seq_len, rng=None):

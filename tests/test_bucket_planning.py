@@ -174,3 +174,41 @@ def test_bucket_order_matches_sorted_groups(comp):
     assert [b.key for b in buckets] == sorted(b.key for b in buckets)
     groups = [int(b.key.split("_")[0][1:]) for b in buckets]
     assert groups == sorted(groups)
+
+
+# -- a bucket with nobody to reduce with --------------------------------------
+
+@pytest.mark.parametrize("devices,comp,packed", [
+    (1, "NoneCompressor", False),       # the mean of one is the gradient
+    (2, "NoneCompressor", True),
+    (1, "BF16Compressor", True),        # a codec rounds on one device too
+])
+def test_a_bucket_over_one_device_is_left_unpacked(devices, comp, packed):
+    """``sync_bucketed`` over axes of one device hands a codec-free bucket's
+    gradients back as they are: no concatenation, no slices (on one chip the
+    compiler does not always fold them away: a router's ``[hidden, experts]``
+    gradient keeps the whole buffer alive, PERF.md PR 35).  With anyone to
+    reduce with, or a codec, the bucket is packed as before; the values are
+    the mean either way."""
+    import jax
+
+    shapes = {"router": (8, 4), "w": (5,)}
+    dtypes = {n: np.dtype(np.float32) for n in shapes}
+    plans = {n: _plan(n, shapes[n], comp=getattr(_C, comp)) for n in shapes}
+    buckets = ar.plan_buckets(plans, shapes, dtypes)
+    states = ar.init_compressor_states(buckets)
+    r = np.random.RandomState(0)
+    grads = {n: jnp.asarray(r.randn(devices, *s), jnp.float32)
+             for n, s in shapes.items()}
+
+    def sync(g):
+        return ar.sync_bucketed(g, buckets, states, "replica")[0]
+
+    mapped = jax.vmap(sync, axis_name="replica")
+    text = str(jax.make_jaxpr(mapped)(grads))
+    assert ("concatenate" in text) is packed
+    got = mapped(grads)
+    for n in shapes:
+        want = np.broadcast_to(np.asarray(grads[n]).mean(0), grads[n].shape)
+        np.testing.assert_allclose(np.asarray(got[n]), want, rtol=1e-2
+                                   if comp != "NoneCompressor" else 1e-6)

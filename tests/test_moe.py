@@ -1,10 +1,12 @@
 """The expert layer (``parallel/moe.py``) against the plain references' routed
 feed-forwards (a loop over the held experts with a mask), on one device and
-over an ``expert`` mesh axis, under both scoring rules and both expert bodies
-the layer knows: a softmax over the experts with SwiGLU experts and a gated
-shared expert (``tests/qwen3_next_reference.py``), and a sigmoid an expert
-with a selection bias, scaled weights, ``relu(.)^2`` experts of two matrices
-and an ungated shared expert (``tests/nemotron_h_reference.py``).
+over an ``expert`` mesh axis, under the combinations of scoring rule and
+expert body that the models bring: a softmax over the experts with SwiGLU
+experts and a gated shared expert (``tests/qwen3_next_reference.py``), a
+sigmoid an expert with a selection bias, scaled weights, ``relu(.)^2``
+experts of two matrices and an ungated shared expert
+(``tests/nemotron_h_reference.py``), and the sigmoid rule at scale 1 round
+SwiGLU experts with no shared expert (``tests/lfm2_reference.py``).
 
 float32 on both sides; what differs is the order of the sums (packed grouped
 products and a scatter-add against masked dense products), so 1e-5 of the
@@ -21,8 +23,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lfm2_reference as RL
 import nemotron_h_reference as RN
 import qwen3_next_reference as R
+from autodist_tpu.models import lfm2 as L
 from autodist_tpu.models import nemotron_h as N
 from autodist_tpu.models import qwen3_next as Q
 from autodist_tpu.parallel.mesh import build_mesh
@@ -31,7 +35,12 @@ from autodist_tpu.parallel.moe import (expert_layer, pack_held, route,
 
 E, D, F, T, K = 8, 16, 32, 64, 2
 SCALE = 2.5
-RULES = ["softmax_swiglu", "sigmoid_relu2"]
+RULES = ["softmax_swiglu", "sigmoid_relu2", "sigmoid_swiglu"]
+# the sigmoid rules' selection bias by the name each model gives it, with the
+# scale and the epsilon of the normalisation
+SIGMOID = {"router_bias": dict(scale=SCALE, norm_eps=1e-20),
+           "expert_bias": dict(scale=1.0, norm_eps=1e-6)}
+BIAS_OF = {"sigmoid_relu2": "router_bias", "sigmoid_swiglu": "expert_bias"}
 
 
 def weights(seed=0, experts=E, rule="softmax_swiglu"):
@@ -47,12 +56,20 @@ def weights(seed=0, experts=E, rule="softmax_swiglu"):
          "shared_gate": w(D, F, scale=0.3), "shared_up": w(D, F, scale=0.3),
          "shared_down": w(F, D, scale=0.3),
          "shared_router": w(D, 1, scale=0.5)}
+    # a bias the size of the scores' spread: it changes the choice
     if rule == "sigmoid_relu2":
-        # a bias the size of the scores' spread: it changes the choice
         p = {k: v for k, v in p.items()
              if k not in ("gate", "shared_gate", "shared_router")}
         p["router_bias"] = w(E, scale=0.2)
+    elif rule == "sigmoid_swiglu":
+        p = {k: v for k, v in p.items() if not k.startswith("shared")}
+        p["expert_bias"] = w(E, scale=0.2)
     return p
+
+
+def bias_of(p):
+    """The name of the selection bias in ``p``, or ``None``."""
+    return next((k for k in SIGMOID if k in p), None)
 
 
 def tokens(seed=1, t=T):
@@ -62,7 +79,11 @@ def tokens(seed=1, t=T):
 def reference(p, x, first=0):
     """``(routed part, shared part, counts)`` of the reference's layer, the
     rule read off the weights."""
-    if "router_bias" in p:
+    if "expert_bias" in p:
+        cfg = {"num_experts_per_tok": K, "first_expert": first}
+        whole, counts = RL.routed_feed_forward(p, x, cfg)
+        shared = jnp.zeros_like(whole)
+    elif "router_bias" in p:
         cfg = {"num_experts_per_tok": K, "first_expert": first,
                "routed_scaling_factor": SCALE}
         whole, counts = RN.routed_feed_forward(p, x, cfg)
@@ -76,10 +97,12 @@ def reference(p, x, first=0):
 
 
 def layer(p, x, **kw):
-    if "router_bias" in p:
-        kw = dict(activation=N.relu2, score=jax.nn.sigmoid,
-                  select_bias=p["router_bias"], scale=SCALE, norm_eps=1e-20,
+    bias = bias_of(p)
+    if bias:
+        kw = dict(score=jax.nn.sigmoid, select_bias=p[bias], **SIGMOID[bias],
                   **kw)
+    if "gate" not in p:
+        kw["activation"] = N.relu2
     return expert_layer(x, p["router"], p.get("gate"), p["up"], p["down"],
                         top_k=K, **kw)
 
@@ -112,8 +135,8 @@ def test_expert_layer_against_the_reference(first, held, rule):
     close(gx, wx)
     for k in set(p) & {"router", "gate", "up", "down"}:
         close(gp[k], wp[k])
-    if "router_bias" in p:          # only the choice reads it
-        assert not np.any(np.asarray(gp["router_bias"]))
+    if bias_of(p):                  # only the choice reads it
+        assert not np.any(np.asarray(gp[bias_of(p)]))
     stats = jax.jit(lambda p, x: layer(p, x, first_expert=first)[1])(p, x)
     counts = np.asarray(jax.jit(lambda p, x: reference(p, x, first)[2])(p, x))
     assert float(stats["rows_here"]) == counts.sum()
@@ -122,65 +145,66 @@ def test_expert_layer_against_the_reference(first, held, rule):
     assert float(stats["overflow_rows"]) == 0
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """The share test: what both halves of the experts give, with the shared
-    expert (which every chip computes alike) counted once, is what the uncut
-    reference gives for the whole layer."""
-    x = jnp.asarray(np.random.RandomState(3).randn(2, 24, 64), jnp.float32)
-    c = dataclasses.replace(Q.QWEN3_NEXT_TINY, experts_held=None)
-    whole = Q.SparseMoE(c)
-    p = jax.jit(whole.init)(jax.random.PRNGKey(0), x)["params"]
-    p = jax.tree.map(lambda w: w * 8, p)            # a router with opinions
-    cfg = {"num_experts_per_tok": c.num_experts_per_tok}
-    want = jax.jit(jax.vmap(
-        lambda t: R.routed_feed_forward(p, t, cfg)[0]))(x)
-    flat = x.reshape(-1, 64)
-    shared = (jax.nn.sigmoid(flat @ p["shared_router"]) * R.swiglu(
+def _qwen3_next_shared(p, flat):
+    return jax.nn.sigmoid(flat @ p["shared_router"]) * R.swiglu(
         flat, p["shared_gate"], p["shared_up"], p["shared_down"])
-    ).reshape(x.shape)
-    halves, rows = [], 0
-    for first in (0, 4):
-        half = dataclasses.replace(c, experts_held=4, first_expert=first)
-        y, stats = jax.jit(Q.SparseMoE(half).apply)(
-            {"params": share(p, first, first + 4)}, x)
-        halves.append(y)
-        rows += float(stats[0])
-    close(halves[0] + halves[1] - shared, want)
-    close(jax.jit(whole.apply)({"params": p}, x)[0], want)
-    # every assignment lands on exactly one of the two shares
-    assert rows == x.shape[0] * x.shape[1] * c.num_experts_per_tok
 
 
-def test_the_sixteen_shares_of_eight_add_up_to_the_uncut_layer():
-    """The same for the sigmoid router's layer at the deployment's cut: the
-    parts that all 16 shares of 8 of the 128 experts give, with the ungated
-    shared expert counted once, are the uncut reference's layer; every
-    share scores all 128 and takes six, under a seeded selection bias."""
+# (the model's routed feed-forward, its configuration uncut, the reference's
+# layer, the part every chip computes alike, experts a share)
+SHARES = {
+    "qwen3_next_two_of_four": (
+        Q.SparseMoE, dataclasses.replace(Q.QWEN3_NEXT_TINY,
+                                         experts_held=None),
+        R.routed_feed_forward, _qwen3_next_shared, 4),
+    "nemotron_h_sixteen_of_eight": (
+        N.RoutedFFN, dataclasses.replace(
+            N.NEMOTRON_H_TINY, n_routed_experts=128, num_experts_per_tok=6,
+            experts_held=None),
+        RN.routed_feed_forward,
+        lambda p, flat: RN.expert(flat, p["shared_up"], p["shared_down"]), 8),
+    "lfm2_four_of_eight": (
+        L.RoutedFFN, dataclasses.replace(
+            L.LFM2_TINY, num_experts=32, num_experts_per_tok=4,
+            experts_held=None),
+        RL.routed_feed_forward, None, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHARES))
+def test_the_shares_add_up_to_the_uncut_layer(case):
+    """The share test at each model's cut (Qwen3-Next's halves; Nemotron-H's
+    16 shares of 8 of 128 experts, six a token; LFM2's 4 shares of 8 of 32,
+    four a token): the parts that all the shares give, with what every chip
+    computes alike (a shared expert; LFM2 has none) counted once, are what
+    the uncut reference gives for the whole layer.  Every share scores all
+    the experts, under a seeded selection bias where the rule has one."""
+    module, c, reference, shared_of, held = SHARES[case]
     x = jnp.asarray(np.random.RandomState(3).randn(2, 24, 64), jnp.float32)
-    c = dataclasses.replace(N.NEMOTRON_H_TINY, n_routed_experts=128,
-                            num_experts_per_tok=6, experts_held=None)
-    whole = N.RoutedFFN(c)
+    whole = module(c)
     p = jax.jit(whole.init)(jax.random.PRNGKey(0), x)["params"]
     p = jax.tree.map(lambda w: w * 8, p)            # a router with opinions
-    p["router_bias"] = 0.2 * jnp.asarray(
-        np.random.RandomState(4).randn(128), jnp.float32)
-    cfg = {"num_experts_per_tok": 6, "routed_scaling_factor": 2.5}
-    want = jax.jit(jax.vmap(
-        lambda t: RN.routed_feed_forward(p, t, cfg)[0]))(x)
-    shared = RN.expert(x.reshape(-1, 64), p["shared_up"],
-                       p["shared_down"]).reshape(x.shape)
-    total, rows = -15 * shared, 0
-    for first in range(0, 128, 8):
-        part = dataclasses.replace(c, experts_held=8, first_expert=first)
-        y, stats = jax.jit(N.RoutedFFN(part).apply)(
-            {"params": {**p, "up": p["up"][first:first + 8],
-                        "down": p["down"][first:first + 8]}}, x)
+    if bias_of(p):
+        p[bias_of(p)] = 0.2 * jnp.asarray(
+            np.random.RandomState(4).randn(*p[bias_of(p)].shape), jnp.float32)
+    k = c.num_experts_per_tok
+    cfg = {"num_experts_per_tok": k, "routed_scaling_factor": getattr(
+        c, "routed_scaling_factor", 1.0)}
+    want = jax.jit(jax.vmap(lambda t: reference(p, t, cfg)[0]))(x)
+    experts = p["up"].shape[0]
+    shared = 0.0 if shared_of is None else shared_of(
+        p, x.reshape(-1, 64)).reshape(x.shape)
+    total, rows = (1 - experts // held) * shared, 0
+    for first in range(0, experts, held):
+        part = dataclasses.replace(c, experts_held=held, first_expert=first)
+        y, stats = jax.jit(module(part).apply)(
+            {"params": share(p, first, first + held)}, x)
         total = total + y
         rows += float(stats[0])
     close(total, want)
     close(jax.jit(whole.apply)({"params": p}, x)[0], want)
-    # every assignment lands on exactly one of the sixteen shares
-    assert rows == x.shape[0] * x.shape[1] * 6
+    # every assignment lands on exactly one of the shares
+    assert rows == x.shape[0] * x.shape[1] * k
 
 
 def test_top_k_by_argmax_is_lax_top_k():
@@ -207,11 +231,14 @@ def test_routing_weights_are_normalised_over_all_the_chosen():
     assert np.all(np.asarray(idx[:, 0]) != np.asarray(idx[:, 1]))
 
 
-def test_sigmoid_scores_are_normalised_and_scaled():
-    p, x = weights(rule="sigmoid_relu2"), tokens()
-    rule = dict(score=jax.nn.sigmoid, norm_eps=1e-20)
-    idx, w = route(x, p["router"], K, scale=SCALE, **rule)
-    np.testing.assert_allclose(np.asarray(w.sum(-1)), SCALE, rtol=1e-6)
+@pytest.mark.parametrize("rule", sorted(BIAS_OF))
+def test_sigmoid_scores_are_normalised_and_scaled(rule):
+    p, x = weights(rule=rule), tokens()
+    scale, eps = (SIGMOID[BIAS_OF[rule]][k] for k in ("scale", "norm_eps"))
+    rule = dict(score=jax.nn.sigmoid, norm_eps=eps)
+    idx, w = route(x, p["router"], K, scale=scale, **rule)
+    # an epsilon of 1e-6 beside a sum of two sigmoids shows in the sixth digit
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), scale, rtol=1e-5)
     scores = jax.nn.sigmoid(x @ p["router"])
     _, raw = route(x, p["router"], K, norm_topk=False, **rule)
     close(raw, jnp.take_along_axis(scores, idx, -1))
@@ -219,25 +246,28 @@ def test_sigmoid_scores_are_normalised_and_scaled():
     assert float(jnp.max(jnp.abs(scores.sum(-1) - 1.0))) > 0.5
 
 
-def test_the_selection_bias_changes_who_is_chosen_and_not_the_weights():
-    p, x = weights(rule="sigmoid_relu2"), tokens()
-    rule = dict(score=jax.nn.sigmoid, scale=SCALE, norm_eps=1e-20)
+@pytest.mark.parametrize("rule", sorted(BIAS_OF))
+def test_the_selection_bias_changes_who_is_chosen_and_not_the_weights(rule):
+    p, x = weights(rule=rule), tokens()
+    bias = p[BIAS_OF[rule]]
+    scale, eps = (SIGMOID[BIAS_OF[rule]][k] for k in ("scale", "norm_eps"))
+    rule = dict(score=jax.nn.sigmoid, scale=scale, norm_eps=eps)
     plain_i, plain_w = route(x, p["router"], K, **rule)
-    idx, w = route(x, p["router"], K, select_bias=p["router_bias"], **rule)
+    idx, w = route(x, p["router"], K, select_bias=bias, **rule)
     scores = jax.nn.sigmoid(x @ p["router"])
-    _, want_i = jax.lax.top_k(scores + p["router_bias"], K)
+    _, want_i = jax.lax.top_k(scores + bias, K)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_i))
     moved = np.any(np.asarray(idx) != np.asarray(plain_i), axis=-1)
     assert 0 < moved.sum() < T
     # the weights are the chosen experts' scores as they are, without the
     # bias: where the choice is the same so are they, to the last bit
     chosen = jnp.take_along_axis(scores, idx, -1)
-    close(w, SCALE * chosen / chosen.sum(-1, keepdims=True))
+    close(w, scale * chosen / (chosen.sum(-1, keepdims=True) + eps))
     np.testing.assert_array_equal(np.asarray(w)[~moved],
                                   np.asarray(plain_w)[~moved])
     # and no gradient reaches it
     g = jax.grad(lambda b: jnp.sum(route(
-        x, p["router"], K, select_bias=b, **rule)[1] ** 2))(p["router_bias"])
+        x, p["router"], K, select_bias=b, **rule)[1] ** 2))(bias)
     assert not np.any(np.asarray(g))
 
 
@@ -288,7 +318,9 @@ def test_overflow_makes_the_loss_non_finite(rule):
         "softmax_swiglu": (train_lib.qwen3_next_capture, dataclasses.replace(
             Q.QWEN3_NEXT_TINY, num_layers=1, full_attention_interval=1)),
         "sigmoid_relu2": (train_lib.nemotron_h_capture, dataclasses.replace(
-            N.NEMOTRON_H_TINY, pattern="E"))}[rule]
+            N.NEMOTRON_H_TINY, pattern="E")),
+        "sigmoid_swiglu": (train_lib.lfm2_capture, dataclasses.replace(
+            L.LFM2_TINY, layers_here=(3,)))}[rule]
     for bound, finite in ((None, True), (8, False)):
         c = dataclasses.replace(tiny, rows_bound=bound)
         made = {}
@@ -361,7 +393,7 @@ def test_expert_parallel_matches_dense(tokens_sharded, rule):
     ``shard_map`` (the router's alike on every device)."""
     mesh = build_mesh(axes={"expert": 8})
     p, x = weights(rule=rule), tokens()
-    alike = [k for k in ("router", "router_bias") if k in p]
+    alike = [k for k in ("router", "router_bias", "expert_bias") if k in p]
     held = [k for k in ("gate", "up", "down") if k in p]
     keys = alike + held
 

@@ -371,6 +371,108 @@ def test_mamba2_layer_nemotron_h_fits_its_share_of_the_step(one_chip):
     assert temp < 2.5e9, temp
 
 
+# the attention layer and a routed layer of the benchmark's LFM2 cell
+LFM2_ATTN = (4, 8192, 32, 64)
+LFM2_KV_HEADS = 8
+# tokens, rows_bound, hidden, expert width, experts, experts held, a token's
+LFM2_ROUTED = (4 * 8192, 49152, 2048, 1792, 32, 8, 4)
+
+
+def _scoped_vmem(text):
+    """The bytes of scoped VMEM the compiler says each ``tpu_custom_call`` of
+    a compiled text uses."""
+    return [int(m) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            for m in re.findall(
+                r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+                r'"offset":"0","size":"(\d+)"', line)]
+
+
+def test_flash_attention_lfm2_shape_and_signatures(one_chip):
+    """D = 64, four query heads to a K/V head, S = 8,192: grouped K/V heads
+    under 128 lanes have no place in the projections' layout (two query
+    heads of a lane block would share half a K/V lane block), so ``_pack``
+    says 0 and the kernels read the FOLDED layout, ``(B*H, S, D)`` made by a
+    transpose, a head's 64 lanes padded to 128.  One head's rows,
+    double-buffered, pass the 14 MiB budget, so a program takes one head
+    under the raised limit and walks the k tiles in a loop.  What the rule
+    reckons (16.25 MB) covers what the compiler says each kernel uses.  The
+    result signatures are those
+    ``benchmark/layer_metrics/full_attn_roofline.py`` tells the kernels
+    apart by, folded too."""
+    b, s, h, d = LFM2_ATTN
+    group = h // LFM2_KV_HEADS
+    heads = F._Heads(d, F._pack(h, group, d, 128))
+    assert heads.pack == 0
+    assert F._together(heads, b * h, h, group, False) == group
+    assert F._pick_heads(heads, group, s, s, 2, 512, 512) == 1
+    assert not F._prefix(True, s, s, 512, s)
+    reckoned = F._vmem_bytes(heads, 1, s, s, 2, 512, 512)
+    assert F._VMEM_BUDGET < reckoned == 16252928 <= F._VMEM_LIMIT * 3 // 4
+    q = _aval(one_chip, (b, s, h, d), jnp.bfloat16)
+    kv = _aval(one_chip, (b, s, LFM2_KV_HEADS, d), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = F.flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    want = [[f"bf16[{b * h},{s},{d}]", f"f32[{b * h},1,{s}]"],
+            [f"bf16[{b * h},{s},{d}]"],
+            [f"f32[{b * h},{s},{d}]", f"f32[{b * h},{s},{d}]"]]
+    assert sorted(_kernel_shapes(text)) == sorted(want)
+    used = _scoped_vmem(text)
+    print(f"LFM2 attention kernels: scoped VMEM {sorted(used)}, "
+          f"reckoned {reckoned}")
+    assert len(used) == 3 and max(used) <= reckoned
+
+
+def test_routed_layer_lfm2_fits_its_share_of_the_step(one_chip, monkeypatch):
+    """One routed layer at the cell's 32,768 tokens, 49,152 packed rows and
+    published widths (sigmoid scores over 32 experts, four a token, the 8
+    held as SwiGLU of 2,048 x 1,792), forward and backward in bfloat16,
+    through ``expert_layer`` as a TPU runs it: the nine grouped products are
+    the repo's kernels at the tiles their rule takes for these widths (1,792
+    is 3.5 x 512, no multiple of the compiler's tile), inside the VMEM an
+    operation may scope by default, and the layer's temporaries (2.07 GB)
+    stay under 2.5 GB."""
+    from autodist_tpu.parallel.moe import expert_layer
+
+    t, m, d, f, experts, held, k = LFM2_ROUTED
+    for down in (False, True):
+        assert M.tiles(m, *((f, d) if down else (d, f)), held, 2) == M.Tiles(
+            256, (f, 1024) if down else (d, 896),
+            (d, 896) if down else (f, 1024),
+            (640, 1024) if down else (1024, 640))
+    monkeypatch.setattr(F, "_on_tpu", lambda: True)
+
+    def loss(x, w_r, bias, w_gate, w_up, w_down):
+        y, stats = expert_layer(
+            x, w_r, w_gate, w_up, w_down, top_k=k, rows_bound=m,
+            score=jax.nn.sigmoid, select_bias=bias, scale=1.0, norm_eps=1e-6)
+        return jnp.sum(y.astype(jnp.float32)) + stats["overflow_rows"]
+
+    avals = [_aval(one_chip, (t, d), jnp.bfloat16),
+             _aval(one_chip, (d, experts), jnp.float32),
+             _aval(one_chip, (experts,), jnp.float32),
+             _aval(one_chip, (held, d, f), jnp.float32),
+             _aval(one_chip, (held, d, f), jnp.float32),
+             _aval(one_chip, (held, f, d), jnp.float32)]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        *avals).compile(compiler_options=TPU_DEFAULTS)
+    text = compiled.as_text()
+    assert "ragged-dot" not in text and "vmem_limit_bytes" not in text
+    shapes = _kernel_shapes(text)
+    assert sorted(shapes) == sorted(
+        [[f"f32[{m},{f}]"]] * 2 + [[f"f32[{m},{d}]"]]            # forward
+        + [[f"bf16[{m},{d}]"]] * 2 + [[f"bf16[{m},{f}]"]]        # rows
+        + [[f"f32[{held},{d},{f}]"]] * 2 + [[f"f32[{held},{f},{d}]"]])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"LFM2 routed layer, 32,768 tokens, 49,152 rows: "
+          f"temp_size_in_bytes {temp}")
+    assert temp < 2.5e9, temp
+
+
 def test_flash_block_update_ring_step(one_chip):
     # one ring step of a device that holds S=1024 positions of 2 x 12 heads
     bh, s, d = 2 * GPT_ATTN[2], 1024, GPT_ATTN[3]
