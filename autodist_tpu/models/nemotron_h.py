@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from autodist_tpu.models.qwen3_next import STATS, _conv_silu, _dense
 from autodist_tpu.ops.pallas.flash_attention import flash_attention, use_flash
 from autodist_tpu.ops.sparse import embedding_lookup
-from autodist_tpu.ops.ssd import ssd_chunked
+from autodist_tpu.ops.ssd import ssd_chunked_packed
 from autodist_tpu.parallel.moe import expert_layer
 
 KINDS = {"M": "ssd", "*": "attn", "E": "moe"}
@@ -190,12 +190,10 @@ class Mamba2Mixer(nn.Module):
                           for lo, hi in zip(cuts, cuts[1:]))
             xbc = _conv_silu(xbc, w_conv, b_conv)
             dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-            u = xbc[..., :inner].reshape(b, s, h, p)
-            b_in = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
-            c_in = xbc[..., inner + g * n:].reshape(b, s, g, n)
         with jax.named_scope("ssd.scan"):
-            y = ssd_chunked(u, dt, -jnp.exp(a_log), b_in, c_in, skip,
-                            chunk=c.chunk_size, dtype=c.dtype)
+            # u, B and C are column blocks of xbc
+            y = ssd_chunked_packed(xbc, dt, -jnp.exp(a_log), skip, g, n,
+                                   chunk=c.chunk_size, dtype=c.dtype)
         with jax.named_scope("ssd.proj"):
             y = _gated_group_norm(y.reshape(b, s, inner), z, w_norm, g,
                                   c.norm_eps)

@@ -40,6 +40,16 @@ The design is the stock one (the compiler's, and ``megablox`` in
     ``a_g^T dy_g`` summed in the float32 result block over a group's visits,
     rows that are not the group's replaced by zeros in BOTH operands (what
     lies past the packed rows may be NaN, and ``0 * NaN`` is NaN);
+  * weights whose ``n`` is no multiple of 128 and whose ``k`` is (Nemotron-H's
+    ``up``, ``[8, 2688, 1856]``) a TPU lays out with ``k`` minor, as it lays
+    any array whose last dimension would need padding to whole lane tiles.
+    Those are read as ``w_t`` ``[g, n, k]``, which is that layout in the
+    kernels' row-major order: the product is the rows' cotangent's kernel
+    and the other way round, and the gradient is made as ``dy_g^T a_g``.
+    Taken the other way, the weights' gradient came out ``n``-minor and the
+    update copied the weights and both their optimizer moments into its
+    layout every step, twelve copies of 160 MB held from the step's start
+    (PERF.md, PR 36);
   * operands in ``a.dtype`` (bfloat16 in training; a float32 cotangent is
     cast first, which is the one-pass bfloat16 contraction the compiler's
     kernel makes of it), float32 accumulation, the result float32, the rows'
@@ -324,10 +334,13 @@ def _tgmm(a, dy, meta, g, block, interpret):
 
 
 @functools.lru_cache(maxsize=None)
-def _make(found, w_dtype, interpret):
+def _make(found, w_dtype, swapped, interpret):
+    """The product's ``custom_vjp``; ``swapped``: over ``w_t`` ``[g, n,
+    k]`` (``_lays_swapped``)."""
     def fwd(a, w, meta):
         w = w.astype(a.dtype)
-        out = _gmm(a, w, meta, found.product, jnp.float32, False, interpret)
+        out = _gmm(a, w, meta, found.product, jnp.float32, swapped,
+                   interpret)
         return out, (a, w, meta)
 
     @jax.custom_vjp
@@ -337,12 +350,23 @@ def _make(found, w_dtype, interpret):
     def bwd(res, dy):
         a, w, meta = res
         dy = dy.astype(a.dtype)
-        da = _gmm(dy, w, meta, found.cotangent, a.dtype, True, interpret)
-        dw = _tgmm(a, dy, meta, w.shape[0], found.gradient, interpret)
+        da = _gmm(dy, w, meta, found.cotangent, a.dtype, not swapped,
+                  interpret)
+        if swapped:
+            dw = _tgmm(dy, a, meta, w.shape[0], found.gradient[::-1],
+                       interpret)
+        else:
+            dw = _tgmm(a, dy, meta, w.shape[0], found.gradient, interpret)
         return da, dw.astype(w_dtype), None
 
     product.defvjp(fwd, bwd)
     return product
+
+
+def _lays_swapped(k, n):
+    """Whether a TPU lays ``[g, k, n]`` out with ``k`` minor: it takes the
+    minor dimension that needs no padding to whole 128-lane tiles."""
+    return n % _LANES != 0 and k % _LANES == 0
 
 
 def grouped_matmul(a, w, meta, interpret=False):
@@ -355,4 +379,9 @@ def grouped_matmul(a, w, meta, interpret=False):
     found = tiles(m, k, n, g, jnp.dtype(a.dtype).itemsize)
     if found is None:
         raise ValueError(f"no tiles for [{m}, {k}] x [{g}, {k}, {n}]")
-    return _make(found, jnp.dtype(w.dtype), bool(interpret))(a, w, meta)
+    swapped = _lays_swapped(k, n)
+    if swapped:
+        # read where it lies, and the gradient handed back the same way
+        w = jnp.swapaxes(w, 1, 2)
+    return _make(found, jnp.dtype(w.dtype), swapped, bool(interpret))(
+        a, w, meta)

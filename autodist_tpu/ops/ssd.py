@@ -28,9 +28,17 @@ At a chunk and a state of 128 every product has a contraction or an output
 of 128.  The products take their operands in ``dtype`` (bfloat16 in
 training) and accumulate in float32; the decays, their sums and the carried
 state are float32 whatever ``dtype`` is.
+
+That scan form is the definition and the CPU's path.  On a TPU, where
+``ops/pallas/ssd.py:tiles`` takes the shapes, two Pallas kernels with the
+same equations and precision run instead, which keep a chunk's tiles and the
+state in VMEM.
 """
 import jax
 import jax.numpy as jnp
+
+from autodist_tpu.ops.pallas import flash_attention
+from autodist_tpu.ops.pallas import ssd as kernels
 
 # Chunks whose state-free parts are computed at once.  A block is a
 # ``jax.checkpoint``: the backward pass keeps the block's inputs and the
@@ -67,6 +75,8 @@ def ssd_chunked(u, dt, a, b, c, d, chunk=128, dtype=None):
     rep = h // g
     if rep * g != h:
         raise ValueError(f"{h} heads over {g} groups")
+    if _kernels_take(chunk, h, p, g, n, dtype):
+        return kernels.ssd_scan(u, dt, a, b, c, d, chunk, dtype)
     q = min(chunk, s)
     span = min(CHUNKS_PER_BLOCK, -(-s // q))
     nb = -(-s // (q * span))
@@ -119,3 +129,32 @@ def ssd_chunked(u, dt, a, b, c, d, chunk=128, dtype=None):
                         (blocks(u), blocks(dt), blocks(b), blocks(c)))
     # [NB, B, span, Q, G, rep, P] -> [B, S, H, P]
     return jnp.moveaxis(y, 0, 1).reshape(bsz, nb * span * q, h, p)[:, :s]
+
+
+def ssd_chunked_packed(xbc, dt, a, d, groups, state, chunk=128, dtype=None):
+    """``ssd_chunked`` over ``xbc`` of ``[B, S, H * P + 2 * G * N]``: ``u``,
+    ``B`` and ``C`` side by side, as the mixer's convolution leaves them
+    (``G = groups``, ``N = state``).  The kernels read the three where they
+    lie, so that no slice of ``xbc`` is copied out for them."""
+    dtype = jnp.dtype(dtype or xbc.dtype)
+    h = dt.shape[2]
+    p = (xbc.shape[2] - 2 * groups * state) // h
+    if _kernels_take(chunk, h, p, groups, state, dtype):
+        return kernels.ssd_scan_packed(xbc, dt, a, d, groups, state, chunk,
+                                       dtype)
+    bsz, s, _ = xbc.shape
+    inner, gn = h * p, groups * state
+    return ssd_chunked(xbc[..., :inner].reshape(bsz, s, h, p), dt, a,
+                       xbc[..., inner:inner + gn].reshape(bsz, s, groups,
+                                                          state),
+                       xbc[..., inner + gn:].reshape(bsz, s, groups, state),
+                       d, chunk, dtype)
+
+
+def _kernels_take(chunk, h, p, g, n, dtype):
+    """The kernels on a TPU (asked as the flash kernels ask) where they tile
+    the chunk, the state and a group's heads inside VMEM; the scan form
+    everywhere else, and the definition."""
+    return (flash_attention._on_tpu() and (h * p) % n == 0
+            and kernels.tiles(chunk, p, h // g, n, dtype.itemsize))
+

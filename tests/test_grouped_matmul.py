@@ -27,8 +27,10 @@ QWEN3_NEXT = (40960, 2048, 512, 16)
 # six row tiles; group 1 is empty, the others start and end inside tiles,
 # 300 rows past the packed ones
 M, GROUPS = 1536, (300, 0, 700, 236)
-# k and n: below a lane block; a multiple of 128; neither that nor the tile
-WIDTHS = [(72, 40), (256, 384), (328, 232)]
+# k and n: below a lane block; a multiple of 128; neither that nor the tile;
+# k a multiple of 128 and n not, which a TPU lays out k-minor (read as [g, n,
+# k], Nemotron-H's up)
+WIDTHS = [(72, 40), (256, 384), (328, 232), (256, 232)]
 
 
 def operands(k, n, dtype=jnp.float32, m=M, groups=GROUPS, seed=0):
@@ -222,3 +224,18 @@ def test_tile_rule_is_a_function_of_the_shapes():
     with pytest.raises(ValueError, match="no tiles"):
         G.grouped_matmul(jnp.zeros((512, 128064), jnp.bfloat16),
                          jnp.zeros((2, 128064, 256)), None)
+
+
+def test_weights_a_tpu_lays_k_minor_are_read_as_they_lie():
+    """Where ``n`` needs padding to whole lane tiles and ``k`` does not, a
+    TPU lays ``[g, k, n]`` out ``k``-minor: the kernels take the weights as
+    ``[g, n, k]`` and hand their gradient back so (Nemotron-H's ``up``, and
+    none of the benchmark's other grouped products)."""
+    assert G._lays_swapped(2688, 1856)
+    for k, n in ((1856, 2688), (2048, 512), (512, 2048), (2048, 1792),
+                 (1792, 2048), (72, 40), (328, 232)):
+        assert not G._lays_swapped(k, n), (k, n)
+    a, w, sizes, ct = operands(256, 232)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda w: jnp.sum(kernels(a, w, sizes) * ct)))(w))
+    assert "f32[4,232,256]" in jaxpr

@@ -17,6 +17,7 @@ router without its selection bias and one without its scaling factor
 (``test_a_dropped_bias_or_scale_would_fail``).
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ import pytest
 import nemotron_h_reference as R
 from autodist_tpu.models import nemotron_h as N
 from autodist_tpu.models.train_lib import nemotron_h_capture
+from autodist_tpu.ops.pallas import ssd as K
 from autodist_tpu.ops.ssd import ssd_chunked
 
 C = N.NEMOTRON_H_TINY          # hidden 64, "MEM*E", 8 experts of which 4
@@ -164,6 +166,130 @@ def test_a_bfloat16_state_would_fail():
     bad = worst(jax.jit(rounded)(u, dt, a, b, c, d),
                 jax.jit(R.ssm_recurrent)(u, dt, a, b, c, d))
     assert bad > 10 * RTOL, bad
+
+
+# ------------------------------------------------- the scan's kernels ----
+
+def scan_and_gradients(path, seed, s, chunk, dtype=jnp.float32, **shape):
+    """``y`` and the gradients of all six inputs of a weighted sum of it, by
+    ``path``: the kernels in the Pallas interpreter, the scan form of
+    ``ssd_chunked`` or the recurrence.  ``u``, ``B`` and ``C`` go in as
+    ``dtype`` (the mixer hands both paths bfloat16 in training)."""
+    u, dt, a, b, c, d = scan_inputs(seed, s, **shape)
+    u, b, c = (t.astype(dtype) for t in (u, b, c))
+    w = jnp.asarray(np.random.RandomState(seed + 1).randn(*u.shape),
+                    jnp.float32)
+    f = {"kernels": functools.partial(K.ssd_scan, chunk=chunk, dtype=dtype,
+                                      interpret=True),
+         "scan": functools.partial(ssd_chunked, chunk=chunk, dtype=dtype),
+         "reference": scan_reference}[path]
+
+    @jax.jit
+    def run(*x):
+        y, back = jax.vjp(lambda *x: f(*x).astype(jnp.float32), *x)
+        return (y,) + back(w)
+
+    return [t.astype(jnp.float32) for t in run(u, dt, a, b, c, d)]
+
+
+def scans_close(got, want, rtol=RTOL):
+    for g, t, name in zip(got, want, "y du ddt da db dc dd".split()):
+        try:
+            close(g, t, rtol)
+        except AssertionError as e:
+            raise AssertionError(name + str(e)) from e
+
+
+SCAN_KERNEL_CASES = [
+    # S not a whole number of chunks, one block; two heads of 8 a tile
+    pytest.param(41, 16, {}, id="ragged"),
+    # eighteen chunks: three blocks (the last padded), the state and its
+    # cotangent carried from grid step to grid step
+    pytest.param(70, 4, {}, id="three-blocks"),
+    # shorter than a chunk, one sequence
+    pytest.param(7, 16, dict(b=1), id="short"),
+    # heads of 64, four to a group: two 128-lane tiles a program
+    pytest.param(41, 16, dict(h=8, p=64, g=2, b=1), id="two-tiles"),
+    # one head a group, a head wider than a tile
+    pytest.param(41, 16, dict(h=2, p=160, g=2, b=1), id="wide-head")]
+
+
+@pytest.mark.parametrize("s,chunk,shape", SCAN_KERNEL_CASES)
+def test_scan_kernels_against_the_recurrence(s, chunk, shape):
+    scans_close(scan_and_gradients("kernels", 3, s, chunk, **shape),
+                scan_and_gradients("reference", 3, s, chunk, **shape))
+
+
+def test_scan_kernels_against_the_scan_form_in_bfloat16():
+    """The two paths of ``ssd_chunked`` with bfloat16 operands in their
+    products (the benchmark's cell): within a few bfloat16 roundings of each
+    other, since the two round different intermediates in the backward
+    pass."""
+    shape = dict(h=8, p=64, g=2, b=1)
+    got = scan_and_gradients("kernels", 3, 70, 16, jnp.bfloat16, **shape)
+    want = scan_and_gradients("scan", 3, 70, 16, jnp.bfloat16, **shape)
+    scans_close(got, want, 2e-2)
+
+
+@pytest.mark.parametrize("what", ["state", "skip"])
+def test_scan_kernels_with_a_bfloat16_state_or_no_skip_would_fail(
+        what, monkeypatch):
+    """The tolerance sees the kernels' precision and every term: with the
+    state rounded to bfloat16 after every chunk, or ``D u`` left out, they
+    are out of it."""
+    if what == "state":
+        whole = K._next_state
+        monkeypatch.setattr(K, "_next_state", lambda *a: whole(*a).astype(
+            jnp.bfloat16).astype(jnp.float32))
+    else:
+        rows = K._rows
+        monkeypatch.setattr(K, "_rows", lambda dt, a, d, q: rows(
+            dt, a, jnp.zeros_like(d), q))
+    got = scan_and_gradients("kernels", 3, 70, 4)
+    want = scan_and_gradients("reference", 3, 70, 4)
+    bad = max(worst(g, t) for g, t in zip(got, want))
+    assert bad > 10 * RTOL, bad
+
+
+@pytest.mark.parametrize("s,chunk", [
+    (70, 4),    # three blocks of the kernels in two of the scan form
+    (41, 16),   # one block of three chunks on both sides
+])
+def test_the_tpu_path_is_the_kernels(s, chunk, monkeypatch):
+    """``ssd_chunked`` as it runs on a TPU, the backend and the tile rule
+    answered for it here and the kernels in the Pallas interpreter: ``y``
+    and the gradients from the forward and the backward kernel, against the
+    recurrence."""
+    from autodist_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(K, "tiles", lambda *a, **k: True)
+    monkeypatch.setattr(K, "ssd_scan", functools.partial(K.ssd_scan,
+                                                         interpret=True))
+    runs = []
+    for name in ("_forward", "_backward"):
+        kernel = getattr(K, name)
+        monkeypatch.setattr(K, name, lambda *a, _k=kernel, _n=name, **k:
+                            runs.append(_n) or _k(*a, **k))
+    shape = dict(h=8, p=64, g=2, b=2)
+    scans_close(scan_and_gradients("scan", 3, s, chunk, **shape),
+                scan_and_gradients("reference", 3, s, chunk, **shape))
+    assert set(runs) == {"_forward", "_backward"}
+
+
+def test_scan_kernels_are_for_chunks_states_and_head_tiles_of_128():
+    """Who takes which path: the kernels take chunks of 128, a state of
+    whole 128-lane tiles and a group's heads in whole 128-lane tiles where a
+    block fits their VMEM; everything else, and every CPU run, is the scan
+    form's (``ssd_chunked`` asks the backend as the flash kernels do)."""
+    assert K.tiles(128, 64, 8, 128)             # the benchmark's cell
+    assert K.tiles(128, 128, 1, 128) and K.tiles(128, 64, 2, 256)
+    assert not K.tiles(64, 64, 8, 128)          # a chunk of 64
+    assert not K.tiles(128, 64, 8, 64)          # a state of 64
+    assert not K.tiles(128, 64, 1, 128)         # (H / G) * P of 64
+    assert not K.tiles(128, 96, 4, 128)         # heads across tile edges
+    assert not K.tiles(128, 128, 64, 128)       # a block past the budget
+    assert jax.default_backend() == "cpu"
 
 
 # ------------------------------------------------------------- mixers ----

@@ -27,6 +27,7 @@ from autodist_tpu.ops.pallas import fused_norm as N
 from autodist_tpu.ops.pallas import gated_delta as G
 from autodist_tpu.ops.pallas import grouped_matmul as M
 from autodist_tpu.ops.pallas import quantize as Q
+from autodist_tpu.ops.pallas import ssd as SSD
 
 # GPT-2-small's training shape in chip_smoke.py: (B, S, H, D)
 GPT_ATTN = (32, 1024, 12, 64)
@@ -287,8 +288,11 @@ def test_grouped_matmul_benchmark_shapes_and_signatures(one_chip, cell, down):
     assert M.tiles(m, k, n, g, 2) is not None
     assert M._PARAMS.vmem_limit_bytes is None
     text, shapes = _grouped_kernels(one_chip, m, k, n, g, jnp.bfloat16)
+    # weights the TPU lays out k-minor come back as [g, n, k] (Nemotron-H's up)
+    grad = f"f32[{g},{n},{k}]" if M._lays_swapped(k, n) else f"f32[{g},{k},{n}]"
+    assert M._lays_swapped(k, n) == (cell == "nemotron_h" and not down)
     assert shapes == sorted(
-        [[f"f32[{m},{n}]"], [f"bf16[{m},{k}]"], [f"f32[{g},{k},{n}]"]])
+        [[f"f32[{m},{n}]"], [f"bf16[{m},{k}]"], [grad]])
     assert "vmem_limit_bytes" not in text
     assert "ragged-dot" not in text
 
@@ -369,6 +373,82 @@ def test_mamba2_layer_nemotron_h_fits_its_share_of_the_step(one_chip):
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(f"Mamba-2 layer, 16,384 tokens: temp_size_in_bytes {temp}")
     assert temp < 2.5e9, temp
+
+
+# the selective scan of the benchmark's Nemotron-H cell: (B, S, heads, head
+# size, groups, state), chunks of 128
+NEMOTRON_H_SCAN = (2, 8192, 64, 64, 8, 128)
+
+
+def test_ssd_scan_nemotron_h_shape_and_signatures(one_chip):
+    """The selective scan's two kernels at the cell's shape, bfloat16, inside
+    the VMEM an operation may scope by default (no limit is passed, PERF.md
+    PR 26), called under the mixer's scope as ``models/nemotron_h.py`` calls
+    them: both custom calls carry ``ssd.scan`` in their ``op_name``, which
+    is how ``ssd_scan_ms`` and ``ssd_scan_kernel_share`` find them.  Results:
+    forward ``y`` as ``[B, H * P, S]`` (positions along the lanes, as the
+    gated group norm takes it) and the float32 state of a group's heads at
+    each block's start; backward ``du``, ``dB`` and ``dC``
+    (summed over a group's heads) and the step rows' cotangent."""
+    b, s, h, p, g, n = NEMOTRON_H_SCAN
+    assert SSD.tiles(128, p, h // g, n)
+    assert SSD._PARAMS.vmem_limit_bytes is None
+    u = _aval(one_chip, (b, s, h, p), jnp.bfloat16)
+    dt = _aval(one_chip, (b, s, h), jnp.float32)
+    heads = _aval(one_chip, (h,), jnp.float32)
+    bc = _aval(one_chip, (b, s, g, n), jnp.bfloat16)
+
+    def loss(u, dt, a, b_in, c_in, d):
+        # the mixer's module name, then its scope, as in the model's op_name
+        with jax.named_scope("Mamba2Mixer"), jax.named_scope("ssd.scan"):
+            y = SSD.ssd_scan(u, dt, a, b_in, c_in, d, 128, jnp.bfloat16)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=range(6)), u, dt, heads, bc, bc,
+                    heads)
+    blocks = s // (SSD.SPAN * 128)
+    want = [[f"bf16[{b},{h * p},{s}]",
+             f"f32[{b},{g},{blocks},{h // g * p},{n}]"],
+            [f"bf16[{b},{s},{h * p}]", f"bf16[{b},{s},{g * n}]",
+             f"bf16[{b},{s},{g * n}]", f"f32[{b},{h},{s // 128},8,128]"]]
+    assert sorted(_kernel_shapes(text)) == sorted(want)
+    assert "vmem_limit_bytes" not in text
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert all("/ssd.scan/" in re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in calls)
+    used = _scoped_vmem(text)
+    print(f"selective scan kernels' scoped VMEM: {used}")
+    assert len(used) == 2 and max(used) <= 16 * 1024 * 1024, used
+
+
+def test_mamba2_layer_nemotron_h_runs_the_kernels_under_its_scope(
+        one_chip, monkeypatch):
+    """The same layer as a TPU runs it (the backend answered for it here):
+    the forward and the backward kernel, their custom calls under the mixer's
+    ``ssd.scan`` scope; the temporaries stay under the scan form's bound."""
+    from autodist_tpu.models.nemotron_h import Mamba2Mixer, NemotronHConfig
+
+    monkeypatch.setattr(F, "_on_tpu", lambda: True)
+    mixer = Mamba2Mixer(NemotronHConfig())
+    x = _aval(one_chip, NEMOTRON_H_TOKENS + (2688,), jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: _aval(one_chip, a.shape, a.dtype),
+        jax.eval_shape(mixer.init, jax.random.PRNGKey(0), x)["params"])
+
+    def loss(p, x):
+        return jnp.sum(mixer.apply({"params": p}, x).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile(compiler_options=TPU_DEFAULTS)
+    text = compiled.as_text()
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and all("/ssd.scan/" in c for c in calls), calls
+    assert "vmem_limit_bytes" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
 # the attention layer and a routed layer of the benchmark's LFM2 cell
