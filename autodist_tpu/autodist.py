@@ -17,6 +17,7 @@ TPU-native UX (no graph capture needed — models are functions)::
 framework distributes it according to the strategy.
 """
 import contextlib
+import functools
 from typing import Any, Callable, Optional, Sequence
 
 from autodist_tpu import const
@@ -24,10 +25,21 @@ from autodist_tpu.const import ENV
 from autodist_tpu.model_item import ModelItem
 from autodist_tpu.resource_spec import ResourceSpec
 from autodist_tpu.strategy.base import Strategy, StrategyCompiler
+from autodist_tpu.telemetry import setup
 from autodist_tpu.utils import logging
 from autodist_tpu.utils.compile_cache import ensure_compile_cache
 
 _DEFAULT_AUTODIST = {}
+
+
+def _set_up(entry):
+    """An entry point that builds a session (or compiles its step) runs
+    inside the set-up span ``ad.distribute`` (telemetry/setup.py)."""
+    @functools.wraps(entry)
+    def spanned(*args, **kwargs):
+        with setup.span("ad.distribute"):
+            return entry(*args, **kwargs)
+    return spanned
 
 
 def _strategy_requests_async(proto):
@@ -131,6 +143,9 @@ class AutoDist:
     def build_strategy(self, model_item) -> Strategy:
         """Build (or load) + compile the strategy for a captured model."""
         raw = self._build_or_load_strategy(model_item)
+        return self._compile_strategy(model_item, raw)
+
+    def _compile_strategy(self, model_item, raw) -> Strategy:
         # all hosts must realize the identical program; check BEFORE compiling
         # so a mismatch fails with a clear message (utils/consistency)
         from autodist_tpu.utils.consistency import verify_agreement
@@ -138,8 +153,20 @@ class AutoDist:
         verify_agreement(raw.proto.SerializeToString(), "strategy")
         return StrategyCompiler(model_item, self._resource_spec).compile(raw)
 
+    def _capture(self, loss_fn, params, optimizer, remat=False, **capture):
+        """The captured model and its strategy as built or loaded."""
+        with setup.span("ad.capture"):
+            if remat:
+                import jax
+
+                loss_fn = jax.checkpoint(loss_fn)
+            item = ModelItem(loss_fn, params, optimizer, **capture)
+        with setup.span("ad.strategy"):
+            return item, self._build_or_load_strategy(item)
+
     # -- main entry --------------------------------------------------------
 
+    @_set_up
     def distribute(
         self,
         loss_fn: Callable,
@@ -191,14 +218,10 @@ class AutoDist:
         MUST exclude masked rows from its local mean (all
         ``models.train_lib`` losses do when the mask is present).
         """
-        if remat:
-            import jax
-
-            loss_fn = jax.checkpoint(loss_fn)
-        item = ModelItem(loss_fn, params, optimizer, sparse_vars=sparse_vars,
-                         has_aux=has_aux, has_rng=has_rng,
-                         mutable_state=mutable_state, eval_fn=eval_fn, name=name)
-        raw = self._build_or_load_strategy(item)
+        item, raw = self._capture(
+            loss_fn, params, optimizer, remat=remat, sparse_vars=sparse_vars,
+            has_aux=has_aux, has_rng=has_rng, mutable_state=mutable_state,
+            eval_fn=eval_fn, name=name)
         return self._assemble_session(
             item, raw, rng=rng, donate=donate, batch_mask=batch_mask,
             verify=verify, data_axes=data_axes, batch_spec=batch_spec,
@@ -212,10 +235,9 @@ class AutoDist:
         cross-host agreement, compile, transform, wrap in a session."""
         from autodist_tpu.kernel.graph_transformer import GraphTransformer
         from autodist_tpu.runner import DistributedSession
-        from autodist_tpu.utils.consistency import verify_agreement
 
-        verify_agreement(raw.proto.SerializeToString(), "strategy")
-        strategy = StrategyCompiler(item, self._resource_spec).compile(raw)
+        with setup.span("ad.strategy_compile"):
+            strategy = self._compile_strategy(item, raw)
         if _strategy_requests_async(strategy.proto):
             # PS(sync=False, ...) selects TRUE asynchrony through the user
             # API (reference: staleness/async is a strategy field,
@@ -271,14 +293,16 @@ class AutoDist:
                 param_specs=transformer_kwargs.get("param_specs"),
                 passes=STATIC_PASSES)
             report.raise_for_errors()
-        transformer = GraphTransformer(strategy, item, self._mesh_for(strategy),
-                                       **transformer_kwargs)
+        with setup.span("ad.transform"):
+            transformer = GraphTransformer(
+                strategy, item, self._mesh_for(strategy), **transformer_kwargs)
         return DistributedSession(transformer, rng=rng, donate=donate,
                                   batch_mask=batch_mask, verify=verify)
 
     # parity alias with the reference's create_distributed_session
     create_distributed_session = distribute
 
+    @_set_up
     def launch(self, loss_fn, params, optimizer, *, coordinator_port=None,
                **kwargs):
         """Full multi-host entry (reference ``create_distributed_session``
@@ -298,16 +322,11 @@ class AutoDist:
         """
         from autodist_tpu.cluster import Coordinator
 
-        if kwargs.pop("remat", False):
-            import jax
-
-            loss_fn = jax.checkpoint(loss_fn)
-        capture_keys = ("sparse_vars", "has_aux", "has_rng", "mutable_state",
-                        "eval_fn", "name")
-        item = ModelItem(loss_fn, params, optimizer,
-                         **{k: kwargs.pop(k) for k in capture_keys
-                            if k in kwargs})
-        raw = self._build_or_load_strategy(item)
+        capture_keys = ("remat", "sparse_vars", "has_aux", "has_rng",
+                        "mutable_state", "eval_fn", "name")
+        item, raw = self._capture(
+            loss_fn, params, optimizer,
+            **{k: kwargs.pop(k) for k in capture_keys if k in kwargs})
 
         kw = {} if coordinator_port is None else {
             "coordinator_port": coordinator_port}
@@ -349,6 +368,7 @@ class AutoDist:
         coordinator.setup(raw)  # chief launches workers; everyone joins
         return self._assemble_session(item, raw, **session_kwargs)
 
+    @_set_up
     def aot_compile(self, loss_fn, params, optimizer, *, batch_shapes,
                     topology="v5e:2x2", **kwargs):
         """Compile the distributed training step for a DEVICELESS TPU

@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from autodist_tpu import telemetry as _telemetry
 from autodist_tpu.const import BATCH_MASK_KEY
 from autodist_tpu.kernel.partitioner import Placement
+from autodist_tpu.telemetry import setup
 from autodist_tpu.utils import logging
 
 
@@ -70,7 +71,15 @@ class DistributedSession:
         self._t = transformer
         self._mesh = transformer.mesh
         self._axis = transformer.axis
-        self.state = transformer.init_state(rng=rng)
+        enclosing = setup.current()
+        with setup.span("ad.init_state") as init:
+            self.state = transformer.init_state(rng=rng)
+            init.count(state_bytes=setup.fullest_device_bytes(self.state))
+        # the set-up spans whose totals every ``ad.run`` after the first
+        # carries (``ad.distribute`` where it built this session), until the
+        # first step has run (telemetry/setup.py)
+        self._setup = [enclosing or init]
+        self._setup_totals = {}
         if transformer.sync_schedule == "overlap":
             # the step compiles with the latency-hiding scheduler + bucket-
             # sized combine thresholds on TPU (kernel/xla_options.py, via
@@ -365,48 +374,79 @@ class DistributedSession:
 
         With ``trace_dir`` the step runs under ``jax.profiler.trace`` in
         ``<trace_dir>/step_<n>/`` (namespaced so repeated traced runs
-        keep every capture) and the metrics carry the capture path under
-        ``"trace_dir"``.
+        keep every capture), which starts before ``ad.run`` opens, and the
+        metrics carry the capture path under ``"trace_dir"``.
 
         Without telemetry this is the hot path: one async dispatch, no
         host sync (unless tracing), no file I/O.  With it (``tel``), the
         step's wall time is closed at a real sync point and the watchdog
         may arm a capture.  Both carry the same ``ad.*`` spans, inert
-        while no profile is taken.
+        while no profile is taken.  The first call is the last part of
+        set-up: it runs inside the set-up span ``ad.first_run``.
         """
+        if self._setup is not None:
+            return self._first_run(batch, trace_dir)
+        tel = self._telemetry
+        path = capture_dir = None
+        if trace_dir:
+            path = self._trace_step_dir(trace_dir, self.step)
+        elif tel is not None:
+            path = capture_dir = tel.arm_capture_dir()
+            if capture_dir:
+                os.makedirs(capture_dir, exist_ok=True)
+        if not path:
+            return self._run(batch)[0]
+        with jax.profiler.trace(path):
+            metrics, gbatch = self._run(batch, finish=False)
+            jax.block_until_ready(metrics)
+        if tel is not None:
+            # once the profile is written: a watchdog capture is read back
+            tel.step_finished(metrics, gbatch, trace_dir=path,
+                              watchdog_capture=capture_dir is not None)
+        metrics = dict(metrics)
+        metrics["trace_dir"] = path
+        return metrics
+
+    def _run(self, batch, finish=True):
+        """``ad.run``: shard, first-step hooks, dispatch.  ``finish=False``
+        leaves the telemetry's step record to the caller, for a profiled
+        step whose profile has to be written first."""
         tel = self._telemetry
         with jax.profiler.StepTraceAnnotation(
                 "ad.run", step_num=self._dispatches) as run_span:
             gbatch = self._shard_batch(batch)
             with self._span("ad.pre_step"):
                 self._pre_step(gbatch)
-            path = capture_dir = None
-            if trace_dir:
-                path = self._trace_step_dir(trace_dir, self.step)
-            elif tel is not None:
-                path = capture_dir = tel.arm_capture_dir()
-                if capture_dir:
-                    os.makedirs(capture_dir, exist_ok=True)
             if tel is not None:
                 tel.step_started()
-            if path:
-                with jax.profiler.trace(path):
-                    metrics = self._dispatch(gbatch)
-                    jax.block_until_ready(metrics)
-            else:
-                metrics = self._dispatch(gbatch)
+            metrics = self._dispatch(gbatch)
             variants = self._step._cache_size()
-            run_span.set_metadata(variants=variants,
-                                  **self._finished_aux(metrics))
+            run_span.set_metadata(variants=variants, **{
+                **self._setup_totals, **self._finished_aux(metrics)})
             if variants > self._variants:
                 self._note_compiled(variants)
             self._dispatches += 1
-            if tel is not None:
-                tel.step_finished(metrics, gbatch, trace_dir=path,
-                                  watchdog_capture=capture_dir is not None)
-        if path:
-            metrics = dict(metrics)
-            metrics["trace_dir"] = path
+            if tel is not None and finish:
+                tel.step_finished(metrics, gbatch)
+        return metrics, gbatch
+
+    def _first_run(self, batch, trace_dir):
+        """The first step, where the step traces, lowers and compiles (or
+        loads from the persistent cache) inside ``ad.dispatch``; then the
+        set-up record's totals for the later ``ad.run`` spans."""
+        frames, self._setup = self._setup, None
+        with setup.span("ad.first_run") as first:
+            metrics = self.run(batch, trace_dir)
+            reserved = [(d.memory_stats() or {}).get("bytes_reserved")
+                        for d in self._mesh.devices.flat
+                        if d.process_index == jax.process_index()]
+            reserved = [r for r in reserved if r is not None]
+            if reserved:
+                # the loaded step's temporaries (CPU keeps no such count)
+                first.count(step_reserved_bytes=max(reserved))
+        self._setup_totals = setup.totals(*frames, first)
+        if self._telemetry is not None:
+            self._telemetry.note_setup(self._setup_totals)
         return metrics
 
     def _finished_aux(self, metrics):

@@ -151,13 +151,17 @@ def flight(worker=None, run_dir=None):
     return recorder(worker=worker, run_dir=run_dir)
 
 
-def span(name, recorder=None, **args):
+def span(name, recorder=None, frame=None, **args):
     """``with telemetry.span("ad.shard_batch", batch=3):`` — the one span
     entry of the program.  Always a ``jax.profiler.TraceAnnotation``: inert
     while no profile is taken, and in a profile a host span on the device
     trace's clock with ``args`` as its stats.  When telemetry is enabled
     (or a session hands in its own ``recorder``) the registry ``span``
-    record is written as well."""
+    record is written as well, and the ``with`` target is the record's
+    arguments: what is added to them inside the span lands on the record
+    and, as stats, on the profile's event.  ``frame``: what the thread's
+    stack of open recorded spans holds for this one
+    (``spans.open_spans``)."""
     import jax
 
     annotation = jax.profiler.TraceAnnotation(name, **args)
@@ -165,13 +169,17 @@ def span(name, recorder=None, **args):
         if not _STATE["enabled"]:
             return annotation
         recorder = SpanRecorder(get_registry())
-    return _recorded(annotation, recorder.span(name, **args))
+    return _recorded(annotation, recorder.span(name, frame=frame, **args),
+                     args)
 
 
 @contextlib.contextmanager
-def _recorded(annotation, record):
-    with annotation, record:
-        yield
+def _recorded(annotation, record, given):
+    with annotation, record as args:
+        yield args
+        added = {k: v for k, v in args.items() if k not in given}
+        if added:
+            annotation.set_metadata(**added)
 
 
 def new_run_id():

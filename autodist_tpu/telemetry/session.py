@@ -18,8 +18,10 @@ What one instrumented step records (a ``step`` JSONL line):
   (:func:`autodist_tpu.simulator.cost_model.traced_step_flops` — the
   shard_map body jaxpr carries per-device shapes, so the count is
   per-chip work including the backward pass), computed once per session.
-- first step carries compile+execute; the compile-vs-execute split is
-  estimated at finalize as ``first_wall - median(steady walls)``.
+- the summary's ``compile_s`` is measured, not estimated: the trace,
+  lowering and compile seconds of the session's set-up record
+  (:mod:`autodist_tpu.telemetry.setup`, handed in after the first step
+  through :meth:`SessionTelemetry.note_setup`).
 
 Plus periodic ``snapshot`` records (``memory_stats`` per device, peak
 summarized), host ``span`` records, the slow-step watchdog's
@@ -109,6 +111,7 @@ class SessionTelemetry:
         self._rtt_s = None
         self._first_wall = None
         self._walls = []               # steady-state RTT-cancelled walls
+        self._setup = None             # the set-up record's totals
         self._mfus = []
         try:
             self._peak_flops = peak_flops()
@@ -206,6 +209,11 @@ class SessionTelemetry:
 
     def step_started(self):
         self._t0 = time.perf_counter()
+
+    def note_setup(self, totals):
+        """The session's set-up totals (``setup.totals``), after its first
+        step."""
+        self._setup = totals
 
     def arm_capture_dir(self):
         """Watchdog-armed one-step profiler dir for the upcoming step, or
@@ -454,8 +462,9 @@ class SessionTelemetry:
                    "step_time_p99_s": ps[0.99]}
         if self._rtt_s is not None:
             summary["rtt_s"] = self._rtt_s
-        if self._walls and self._first_wall is not None:
-            summary["compile_s"] = max(0.0, self._first_wall - ps[0.5])
+        if self._setup is not None:
+            summary["compile_s"] = sum(self._setup[k] for k in (
+                "setup_trace_s", "setup_lower_s", "setup_compile_s"))
         if self._mfus:
             summary["mfu_p50"] = percentiles(self._mfus)[0.5]
         rec_path = self._dump_runtime_record(ps[0.5])

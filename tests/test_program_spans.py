@@ -289,6 +289,25 @@ def test_same_spans_with_telemetry_enabled(tmp_path):
     ds.close()
 
 
+def test_a_run_with_trace_dir_profiles_its_own_spans(tmp_path):
+    """``run(batch, trace_dir=...)`` starts its profile before ``ad.run``
+    opens, so the profile holds the runner's spans, the first step's and a
+    steady one's."""
+    from jax.profiler import ProfileData
+
+    sess = _session(8, AllReduce())
+    for step in range(2):
+        metrics = sess.run(_batch(), trace_dir=str(tmp_path))
+        assert metrics["trace_dir"] == str(tmp_path / f"step_{step}")
+        (path,) = glob.glob(os.path.join(metrics["trace_dir"], "**",
+                                         "*.xplane.pb"), recursive=True)
+        names = {ev.name for plane in ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert names >= {"ad.run", "ad.shard_batch", "ad.pre_step",
+                         "ad.dispatch"}
+
+
 def test_numpy_fallback_has_no_ring(tmp_path, monkeypatch):
     sess, prefetcher, ld, ds = _fed_session(tmp_path, monkeypatch,
                                             native=False)

@@ -83,7 +83,12 @@ def test_five_step_run_manifest_report_calibrate(tmp_path, monkeypatch):
     (summary,) = [r for r in records if r["kind"] == "summary"]
     assert summary["steps"] == 5
     assert summary["step_time_p50_s"] > 0
-    assert summary["compile_s"] >= 0  # first-step compile/execute split
+    # measured: the set-up record's trace, lowering and compile seconds
+    setup = sess._setup_totals
+    assert summary["compile_s"] == pytest.approx(
+        setup["setup_trace_s"] + setup["setup_lower_s"]
+        + setup["setup_compile_s"])
+    assert 0 < summary["compile_s"] < setup["setup_program_s"]
     meta = next(r for r in records if r["kind"] == "meta")
     assert meta["backend"] == "cpu" and meta["num_devices"] == 8
     assert "cost_estimate" in meta  # predicted-vs-measured substrate
@@ -135,7 +140,11 @@ def test_step_record_without_peak_entry(tmp_path):
 
 def test_disabled_zero_overhead(monkeypatch):
     """Default-off: the hot path must perform no device sync, no file
-    I/O, and touch no telemetry code (the acceptance guard)."""
+    I/O, and touch no telemetry code (the acceptance guard).  The first
+    step is set-up: the one span it may record is the always-on set-up
+    record's.  The steps after it record none."""
+    from autodist_tpu.telemetry import setup
+
     assert not telemetry.enabled()
     sess = _session()
     assert sess._telemetry is None
@@ -147,11 +156,20 @@ def test_disabled_zero_overhead(monkeypatch):
 
     monkeypatch.setattr(timing, "fetch_scalar", boom)
     monkeypatch.setattr(telemetry.JsonlWriter, "__init__", boom)
-    monkeypatch.setattr(telemetry.SpanRecorder, "span", boom)
     monkeypatch.setattr(telemetry.MetricsRegistry, "counter", boom)
     monkeypatch.setattr(telemetry.MetricsRegistry, "gauge", boom)
     monkeypatch.setattr(jax, "block_until_ready", boom)   # no device sync
     monkeypatch.setattr(jax.profiler, "trace", boom)      # no profiler I/O
+    span = telemetry.SpanRecorder.span
+
+    def setup_span_only(recorder, *a, **k):
+        if recorder is not setup.RECORDER:
+            boom()
+        return span(recorder, *a, **k)
+
+    monkeypatch.setattr(telemetry.SpanRecorder, "span", setup_span_only)
+    sess.run(BATCH)
+    monkeypatch.setattr(telemetry.SpanRecorder, "span", boom)
     for _ in range(3):
         metrics = sess.run(BATCH)
     assert np.isfinite(float(metrics["loss"]))

@@ -202,7 +202,8 @@ def render(summary):
         f"p90 {_fmt_s(summary['step_time_p90_s'])}   "
         f"p99 {_fmt_s(summary['step_time_p99_s'])}")
     if "compile_s" in summary:
-        add(f"compile (first-step estimate): {_fmt_s(summary['compile_s'])}")
+        add(f"compile (set-up trace + lower + compile, measured): "
+            f"{_fmt_s(summary['compile_s'])}")
     if "throughput_eps_p50" in summary:
         add(f"throughput p50: {summary['throughput_eps_p50']:.1f} examples/s")
     if "mfu_p50" in summary:
