@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from autodist_tpu.models.llama import rope
 from autodist_tpu.models.nemotron_h import RMSNorm, rms
 from autodist_tpu.models.qwen3_next import STATS, _dense, causal_conv
+from autodist_tpu.ops.pallas import flash_attention as pallas_attention
+from autodist_tpu.ops.pallas import short_conv
 from autodist_tpu.ops.pallas.flash_attention import flash_attention, use_flash
 from autodist_tpu.ops.sparse import embedding_lookup
 from autodist_tpu.parallel.moe import expert_layer
@@ -114,13 +116,24 @@ def _taps_init(key, shape, dtype=jnp.float32):
 
 
 @jax.checkpoint
-def _gated_conv(b, c, u, w):
+def _gated_conv_xla(b, c, u, w):
     """``C * conv(B * u)``, the mixer's elementwise stretch: float32 between
     bfloat16 tensors, a ``jax.checkpoint`` as the stretches of
     ``models/qwen3_next.py`` are (the backward pass keeps ``b``, ``c`` and
-    ``u`` and runs it again)."""
+    ``u`` and runs it again).  The definition, and the CPU's path."""
     v = b.astype(jnp.float32) * u.astype(jnp.float32)
     return (c.astype(jnp.float32) * causal_conv(v, w)).astype(b.dtype)
+
+
+def _gated_conv(b, c, u, w):
+    """The mix through the Pallas kernels of ``ops/pallas/short_conv.py`` on
+    a TPU (asked as the flash kernels ask) where ``tiles`` takes the shapes,
+    which read each tensor once a pass and keep the same residuals; the XLA
+    form everywhere else."""
+    if pallas_attention._on_tpu() and short_conv.tiles(
+            *b.shape, w.shape[0], b.dtype.itemsize):
+        return short_conv.gated_short_conv(b, c, u, w)
+    return _gated_conv_xla(b, c, u, w)
 
 
 class ShortConv(nn.Module):

@@ -27,6 +27,7 @@ from autodist_tpu.ops.pallas import fused_norm as N
 from autodist_tpu.ops.pallas import gated_delta as G
 from autodist_tpu.ops.pallas import grouped_matmul as M
 from autodist_tpu.ops.pallas import quantize as Q
+from autodist_tpu.ops.pallas import short_conv as SC
 from autodist_tpu.ops.pallas import ssd as SSD
 
 # GPT-2-small's training shape in chip_smoke.py: (B, S, H, D)
@@ -551,6 +552,58 @@ def test_routed_layer_lfm2_fits_its_share_of_the_step(one_chip, monkeypatch):
     print(f"LFM2 routed layer, 32,768 tokens, 49,152 rows: "
           f"temp_size_in_bytes {temp}")
     assert temp < 2.5e9, temp
+
+
+# the short convolutions' mix of the benchmark's LFM2 cell: (B, S, C), taps
+LFM2_MIX = (4, 8192, 2048, 3)
+
+
+def test_gated_short_conv_lfm2_shape_and_signatures(one_chip):
+    """The mix's two kernels at the cell's shape, bfloat16, called under the
+    mixer's scope as ``models/lfm2.py`` calls them: both custom calls carry
+    ``sconv.mix`` in their ``op_name``, which is how ``sconv_mix_ms`` and
+    ``sconv_mix_kernel_share`` find them, inside the VMEM an operation may
+    scope by default (no limit is passed, PERF.md section 6).  Results:
+    forward ``y``; backward ``db``, ``dc``, ``du`` and the taps' gradient a
+    sequence.  Forward and backward, the mix's temporaries are no more than
+    the checkpointed XLA form's."""
+    from autodist_tpu.models.lfm2 import _gated_conv_xla
+
+    b, s, c, k = LFM2_MIX
+    assert SC.tiles(b, s, c, k) == (512, 512)
+    assert SC._PARAMS.vmem_limit_bytes is None
+    assert SC._SUMMED.vmem_limit_bytes is None
+    x = _aval(one_chip, (b, s, c), jnp.bfloat16)
+    w = _aval(one_chip, (k, c), jnp.float32)
+
+    def grads(mix):
+        def loss(b_in, c_in, u, w):
+            # the mixer's module name, then its scope, as in the model's
+            # op_name; y squared, so that the backward pass needs y
+            with jax.named_scope("ShortConv"), jax.named_scope("sconv.mix"):
+                y = mix(b_in, c_in, u, w)
+            return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+        return jax.jit(jax.grad(loss, argnums=range(4))).lower(
+            x, x, x, w).compile(compiler_options=TPU_DEFAULTS)
+
+    compiled = grads(SC.gated_short_conv)
+    text = compiled.as_text()
+    assert sorted(_kernel_shapes(text)) == sorted(
+        [[f"bf16[{b},{s},{c}]"],
+         [f"bf16[{b},{s},{c}]"] * 3 + [f"f32[{b},{k},{c}]"]])
+    assert "vmem_limit_bytes" not in text
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and all("/sconv.mix/" in c for c in calls), calls
+    used = _scoped_vmem(text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    xla = grads(_gated_conv_xla).memory_analysis().temp_size_in_bytes
+    print(f"short convolution kernels' scoped VMEM: {used}; "
+          f"temp_size_in_bytes {temp}, the XLA form's {xla}")
+    assert len(used) == 2 and max(used) < 16 * 1024 * 1024, used
+    assert temp <= xla, (temp, xla)
 
 
 def test_flash_block_update_ring_step(one_chip):
